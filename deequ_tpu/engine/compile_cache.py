@@ -6,7 +6,7 @@ The stock jax file cache writes entries with a plain
 children of engine/subproc.py die by SIGKILL as a matter of course)
 leaves a TRUNCATED entry that the next process happily deserializes —
 the PR 12 ops note traced ApproxCountDistinct returning garbage
-registers to exactly such a poisoned ``~/.cache/deequ_tpu_xla`` entry.
+registers to exactly such a poisoned persistent-cache entry.
 
 :class:`SafeCompilationCache` closes both holes:
 

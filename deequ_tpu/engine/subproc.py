@@ -122,6 +122,13 @@ class BreakerOpen(Exception):
         self.key = key
 
 
+class ChipHeldByParent(RuntimeError):
+    """The child would need the TPU that this process already holds. A
+    chip belongs to one process at a time, so such a child fails or
+    hangs at backend start-up; the runner refuses before spawning
+    (docs/RESILIENCE.md "One process per chip")."""
+
+
 class _ChildError(RuntimeError):
     """Carrier for a child exception that did not survive pickling —
     the class name and traceback text ride back instead."""
@@ -825,6 +832,7 @@ class IsolatedRunner:
         budget for a single stuck position is exhausted."""
         from deequ_tpu.telemetry import get_telemetry
 
+        _refuse_chip_contention(_parent_platform())
         tm = get_telemetry()
         if self.breaker is not None:
             self.breaker.admit(self.key)
@@ -887,6 +895,26 @@ class IsolatedRunner:
                     ),
                 )
             return result
+
+
+def _refuse_chip_contention(platform: Optional[str]) -> None:
+    """Raise :class:`ChipHeldByParent` when this process has started a
+    TPU backend and a child pinned to ``platform`` would reach for the
+    TPU too. Never starts a backend itself."""
+    if platform and "tpu" not in platform.split(","):
+        return
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return
+    import jax
+
+    if jax.default_backend() == "tpu":
+        raise ChipHeldByParent(
+            "this process holds the TPU, so an isolated child cannot "
+            "start on it; run in-process (isolated_execution=False) or "
+            "keep the parent off JAX"
+        )
 
 
 def _parent_platform() -> Optional[str]:

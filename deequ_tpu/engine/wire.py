@@ -1,9 +1,8 @@
 """Per-column wire codecs for the streamed packed wire.
 
-The streamed path is bytes-bound: BENCH_r04's ``streaming_bundle_100m``
-shows wall ≈ bytes/link exactly, while the host decode pipeline has
-~10x headroom (docs/PERF.md "Wire diet"). Every byte NOT shipped is
-therefore wall time recovered at link rate. This module decides, ONCE
+The streamed path ships every batch host->device, so every byte NOT
+shipped is transfer time recovered (docs/PERF.md "Wire diet"; how
+much that moves the wall on the chip is not measured yet). This module decides, ONCE
 per run, a per-column *wire* dtype narrower than the canonical batch
 dtype wherever the data provably allows it:
 

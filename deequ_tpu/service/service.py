@@ -1657,10 +1657,12 @@ def _child_engine(payload: Dict[str, Any]):
         from jax.sharding import Mesh
 
         devices = jax.devices()
-        if len(devices) >= int(ndev):
-            kwargs["mesh"] = Mesh(
-                np.array(devices[: int(ndev)]), ("dp",)
+        if len(devices) < int(ndev):
+            raise RuntimeError(
+                f"run was placed on {ndev} devices but this child sees "
+                f"{len(devices)}; refusing to run it unsharded"
             )
+        kwargs["mesh"] = Mesh(np.array(devices[: int(ndev)]), ("dp",))
     if not kwargs:
         return None
     from deequ_tpu.engine.scan import AnalysisEngine

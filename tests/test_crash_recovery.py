@@ -14,6 +14,7 @@ marker files, never in-memory state. The autouse reap fixture asserts
 no test leaves a zombie child behind.
 """
 
+import json
 import multiprocessing
 import signal
 import threading
@@ -330,6 +331,44 @@ class TestIsolatedRunner:
         with pytest.raises(CrashLoopError) as excinfo:
             runner.run(_child_sleep, {"seconds": 600})
         assert excinfo.value.last_signal == "timeout"
+
+    @pytest.mark.parametrize(
+        "child_platform, parent_holds_tpu, refused",
+        [
+            (None, True, True),  # child would default to the held chip
+            ("tpu", True, True),
+            ("cpu", True, False),  # child pinned off the chip
+            (None, False, False),  # parent never started a backend
+        ],
+    )
+    def test_one_process_per_chip(
+        self, monkeypatch, child_platform, parent_holds_tpu, refused
+    ):
+        """A parent holding the TPU refuses, before any spawn, to start
+        a child that would reach for it (docs/RESILIENCE.md)."""
+        import jax
+        from jax._src import xla_bridge
+
+        from deequ_tpu.engine import subproc
+
+        monkeypatch.setattr(
+            xla_bridge, "backends_are_initialized", lambda: parent_holds_tpu
+        )
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(subproc, "_parent_platform", lambda: child_platform)
+        launched = []
+        monkeypatch.setattr(
+            IsolatedRunner, "_launch_once",
+            lambda self, fn, payload, launches: launched.append(1) or 7,
+        )
+        runner = IsolatedRunner(key="chip", use_breaker=False)
+        if refused:
+            with pytest.raises(subproc.ChipHeldByParent):
+                runner.run(_child_ok, {"x": 1})
+            assert not launched
+        else:
+            assert runner.run(_child_ok, {"x": 1}) == 7
+            assert launched == [1]
 
 
 # --------------------------------------------------------------------------
@@ -722,3 +761,36 @@ class TestBenchHarness:
 
         assert "profiler" in bench.CONFIG_REGISTRY
         assert all(callable(fn) for fn in bench.CONFIG_REGISTRY.values())
+
+    def test_probe_host_starts_no_backend(self, monkeypatch):
+        """The parent of spawned configs must stay off the chip."""
+        import jax
+
+        import bench
+
+        def touched(*_a, **_k):
+            raise AssertionError("probe_host touched a JAX backend")
+
+        for name in ("default_backend", "devices", "device_count"):
+            monkeypatch.setattr(jax, name, touched)
+        assert "jax_backend" not in bench.probe_host()
+
+    @pytest.mark.parametrize("headline_ok, rc", [(True, 0), (False, 1)])
+    def test_main_exit_code_follows_headline(
+        self, monkeypatch, capsys, headline_ok, rc
+    ):
+        import bench
+
+        def profiler(_args):
+            if not headline_ok:
+                raise RuntimeError("headline config failed")
+            return {
+                "rows_per_sec": 1.0,
+                "link_mb_per_sec": 1.0,
+                "resident_rows_per_sec": 1.0,
+            }
+
+        monkeypatch.setitem(bench.CONFIG_REGISTRY, "profiler", profiler)
+        assert bench.main(["--quick", "--inline", "--budget", "60"]) == rc
+        line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+        assert ("error" in line) != headline_ok

@@ -135,6 +135,26 @@ class TestPallasScatterUnit:
             pallas_scatter._reset_probe_for_tests()
 
 
+    def test_tpu_probe_failure_raises(self, monkeypatch):
+        """On a TPU a kernel that fails to compile is an error, never a
+        silent fall back to the XLA scatter."""
+        import jax
+
+        def refused(*_a, **_k):
+            raise ValueError("Mosaic refused the kernel")
+
+        pallas_scatter._reset_probe_for_tests()
+        monkeypatch.delenv("DEEQU_TPU_PALLAS_INTERPRET", raising=False)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(pallas_scatter, "_make_call", refused)
+        try:
+            with config.configure(pallas_scatter=True):
+                with pytest.raises(ValueError, match="Mosaic refused"):
+                    pallas_scatter.impl_token()
+        finally:
+            pallas_scatter._PROBE.clear()
+
+
 def _profile_like_data(n=8192, seed=3):
     rng = np.random.default_rng(seed)
     return {

@@ -48,6 +48,23 @@ def test_mesh_equals_single_device(cpu_mesh):
         assert a == pytest.approx(b, rel=1e-9), analyzer
 
 
+@pytest.mark.parametrize(
+    "cache_bytes", [8 << 30, 0], ids=["resident", "streaming"]
+)
+def test_mesh_scan_traces_once_over_many_batches(cpu_mesh, cache_bytes):
+    """The init carry is placed on the mesh like the carry each step
+    returns, so step 2 does not retrace (and, on a chip, recompile)
+    the whole fused scan — found on 4 v5e chips in PR 21."""
+    from deequ_tpu import config
+
+    data = big_numeric(40_000)
+    engine = AnalysisEngine(mesh=cpu_mesh, batch_size=8_192)
+    with config.configure(device_cache_bytes=cache_bytes):
+        AnalysisRunner.do_analysis_run(data, ANALYZERS, engine=engine)
+    assert data.num_batches(8_192) > 2
+    assert engine.trace_count == 1 or engine.plan_cache_hit
+
+
 def test_explicit_shard_map_step(cpu_mesh):
     """The explicit-SPMD path: per-shard update + monoid all-reduce."""
     data = big_numeric(16_384)

@@ -1,6 +1,6 @@
 """Streaming parquet ingest: multi-file sources feed the fused scan
 batch-by-batch with bounded host memory and results identical to the
-in-memory path (VERDICT.md next-round #3; SURVEY.md §7 stage 0)."""
+in-memory path (SURVEY.md §7 stage 0)."""
 
 import os
 

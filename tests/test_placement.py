@@ -569,6 +569,16 @@ class TestServiceElasticComposition:
             release.set()
             svc.stop(drain=False, timeout=30)
 
+    def test_child_engine_refuses_missing_devices(self):
+        """A child that sees fewer devices than its run was placed on
+        raises instead of silently running unsharded."""
+        import jax
+
+        from deequ_tpu.service.service import _child_engine
+
+        with pytest.raises(RuntimeError, match="refusing"):
+            _child_engine({"placement_ndev": len(jax.devices()) + 1})
+
     def test_isolation_payload_carries_slice_size(self):
         """Crash isolation composes: the lease itself cannot cross the
         spawn boundary, so the payload ships the slice SIZE and the

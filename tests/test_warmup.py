@@ -4,6 +4,7 @@ nullability) so precompiled plans actually get reused."""
 
 import numpy as np
 import pyarrow as pa
+import pytest
 
 from deequ_tpu import config
 from deequ_tpu.profiles.profiler import ColumnProfiler
@@ -81,3 +82,65 @@ def test_schema_from_parquet(tmp_path):
         "b": "int64",
         "c": "string",
     }
+
+
+# -- where the persistent compile cache lives (what warmup fills) -------
+
+
+@pytest.mark.parametrize(
+    "env, want",
+    [
+        ({"JAX_COMPILATION_CACHE_DIR": "/elsewhere/jax"}, "/elsewhere/jax"),
+        ({}, config.REPO_CACHE_DIR),
+        (
+            {"DEEQU_TPU_COMPILE_CACHE": "",
+             "JAX_COMPILATION_CACHE_DIR": "/elsewhere/jax"},
+            "",
+        ),
+    ],
+)
+def test_compile_cache_dir_order(monkeypatch, env, want):
+    for name in ("JAX_COMPILATION_CACHE_DIR", "DEEQU_TPU_COMPILE_CACHE"):
+        monkeypatch.delenv(name, raising=False)
+    for name, value in env.items():
+        monkeypatch.setenv(name, value)
+    assert config._default_compile_cache_dir() == want
+
+
+@pytest.mark.parametrize("jax_owns_it", [True, False])
+def test_install_sets_the_dir_only_when_jax_does_not(
+    monkeypatch, tmp_path, jax_owns_it
+):
+    """JAX reads JAX_COMPILATION_CACHE_DIR itself: nothing is set in
+    code then. The torn-write-safe store is installed either way."""
+    import jax
+
+    from deequ_tpu.engine import compile_cache
+
+    cache_dir = str(tmp_path / "cache")
+    if jax_owns_it:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache_dir)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    updates, installed = [], []
+    monkeypatch.setattr(jax.config, "update", lambda k, v: updates.append(k))
+    monkeypatch.setattr(
+        compile_cache, "install", lambda d: installed.append(d) or True
+    )
+    monkeypatch.setattr(config, "_compile_cache_installed", False)
+    with config.configure(compilation_cache_dir=cache_dir):
+        config.install_compilation_cache()
+    assert installed == [cache_dir]
+    assert ("jax_compilation_cache_dir" in updates) != jax_owns_it
+
+
+def test_install_failure_is_reported(monkeypatch, tmp_path):
+    from deequ_tpu.engine import compile_cache
+
+    cache_dir = str(tmp_path / "cache")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache_dir)
+    monkeypatch.setattr(compile_cache, "install", lambda d: False)
+    monkeypatch.setattr(config, "_compile_cache_installed", False)
+    with config.configure(compilation_cache_dir=cache_dir):
+        with pytest.warns(RuntimeWarning, match="not installed"):
+            config.install_compilation_cache()

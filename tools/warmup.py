@@ -2,7 +2,7 @@
 
 First-EVER XLA:TPU compilation of a big fused profiler plan costs
 ~110 s (20-col plan; docs/PERF.md pool 3). The persistent cache
-(``DEEQU_TPU_COMPILE_CACHE``, default ``~/.cache/deequ_tpu_xla``)
+(``JAX_COMPILATION_CACHE_DIR`` when set, else ``<repo>/.jax_cache``)
 makes it one-time per machine — but without this tool, the FIRST
 production run eats it in full. Run warmup at deploy time instead:
 
