@@ -64,8 +64,13 @@ def main() -> None:
     sizing = autosize(probe_host())
     n = _sized(NOMINAL_ROWS, sizing, streamed=True)
     data = make_events(n)
-    out_dir = tempfile.mkdtemp(prefix="deequ_tpu_egress_")
+    # the split is gigabytes at a host-sized run: removed when the
+    # demo has checked it
+    with tempfile.TemporaryDirectory(prefix="deequ_tpu_egress_") as d:
+        split_and_check(data, n, d)
 
+
+def split_and_check(data, n: int, out_dir: str) -> None:
     checks = [
         Check(CheckLevel.ERROR, "event hygiene")
         .is_complete("email")

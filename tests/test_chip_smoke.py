@@ -72,10 +72,17 @@ def test_pallas_phase(ds_ref, monkeypatch):
         pallas_scatter._reset_probe_for_tests()
 
 
-def test_mesh_phase_on_four_devices(ds_ref):
+def test_mesh_phase_on_four_devices(ds_ref, monkeypatch):
+    """Five steps on a fresh plan cache: a carry whose sharding changes
+    after step 1 (the retrace found on 4 chips in PR 21) fails it."""
+    from collections import OrderedDict
+
+    from deequ_tpu.engine import scan
+
+    monkeypatch.setattr(scan, "_PLAN_CACHE", OrderedDict())
     devices = jax.devices()
     assert len(devices) >= 4
-    chip_smoke.phase_mesh(*ds_ref, devices[:4])
+    chip_smoke.phase_mesh(*ds_ref, devices[:4], batch_size=ROWS // 5)
 
 
 def test_main_refuses_without_a_tpu(capsys):

@@ -51,18 +51,26 @@ def test_mesh_equals_single_device(cpu_mesh):
 @pytest.mark.parametrize(
     "cache_bytes", [8 << 30, 0], ids=["resident", "streaming"]
 )
-def test_mesh_scan_traces_once_over_many_batches(cpu_mesh, cache_bytes):
+def test_mesh_scan_traces_once_over_many_batches(
+    cpu_mesh, cache_bytes, monkeypatch
+):
     """The init carry is placed on the mesh like the carry each step
     returns, so step 2 does not retrace (and, on a chip, recompile)
-    the whole fused scan — found on 4 v5e chips in PR 21."""
-    from deequ_tpu import config
+    the whole fused scan — found on 4 v5e chips in PR 21. An empty
+    plan cache makes the run trace fresh: exactly one trace."""
+    from collections import OrderedDict
 
+    from deequ_tpu import config
+    from deequ_tpu.engine import scan as scan_mod
+
+    monkeypatch.setattr(scan_mod, "_PLAN_CACHE", OrderedDict())
     data = big_numeric(40_000)
     engine = AnalysisEngine(mesh=cpu_mesh, batch_size=8_192)
     with config.configure(device_cache_bytes=cache_bytes):
         AnalysisRunner.do_analysis_run(data, ANALYZERS, engine=engine)
     assert data.num_batches(8_192) > 2
-    assert engine.trace_count == 1 or engine.plan_cache_hit
+    assert not engine.plan_cache_hit
+    assert engine.trace_count == 1
 
 
 def test_explicit_shard_map_step(cpu_mesh):

@@ -23,13 +23,13 @@ ROWS = 1 << 21  # the engine's real batch (engine/scan.py DEFAULT_MAX_BATCH)
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
-        topo = topologies.get_topology_desc(
+        described = topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2"
         )
     except Exception as e:  # noqa: BLE001 — no TPU compiler here
@@ -37,9 +37,14 @@ def one_chip():
     enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield described
     jax.config.update("jax_enable_compilation_cache", enabled)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _shapes(tree, sharding):
@@ -129,3 +134,59 @@ def test_f64_spill_collector_tpu_branch(one_chip, monkeypatch):
         .compile()
     )
     assert compiled.memory_analysis() is not None
+
+
+def test_dp4_mesh_step_returns_its_carry(topo, monkeypatch):
+    """chip_smoke's suite as one fused step on a 4-chip ``dp`` mesh: the
+    TPU compiler returns the carry with the shardings the init carry is
+    given, so step 2 reuses step 1's program (the retrace found on 4
+    chips in PR 21). The step is captured from a small run on 4 CPU
+    devices and recompiled for the described chips."""
+    from collections import OrderedDict
+
+    from jax.sharding import Mesh, NamedSharding
+
+    import chip_smoke
+    from deequ_tpu import Dataset
+    from deequ_tpu.analyzers import spill
+    from deequ_tpu.engine import AnalysisEngine, scan
+
+    monkeypatch.setattr(spill, "_FORCE_HOST_F64_BITS", True)  # TPU branch
+    monkeypatch.setattr(scan, "_PLAN_CACHE", OrderedDict())
+    calls = []
+    lookup = scan._plan_cache_lookup
+
+    def capturing(key, make_entry):
+        entry, hit = lookup(key, make_entry)
+        fn = entry.fn
+        entry.fn = lambda *args: calls.append((fn, args)) or fn(*args)
+        return entry, hit
+
+    monkeypatch.setattr(scan, "_plan_cache_lookup", capturing)
+    table = chip_smoke.build_table(20_000, chip_smoke.COLS, seed=0)
+    cpu_mesh = Mesh(np.array(jax.devices("cpu")[:4]), ("dp",))
+    chip_smoke.phase_verify(
+        Dataset.from_arrow(table), chip_smoke.Reference(table), "dp4",
+        AnalysisEngine(mesh=cpu_mesh),
+    )
+    fn, args = calls[0]
+
+    chips = Mesh(np.array(topo.devices[:4]), ("dp",))
+
+    def described(x):
+        sharding = getattr(x, "sharding", None)
+        if isinstance(sharding, NamedSharding):  # committed to the mesh
+            sharding = NamedSharding(chips, sharding.spec)
+        else:  # uncommitted: placed by the compiler
+            sharding = None
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+    specs = jax.tree_util.tree_map(described, args)
+    compiled = fn.lower(*specs).compile()
+    carry_in = jax.tree_util.tree_leaves(specs[0])
+    carry_out = jax.tree_util.tree_leaves(compiled.output_shardings[0])
+    assert len(carry_in) == len(carry_out) > 40
+    for given, got in zip(carry_in, carry_out):
+        assert given.sharding is not None
+        assert given.sharding.is_equivalent_to(got, len(given.shape))
+    assert "all-reduce" in compiled.as_text()
