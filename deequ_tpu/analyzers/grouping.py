@@ -563,7 +563,11 @@ def finalize_collector_states(
                 f"({getattr(cancel, 'reason', None) or 'cancelled'})"
             )
         return out
-    fetched = packed_device_get(tuple(pendings))
+    from deequ_tpu.telemetry import get_telemetry
+
+    # the launched sorts finish here: the host blocks on their scalars
+    with get_telemetry().span("spill:sort"):
+        fetched = packed_device_get(tuple(pendings))
     for (spec, build), got in zip(launched, fetched):
         try:
             out[spec.plan] = build(got)
@@ -858,58 +862,61 @@ def _arrow_frequencies(
     Without a where-filter this STREAMS record batches — group_by per
     chunk, then the vectorized sparse merge — so memory is O(chunk +
     distinct), and parquet sources are never fully loaded."""
-    from deequ_tpu.analyzers.spill import _count_data_pass
+    from deequ_tpu.telemetry import get_telemetry
 
-    _count_data_pass()  # host group_by reads the whole source once
     columns = list(plan.columns)
-    if plan.where is None:
-        # group each chunk in Arrow, stash the (small) grouped tables,
-        # and run ONE final group_by over their concatenation — keys
-        # never round-trip through Python objects, and the cost is
-        # O(rows + total_partial_groups), not O(chunks x distinct)
-        parts: List[pa.Table] = []
-        num_rows = 0
-        for record_batch in dataset.record_batches(columns):
-            table = _normalize_float_keys(
-                pa.Table.from_batches([record_batch]), columns
+    # host group_by reads the whole source once
+    with get_telemetry().traversal(
+        "groupby", rows=dataset.num_rows, columns=len(columns)
+    ):
+        if plan.where is None:
+            # group each chunk in Arrow, stash the (small) grouped tables,
+            # and run ONE final group_by over their concatenation — keys
+            # never round-trip through Python objects, and the cost is
+            # O(rows + total_partial_groups), not O(chunks x distinct)
+            parts: List[pa.Table] = []
+            num_rows = 0
+            for record_batch in dataset.record_batches(columns):
+                table = _normalize_float_keys(
+                    pa.Table.from_batches([record_batch]), columns
+                )
+                if not plan.include_nulls:
+                    non_null = np.zeros(table.num_rows, dtype=bool)
+                    for c in columns:
+                        col = table.column(c)
+                        non_null |= ~np.asarray(
+                            col.is_null().combine_chunks()
+                        )
+                    table = table.filter(pa.array(non_null))
+                num_rows += table.num_rows
+                parts.append(
+                    table.group_by(columns).aggregate([([], "count_all")])
+                )
+            if not parts:
+                return FrequenciesAndNumRows(
+                    tuple(columns),
+                    np.empty((0, len(columns)), dtype=object),
+                    np.zeros(0, dtype=np.int64),
+                    0,
+                )
+            combined = pa.concat_tables(parts)
+            grouped = combined.group_by(columns).aggregate(
+                [("count_all", "sum")]
             )
-            if not plan.include_nulls:
-                non_null = np.zeros(table.num_rows, dtype=bool)
-                for c in columns:
-                    col = table.column(c)
-                    non_null |= ~np.asarray(
-                        col.is_null().combine_chunks()
-                    )
-                table = table.filter(pa.array(non_null))
-            num_rows += table.num_rows
-            parts.append(
-                table.group_by(columns).aggregate([([], "count_all")])
+            return _grouped_to_frequencies(
+                grouped, columns, "count_all_sum", num_rows
             )
-        if not parts:
-            return FrequenciesAndNumRows(
-                tuple(columns),
-                np.empty((0, len(columns)), dtype=object),
-                np.zeros(0, dtype=np.int64),
-                0,
-            )
-        combined = pa.concat_tables(parts)
-        grouped = combined.group_by(columns).aggregate(
-            [("count_all", "sum")]
-        )
-        return _grouped_to_frequencies(
-            grouped, columns, "count_all_sum", num_rows
-        )
-    # where-filter: the predicate needs full device reprs — materialize
-    table = dataset.table.select(columns)
-    mask = _where_mask_full(dataset, plan.where)
-    if not plan.include_nulls:
-        non_null = np.zeros(dataset.num_rows, dtype=bool)
-        for c in columns:
-            non_null |= dataset.materialize(ColumnRequest(c, "mask"))
-        mask = non_null if mask is None else (mask & non_null)
-    if mask is not None:
-        table = table.filter(pa.array(mask))
-    return _frequencies_of_table(columns, table)
+        # where-filter: the predicate needs full device reprs — materialize
+        table = dataset.table.select(columns)
+        mask = _where_mask_full(dataset, plan.where)
+        if not plan.include_nulls:
+            non_null = np.zeros(dataset.num_rows, dtype=bool)
+            for c in columns:
+                non_null |= dataset.materialize(ColumnRequest(c, "mask"))
+            mask = non_null if mask is None else (mask & non_null)
+        if mask is not None:
+            table = table.filter(pa.array(mask))
+        return _frequencies_of_table(columns, table)
 
 
 def plans_for(

@@ -653,9 +653,10 @@ def _run_fused_pass(
     so persistence/merge semantics are identical to the single path.
     Composes ``_plan_fused_pass`` + ``_execute_fused_pass`` — the
     runner-layer compile/execute split."""
-    pass_plan = _plan_fused_pass(
-        data, analyzers, grouping, engine, metadata, row_sink=row_sink
-    )
+    with get_telemetry().span("plan:prepare"):
+        pass_plan = _plan_fused_pass(
+            data, analyzers, grouping, engine, metadata, row_sink=row_sink
+        )
     if pass_plan.empty:
         return pass_plan.metrics
     return _execute_fused_pass(

@@ -382,13 +382,26 @@ def _pack_top_pairs(pairs, k: int, null_rows: int):
     return keys_out, np.asarray([p[1] for p in pairs], dtype=np.int64)
 
 
-def _count_data_pass() -> None:
-    """Every full traversal of the source bumps ``engine.data_passes``
-    (run_scan counts its own) — the deferred re-scan paths below each
-    cost one; the collector form costs zero beyond the shared scan."""
+def _traversal(kind: str, dataset, requests):
+    """Every full traversal of the source is one
+    ``Telemetry.traversal`` (run_scan opens its own) — the deferred
+    re-scan paths below each cost one; the collector form costs zero
+    beyond the shared scan."""
     from deequ_tpu.telemetry import get_telemetry
 
-    get_telemetry().counter("engine.data_passes").inc()
+    return get_telemetry().traversal(
+        kind,
+        rows=dataset.num_rows,
+        columns=len({r.column for r in requests}),
+    )
+
+
+def _sort_span():
+    """The span ``spill:sort``: a single-device spill finalize (sort +
+    segment count) and the packed fetch of its scalars."""
+    from deequ_tpu.telemetry import get_telemetry
+
+    return get_telemetry().span("spill:sort")
 
 
 class SpillOverflow(Exception):
@@ -1142,7 +1155,6 @@ def _stage_mesh_columns(dataset, engine, needed, extra_arrays=None):
     mesh, axis = engine.mesh, engine.dp_axis
     ndev = mesh.shape[axis]
     n = dataset.num_rows
-    _count_data_pass()  # materializes every needed column: one pass
     pow2 = 1 << max(1, int(max(n, 1) - 1).bit_length())
     padded = max(1, -(-pow2 // ndev)) * ndev
     sharding = NamedSharding(mesh, P(axis))
@@ -1183,20 +1195,22 @@ def _sharded_shuffle(
 
     Returns (scalars, g_keys, g_counts, segs_host, n_null_host);
     raises SpillOverflow when a hash bucket exceeds its static
-    capacity (the caller falls back to Arrow)."""
+    capacity (the caller falls back to Arrow). Staging materializes
+    every needed column: one traversal."""
     import jax
 
     from deequ_tpu.engine.pack import packed_device_get
 
-    flat, mesh, axis, ndev, cap = _stage_mesh_columns(
-        dataset, engine, needed, extra_arrays
-    )
-    keys, n_sentinel, n_null = jax.jit(build)(flat)
-    out = _sharded_spill_fn(mesh, axis, cap)(keys, n_sentinel, n_null)
-    scalars, g_keys, g_counts, g_segs, overflow, n_null_global = out
-    scalars, overflow_host, n_null_host, segs_host = packed_device_get(
-        (scalars, overflow, n_null_global, np.asarray(g_segs))
-    )
+    with _traversal("spill_mesh", dataset, needed):
+        flat, mesh, axis, ndev, cap = _stage_mesh_columns(
+            dataset, engine, needed, extra_arrays
+        )
+        keys, n_sentinel, n_null = jax.jit(build)(flat)
+        out = _sharded_spill_fn(mesh, axis, cap)(keys, n_sentinel, n_null)
+        scalars, g_keys, g_counts, g_segs, overflow, n_null_global = out
+        scalars, overflow_host, n_null_host, segs_host = packed_device_get(
+            (scalars, overflow, n_null_global, np.asarray(g_segs))
+        )
     if int(overflow_host) > 0:
         raise SpillOverflow(
             f"hash bucket exceeded capacity {cap} on {label}"
@@ -1212,15 +1226,16 @@ def _sharded_shuffle2(dataset, engine, needed, build, label: str):
 
     from deequ_tpu.engine.pack import packed_device_get
 
-    flat, mesh, axis, ndev, cap = _stage_mesh_columns(
-        dataset, engine, needed
-    )
-    k1, k2, _ = jax.jit(build)(flat)
-    out = _sharded_spill2_fn(mesh, axis, cap)(k1, k2)
-    scalars, g_hi, g_lo, g_counts, g_segs, overflow = out
-    scalars, overflow_host, segs_host = packed_device_get(
-        (scalars, overflow, np.asarray(g_segs))
-    )
+    with _traversal("spill_mesh", dataset, needed):
+        flat, mesh, axis, ndev, cap = _stage_mesh_columns(
+            dataset, engine, needed
+        )
+        k1, k2, _ = jax.jit(build)(flat)
+        out = _sharded_spill2_fn(mesh, axis, cap)(k1, k2)
+        scalars, g_hi, g_lo, g_counts, g_segs, overflow = out
+        scalars, overflow_host, segs_host = packed_device_get(
+            (scalars, overflow, np.asarray(g_segs))
+        )
     if int(overflow_host) > 0:
         raise SpillOverflow(
             f"hash bucket exceeded capacity {cap} on {label}"
@@ -1318,91 +1333,94 @@ def device_spill_joint_frequencies(
     batch_size = engine._resolve_batch_size(dataset.num_rows)
     nb = dataset.num_batches(batch_size)
     chunk_batches = min(CHUNK_BATCHES, nb)
-    _count_data_pass()  # deferred re-scan: one traversal per plan
-    split = split_joint_lanes(tuple(sizes))
-    if split is None:  # planner should have gated; double-check
-        raise SpillOverflow("joint key space exceeds two u64 lanes")
-    two_lane = split < len(columns)
-    if two_lane:
-        key2_fn = _joint_chunk_key2_fn(split, len(columns) - split)
-        sizes1 = jnp.asarray(np.asarray(sizes[:split], dtype=np.int64))
-        sizes2 = jnp.asarray(np.asarray(sizes[split:], dtype=np.int64))
-    else:
-        key_fn = _joint_chunk_key_fn(len(columns))
-        sizes_dev = jnp.asarray(np.asarray(sizes, dtype=np.int64))
-
-    keys_parts = []
-    keys2_parts = []
-    n_sentinel = jnp.int64(0)
-    for chunk in dataset.device_scan_chunks(
-        requests,
-        batch_size,
-        chunk_batches=chunk_batches,
-        budget_bytes=config.options().device_cache_bytes,
-    ):
-        rows = chunk[ROW_MASK]
-        if pred is not None:
-            flat = {k: v.reshape(-1) for k, v in chunk.items()}
-            rows = rows & pred.complies(flat).reshape(rows.shape)
-        codes = tuple(chunk[f"{c}::codes"] for c in columns)
-        masks = tuple(chunk[f"{c}::mask"] for c in columns)
+    # deferred re-scan: one traversal per plan
+    with _traversal("spill", dataset, requests):
+        split = split_joint_lanes(tuple(sizes))
+        if split is None:  # planner should have gated; double-check
+            raise SpillOverflow("joint key space exceeds two u64 lanes")
+        two_lane = split < len(columns)
         if two_lane:
-            k1, k2, ns = key2_fn(codes, masks, rows, sizes1, sizes2)
-            keys_parts.append(k1)
-            keys2_parts.append(k2)
+            key2_fn = _joint_chunk_key2_fn(split, len(columns) - split)
+            sizes1 = jnp.asarray(np.asarray(sizes[:split], dtype=np.int64))
+            sizes2 = jnp.asarray(np.asarray(sizes[split:], dtype=np.int64))
         else:
-            k, ns = key_fn(codes, masks, rows, sizes_dev)
-            keys_parts.append(k)
-        n_sentinel = n_sentinel + ns
+            key_fn = _joint_chunk_key_fn(len(columns))
+            sizes_dev = jnp.asarray(np.asarray(sizes, dtype=np.int64))
 
-    def _joined(parts):
-        return jnp.concatenate(parts) if len(parts) > 1 else parts[0]
+        keys_parts = []
+        keys2_parts = []
+        n_sentinel = jnp.int64(0)
+        for chunk in dataset.device_scan_chunks(
+            requests,
+            batch_size,
+            chunk_batches=chunk_batches,
+            budget_bytes=config.options().device_cache_bytes,
+        ):
+            rows = chunk[ROW_MASK]
+            if pred is not None:
+                flat = {k: v.reshape(-1) for k, v in chunk.items()}
+                rows = rows & pred.complies(flat).reshape(rows.shape)
+            codes = tuple(chunk[f"{c}::codes"] for c in columns)
+            masks = tuple(chunk[f"{c}::mask"] for c in columns)
+            if two_lane:
+                k1, k2, ns = key2_fn(codes, masks, rows, sizes1, sizes2)
+                keys_parts.append(k1)
+                keys2_parts.append(k2)
+            else:
+                k, ns = key_fn(codes, masks, rows, sizes_dev)
+                keys_parts.append(k)
+            n_sentinel = n_sentinel + ns
 
-    keys = _joined(keys_parts)
-    n = keys.shape[0]
-    padded = 1 << max(1, int(n - 1).bit_length()) if n > 1 else 1
-    pad = padded - n
-    if pad:
-        keys = jnp.concatenate(
-            [keys, jnp.full(pad, _SENTINEL, dtype=keys.dtype)]
-        )
-        n_sentinel = n_sentinel + pad
+        def _joined(parts):
+            return jnp.concatenate(parts) if len(parts) > 1 else parts[0]
 
-    from deequ_tpu.engine.pack import packed_device_get
-
-    if two_lane:
-        keys2 = _joined(keys2_parts)
+        keys = _joined(keys_parts)
+        n = keys.shape[0]
+        padded = 1 << max(1, int(n - 1).bit_length()) if n > 1 else 1
+        pad = padded - n
         if pad:
-            keys2 = jnp.concatenate(
-                [keys2, jnp.full(pad, _SENTINEL, dtype=keys2.dtype)]
+            keys = jnp.concatenate(
+                [keys, jnp.full(pad, _SENTINEL, dtype=keys.dtype)]
             )
-        scalars, group_hi, group_lo, counts = _finalize2_fn()(
-            keys, keys2, n_sentinel
-        )
-        scalars = packed_device_get(scalars)
-        return TwoLaneDeviceFrequencies(
-            plan.columns,
-            scalars,
-            group_hi,
-            group_lo,
-            counts,
-            list(dictionaries),
-            list(sizes),
-            split,
-        )
+            n_sentinel = n_sentinel + pad
 
-    scalars, group_keys, counts = _finalize_fn()(keys, n_sentinel)
-    scalars = packed_device_get(scalars)
-    return DeviceFrequencies(
-        plan.columns,
-        np.dtype(np.int64),
-        scalars,
-        group_keys,
-        counts,
-        0,
-        False,
-        joint=(list(dictionaries), list(sizes)),
-    )
+        from deequ_tpu.engine.pack import packed_device_get
+
+        if two_lane:
+            keys2 = _joined(keys2_parts)
+            if pad:
+                keys2 = jnp.concatenate(
+                    [keys2, jnp.full(pad, _SENTINEL, dtype=keys2.dtype)]
+                )
+            with _sort_span():
+                scalars, group_hi, group_lo, counts = _finalize2_fn()(
+                    keys, keys2, n_sentinel
+                )
+                scalars = packed_device_get(scalars)
+            return TwoLaneDeviceFrequencies(
+                plan.columns,
+                scalars,
+                group_hi,
+                group_lo,
+                counts,
+                list(dictionaries),
+                list(sizes),
+                split,
+            )
+
+        with _sort_span():
+            scalars, group_keys, counts = _finalize_fn()(keys, n_sentinel)
+            scalars = packed_device_get(scalars)
+        return DeviceFrequencies(
+            plan.columns,
+            np.dtype(np.int64),
+            scalars,
+            group_keys,
+            counts,
+            0,
+            False,
+            joint=(list(dictionaries), list(sizes)),
+        )
 
 
 def device_spill_frequencies(
@@ -1445,88 +1463,89 @@ def device_spill_frequencies(
     batch_size = engine._resolve_batch_size(dataset.num_rows)
     nb = dataset.num_batches(batch_size)
     chunk_batches = min(CHUNK_BATCHES, nb)
-    _count_data_pass()  # deferred re-scan: one traversal per plan
+    # deferred re-scan: one traversal per plan
+    with _traversal("spill", dataset, requests):
 
-    if host_f64:
-        # u64 keys packed on the HOST (host_f64_u64_keys; the TPU X64
-        # rewriter cannot lower the f64->u64 bitcast — measured r4),
-        # shipped instead of the values: same wire bytes, and the
-        # device sort/segment path below is shared untouched
-        parts, n_sent, n_nul = [], 0, 0
-        for batch in dataset.device_batches(requests, batch_size):
-            rows = np.asarray(batch[ROW_MASK], dtype=bool)
-            if pred is not None:
-                rows = rows & np.asarray(pred.complies(batch), dtype=bool)
-            k, ns, nn = host_f64_u64_keys(
-                batch[f"{column}::values"],
-                np.asarray(batch[f"{column}::mask"], dtype=bool),
-                rows,
-                bool(plan.include_nulls),
+        if host_f64:
+            # u64 keys packed on the HOST (host_f64_u64_keys; the TPU X64
+            # rewriter cannot lower the f64->u64 bitcast — measured r4),
+            # shipped instead of the values: same wire bytes, and the
+            # device sort/segment path below is shared untouched
+            parts, n_sent, n_nul = [], 0, 0
+            for batch in dataset.device_batches(requests, batch_size):
+                rows = np.asarray(batch[ROW_MASK], dtype=bool)
+                if pred is not None:
+                    rows = rows & np.asarray(pred.complies(batch), dtype=bool)
+                k, ns, nn = host_f64_u64_keys(
+                    batch[f"{column}::values"],
+                    np.asarray(batch[f"{column}::mask"], dtype=bool),
+                    rows,
+                    bool(plan.include_nulls),
+                )
+                parts.append(k)
+                n_sent += ns
+                n_nul += nn
+            host_keys = (
+                np.concatenate(parts) if len(parts) > 1 else parts[0]
             )
-            parts.append(k)
-            n_sent += ns
-            n_nul += nn
-        host_keys = (
-            np.concatenate(parts) if len(parts) > 1 else parts[0]
-        )
-        from deequ_tpu.data.table import add_transfer_bytes
+            from deequ_tpu.data.table import add_transfer_bytes
 
-        add_transfer_bytes(host_keys.nbytes)
-        keys = _jax.device_put(host_keys)
-        n_sentinel = jnp.int64(n_sent)
-        n_null = jnp.int64(n_nul)
-    else:
-        key_fn = _chunk_key_fn(key_kind, bool(plan.include_nulls))
+            add_transfer_bytes(host_keys.nbytes)
+            keys = _jax.device_put(host_keys)
+            n_sentinel = jnp.int64(n_sent)
+            n_null = jnp.int64(n_nul)
+        else:
+            key_fn = _chunk_key_fn(key_kind, bool(plan.include_nulls))
 
-        keys_parts = []
-        n_sentinel = jnp.int64(0)
-        n_null = jnp.int64(0)
-        for chunk in dataset.device_scan_chunks(
-            requests,
-            batch_size,
-            chunk_batches=chunk_batches,
-            budget_bytes=config.options().device_cache_bytes,
-        ):
-            rows = chunk[ROW_MASK]
-            if pred is not None:
-                flat = {k: v.reshape(-1) for k, v in chunk.items()}
-                rows = rows & pred.complies(flat).reshape(rows.shape)
-            k, ns, nn = key_fn(
-                chunk[f"{column}::values"], chunk[f"{column}::mask"], rows
+            keys_parts = []
+            n_sentinel = jnp.int64(0)
+            n_null = jnp.int64(0)
+            for chunk in dataset.device_scan_chunks(
+                requests,
+                batch_size,
+                chunk_batches=chunk_batches,
+                budget_bytes=config.options().device_cache_bytes,
+            ):
+                rows = chunk[ROW_MASK]
+                if pred is not None:
+                    flat = {k: v.reshape(-1) for k, v in chunk.items()}
+                    rows = rows & pred.complies(flat).reshape(rows.shape)
+                k, ns, nn = key_fn(
+                    chunk[f"{column}::values"], chunk[f"{column}::mask"], rows
+                )
+                keys_parts.append(k)
+                n_sentinel = n_sentinel + ns
+                n_null = n_null + nn
+
+            keys = (
+                jnp.concatenate(keys_parts)
+                if len(keys_parts) > 1
+                else keys_parts[0]
             )
-            keys_parts.append(k)
-            n_sentinel = n_sentinel + ns
-            n_null = n_null + nn
+        # pad to pow2 so the (expensive-to-compile) sort program is shared
+        # across datasets whose row counts round the same way
+        n = keys.shape[0]
+        padded = 1 << max(1, int(n - 1).bit_length()) if n > 1 else 1
+        if padded != n:
+            keys = jnp.concatenate(
+                [keys, jnp.full(padded - n, _SENTINEL, dtype=keys.dtype)]
+            )
+            n_sentinel = n_sentinel + (padded - n)
 
-        keys = (
-            jnp.concatenate(keys_parts)
-            if len(keys_parts) > 1
-            else keys_parts[0]
+        from deequ_tpu.engine.pack import packed_device_get
+
+        with _sort_span():
+            scalars, group_keys, counts = _finalize_fn()(keys, n_sentinel)
+            scalars, n_null_host = packed_device_get((scalars, n_null))
+        return DeviceFrequencies(
+            plan.columns,
+            values_dtype,
+            scalars,
+            group_keys,
+            counts,
+            int(n_null_host),
+            bool(plan.include_nulls),
         )
-    # pad to pow2 so the (expensive-to-compile) sort program is shared
-    # across datasets whose row counts round the same way
-    n = keys.shape[0]
-    padded = 1 << max(1, int(n - 1).bit_length()) if n > 1 else 1
-    if padded != n:
-        keys = jnp.concatenate(
-            [keys, jnp.full(padded - n, _SENTINEL, dtype=keys.dtype)]
-        )
-        n_sentinel = n_sentinel + (padded - n)
-
-    scalars, group_keys, counts = _finalize_fn()(keys, n_sentinel)
-    from deequ_tpu.engine.pack import packed_device_get
-
-    fetched = packed_device_get((scalars, n_null))
-    scalars, n_null_host = fetched
-    return DeviceFrequencies(
-        plan.columns,
-        values_dtype,
-        scalars,
-        group_keys,
-        counts,
-        int(n_null_host),
-        bool(plan.include_nulls),
-    )
 
 
 def _sharded_spill_frequencies(
@@ -2230,73 +2249,77 @@ def multihost_spill_frequencies(
             )
         return host
 
-    _count_data_pass()  # materializes the shard's columns: one pass
-    values = pad_to(dataset.materialize(ColumnRequest(column, "values")))
-    mask = pad_to(dataset.materialize(ColumnRequest(column, "mask")))
-    rows = np.zeros(padded_local, dtype=bool)
-    rows[:n_local] = True
-    if pred is not None:
-        batch = {
-            r.key: pad_to(
-                np.asarray(dataset.materialize(r))
+    # materializes the shard's columns: one pass
+    needed = [ColumnRequest(column, "values")] + (
+        list(pred.requests) if pred is not None else []
+    )
+    with _traversal("spill_mesh", dataset, needed):
+        values = pad_to(dataset.materialize(ColumnRequest(column, "values")))
+        mask = pad_to(dataset.materialize(ColumnRequest(column, "mask")))
+        rows = np.zeros(padded_local, dtype=bool)
+        rows[:n_local] = True
+        if pred is not None:
+            batch = {
+                r.key: pad_to(
+                    np.asarray(dataset.materialize(r))
+                )
+                for r in pred.requests
+            }
+            # one-shot eager eval (like the host_f64 key path): a fresh
+            # jit wrapper here would recompile per call
+            complies = np.asarray(
+                jax.device_get(pred.complies(batch)), dtype=bool
             )
-            for r in pred.requests
+            rows = rows & complies
+
+        if host_bits:
+            bits = pad_to(f64_canonical_bits(values[:n_local]))
+            keys_local, n_sent_l, n_null_l = _finish_keys_jit(
+                plan.include_nulls
+            )(bits, mask, rows)
+        else:
+            keys_local, n_sent_l, n_null_l = _chunk_key_fn(
+                key_kind, plan.include_nulls
+            )(values, mask, rows)
+
+        # global scalar bookkeeping: one tiny allgather
+        sums = np.asarray(
+            multihost_utils.process_allgather(
+                jax.numpy.asarray(
+                    [int(n_sent_l), int(n_null_l)], dtype=jax.numpy.int64
+                )
+            )
+        ).reshape(-1, 2)
+        n_sent = int(sums[:, 0].sum())
+        n_null = int(sums[:, 1].sum())
+
+        sharding = NamedSharding(mesh, P(axis))
+        g_keys = jax.make_array_from_process_local_data(
+            sharding, np.asarray(keys_local)
+        )
+        cap = 1 << max(8, ((4 * per_dev) // ndev - 1).bit_length())
+        out = _sharded_spill_fn(mesh, axis, cap)(
+            g_keys,
+            jax.numpy.int64(n_sent),
+            jax.numpy.int64(n_null),
+        )
+        scalars, gk, gc, g_segs, overflow, _ = out
+        host_scalars = {
+            k: np.asarray(jax.device_get(v)) for k, v in scalars.items()
         }
-        # one-shot eager eval (like the host_f64 key path): a fresh
-        # jit wrapper here would recompile per call (review finding)
-        complies = np.asarray(
-            jax.device_get(pred.complies(batch)), dtype=bool
-        )
-        rows = rows & complies
-
-    if host_bits:
-        bits = pad_to(f64_canonical_bits(values[:n_local]))
-        keys_local, n_sent_l, n_null_l = _finish_keys_jit(
-            plan.include_nulls
-        )(bits, mask, rows)
-    else:
-        keys_local, n_sent_l, n_null_l = _chunk_key_fn(
-            key_kind, plan.include_nulls
-        )(values, mask, rows)
-
-    # global scalar bookkeeping: one tiny allgather
-    sums = np.asarray(
-        multihost_utils.process_allgather(
-            jax.numpy.asarray(
-                [int(n_sent_l), int(n_null_l)], dtype=jax.numpy.int64
+        if int(np.asarray(jax.device_get(overflow))) > 0:
+            raise SpillOverflow(
+                f"hash bucket exceeded capacity {cap} on {column!r} "
+                "(multihost)"
             )
+        state = MultihostDeviceFrequencies(
+            plan.columns,
+            values_dtype,
+            host_scalars,
+            gk,
+            gc,
+            n_null,
+            bool(plan.include_nulls),
         )
-    ).reshape(-1, 2)
-    n_sent = int(sums[:, 0].sum())
-    n_null = int(sums[:, 1].sum())
-
-    sharding = NamedSharding(mesh, P(axis))
-    g_keys = jax.make_array_from_process_local_data(
-        sharding, np.asarray(keys_local)
-    )
-    cap = 1 << max(8, ((4 * per_dev) // ndev - 1).bit_length())
-    out = _sharded_spill_fn(mesh, axis, cap)(
-        g_keys,
-        jax.numpy.int64(n_sent),
-        jax.numpy.int64(n_null),
-    )
-    scalars, gk, gc, g_segs, overflow, _ = out
-    host_scalars = {
-        k: np.asarray(jax.device_get(v)) for k, v in scalars.items()
-    }
-    if int(np.asarray(jax.device_get(overflow))) > 0:
-        raise SpillOverflow(
-            f"hash bucket exceeded capacity {cap} on {column!r} "
-            "(multihost)"
-        )
-    state = MultihostDeviceFrequencies(
-        plan.columns,
-        values_dtype,
-        host_scalars,
-        gk,
-        gc,
-        n_null,
-        bool(plan.include_nulls),
-    )
-    state._dev = (gk, gc, g_segs)
-    return state
+        state._dev = (gk, gc, g_segs)
+        return state

@@ -50,6 +50,21 @@ from deequ_tpu.data.table import (
     dictionary_to_numpy,
     narrow_codes,
 )
+from deequ_tpu.telemetry import get_telemetry
+
+
+def _read_batches(scanner) -> Iterator[pa.RecordBatch]:
+    """The scanner's record batches, each read (parquet page read and
+    decompression) as the span ``ingest:read`` on the reading thread.
+    The span closes before the batch is handed on."""
+    tm = get_telemetry()
+    batches = iter(scanner.to_batches())
+    while True:
+        with tm.span("ingest:read"):
+            batch = next(batches, None)
+        if batch is None:
+            return
+        yield batch
 
 
 class _IncrementalDict:
@@ -225,7 +240,7 @@ class ParquetDataset(Dataset):
         scanner = self._source.scanner(
             columns=list(columns), batch_size=batch_rows
         )
-        return iter(scanner.to_batches())
+        return _read_batches(scanner)
 
     def fingerprint(self) -> str:
         """STRONG source identity for checkpoint invalidation: the
@@ -406,9 +421,14 @@ class ParquetDataset(Dataset):
         # an HONEST pass counter: this pre-pass reads the whole column,
         # so one-pass claims (tests/test_wire_codecs.py) can pin that
         # delta-mode suites never reach here
-        from deequ_tpu.telemetry import get_telemetry
+        with get_telemetry().traversal(
+            "uniques", rows=self.num_rows, columns=1
+        ):
+            return self._scan_uniques(column, cap)
 
-        get_telemetry().counter("engine.data_passes").inc()
+    def _scan_uniques(
+        self, column: str, cap: Optional[int]
+    ) -> Optional[pa.Array]:
         base: Optional[pa.Array] = None  # already-deduped accumulator
         fresh: List[pa.Array] = []  # per-batch uniques since last compact
         fresh_n = 0
@@ -426,7 +446,7 @@ class ParquetDataset(Dataset):
         field_type = self._source.schema.field(column).type
         if pa.types.is_dictionary(field_type):
             field_type = field_type.value_type
-        for batch in scanner.to_batches():
+        for batch in _read_batches(scanner):
             col = batch.column(0)
             if pa.types.is_dictionary(col.type):
                 col = pc.cast(col, col.type.value_type)
@@ -520,7 +540,7 @@ class ParquetDataset(Dataset):
             columns=[req.column], batch_size=self._read_batch_rows
         )
         values_dtype = self._values_dtype(req.column)
-        for batch in scanner.to_batches():
+        for batch in _read_batches(scanner):
             out = _column_batch_to_reprs(
                 batch.column(0), kind, reprs, value_set, values_dtype
             )
@@ -613,12 +633,41 @@ class ParquetDataset(Dataset):
 
         pending: Dict[str, List[np.ndarray]] = {k: [] for k in keys}
         pending_rows = 0
+        tm = get_telemetry()
 
-        def drain(force_pad: bool):
-            nonlocal pending, pending_rows
-            while pending_rows >= batch_size or (
-                force_pad and pending_rows > 0
-            ):
+        def convert(record_batch) -> None:
+            """One record batch to device reprs, appended to pending."""
+            with tm.span("ingest:decode"):
+                for ci, column_name in enumerate(columns):
+                    kind = self._schema.kind_of(column_name)
+                    wanted = by_column[column_name]
+                    col = record_batch.column(ci)
+                    if column_name in accs:
+                        reprs = _column_batch_to_reprs(
+                            col,
+                            kind,
+                            [r for r in wanted if r != "codes"],
+                        )
+                        # absorb new uniques + encode against the grown
+                        # accumulator — the one traversal of the values
+                        reprs["codes"] = accs[
+                            column_name
+                        ].absorb_and_encode(col)
+                    else:
+                        reprs = _column_batch_to_reprs(
+                            col,
+                            kind,
+                            wanted,
+                            value_sets.get(column_name),
+                            values_dtypes.get(column_name),
+                        )
+                    for repr_name, arr in reprs.items():
+                        pending[f"{column_name}::{repr_name}"].append(arr)
+
+        def cut() -> Dict[str, np.ndarray]:
+            """The next engine batch off the front of pending."""
+            nonlocal pending_rows
+            with tm.span("ingest:decode"):
                 batch: Dict[str, np.ndarray] = {}
                 width = min(pending_rows, batch_size)
                 pad = batch_size - width
@@ -655,12 +704,18 @@ class ParquetDataset(Dataset):
                         }
                         shipped_n[c] = acc.n
                 pending_rows -= width
-                yield batch
+            return batch
+
+        def drain(force_pad: bool):
+            while pending_rows >= batch_size or (
+                force_pad and pending_rows > 0
+            ):
+                yield cut()
 
         scanner = self._source.scanner(
             columns=columns, batch_size=self._read_batch_rows
         )
-        for record_batch in scanner.to_batches():
+        for record_batch in _read_batches(scanner):
             if skip_rows > 0:
                 if record_batch.num_rows <= skip_rows:
                     skip_rows -= record_batch.num_rows
@@ -669,31 +724,7 @@ class ParquetDataset(Dataset):
                 skip_rows = 0
             if record_batch.num_rows == 0:
                 continue
-            for ci, column_name in enumerate(columns):
-                kind = self._schema.kind_of(column_name)
-                wanted = by_column[column_name]
-                col = record_batch.column(ci)
-                if column_name in accs:
-                    reprs = _column_batch_to_reprs(
-                        col,
-                        kind,
-                        [r for r in wanted if r != "codes"],
-                    )
-                    # absorb new uniques + encode against the grown
-                    # accumulator — the one traversal of the values
-                    reprs["codes"] = accs[
-                        column_name
-                    ].absorb_and_encode(col)
-                else:
-                    reprs = _column_batch_to_reprs(
-                        col,
-                        kind,
-                        wanted,
-                        value_sets.get(column_name),
-                        values_dtypes.get(column_name),
-                    )
-                for repr_name, arr in reprs.items():
-                    pending[f"{column_name}::{repr_name}"].append(arr)
+            convert(record_batch)
             pending_rows += record_batch.num_rows
             yield from drain(force_pad=False)
         yield from drain(force_pad=True)
@@ -892,7 +923,7 @@ class ParquetDataset(Dataset):
         scanner = self._source.scanner(
             columns=columns, batch_size=self._read_batch_rows
         )
-        for record_batch in scanner.to_batches():
+        for record_batch in _read_batches(scanner):
             if skip_rows > 0:
                 if record_batch.num_rows <= skip_rows:
                     skip_rows -= record_batch.num_rows

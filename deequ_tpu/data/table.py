@@ -561,9 +561,12 @@ class Dataset:
             batch_size = n if n > 0 else 1
         batch_size = max(1, batch_size)
         keys = self._dedup_requests(requests)
-        full: Dict[str, np.ndarray] = {
-            k: self.materialize(r) for k, r in keys.items()
-        }
+        from deequ_tpu.telemetry import get_telemetry
+
+        with get_telemetry().span("ingest:decode"):
+            full: Dict[str, np.ndarray] = {
+                k: self.materialize(r) for k, r in keys.items()
+            }
         if n == 0:
             if start_batch > 0:
                 return
@@ -862,6 +865,9 @@ class Dataset:
         """
         import jax
 
+        from deequ_tpu.telemetry import get_telemetry
+
+        tm = get_telemetry()
         n = self.num_rows
         nb, n_chunks = self._chunk_geometry(batch_size, chunk_batches)
         chunk_rows = chunk_batches * batch_size
@@ -878,8 +884,11 @@ class Dataset:
             )
         self._touch_cache_registry()
 
+        uploaded = [0]  # bytes put by this call: cache:upload's attribute
+
         def put(host: np.ndarray):
             add_transfer_bytes(host.nbytes)
+            uploaded[0] += host.nbytes
             if sharding is not None:
                 # lint-ok: wire-discipline: the chunk-cache put IS the
                 # resident wire (packed chunks, transfer accounted)
@@ -917,7 +926,23 @@ class Dataset:
         lut_cache: Dict[str, object] = {}
         pack_masks = sharding is None
 
-        for ci in range(start_chunk, n_chunks):
+        def host_chunk(r: ColumnRequest, start_row: int) -> np.ndarray:
+            with tm.span("ingest:decode"):
+                return self._host_chunk(r, start_row, chunk_rows, batch_size)
+
+        def chunk_cached(ci: int) -> bool:
+            return all(
+                (k, batch_size, chunk_batches, ci, shard_key)
+                in self._device_cache
+                for k, r in keys.items()
+                if not self._synthesize_mask(r)
+            ) and (
+                ROW_MASK, batch_size, chunk_batches, ci, shard_key
+            ) in self._device_cache
+
+        def chunk(ci: int) -> Dict[str, object]:
+            """Chunk ``ci`` from the device cache, filling what it
+            lacks (host conversion, then the puts)."""
             start_row = ci * chunk_rows
             rm_key = (ROW_MASK, batch_size, chunk_batches, ci, shard_key)
             if rm_key not in self._device_cache:
@@ -950,9 +975,7 @@ class Dataset:
                             shard_key,
                         )
                         if codes_ck not in self._device_cache:
-                            codes_host = self._host_chunk(
-                                codes_req, start_row, chunk_rows, batch_size
-                            )
+                            codes_host = host_chunk(codes_req, start_row)
                             self._device_cache[codes_ck] = put(codes_host)
                             self._add_cache_bytes(codes_host.nbytes)
                         if r.column not in lut_cache:
@@ -966,23 +989,30 @@ class Dataset:
                             lut_cache[r.column],
                         )
                     elif r.repr == "mask" and pack_masks:
-                        host = self._host_chunk(
-                            r, start_row, chunk_rows, batch_size
-                        )
                         packed = np.packbits(
-                            host, axis=1, bitorder="little"
+                            host_chunk(r, start_row), axis=1,
+                            bitorder="little",
                         )
                         arr = _mask_unpack_fn(batch_size)(put(packed))
                     else:
-                        host = self._host_chunk(
-                            r, start_row, chunk_rows, batch_size
-                        )
-                        arr = put(host)
+                        arr = put(host_chunk(r, start_row))
                     self._device_cache[ck] = arr
                     self._add_cache_bytes(
                         chunk_rows * self._request_row_bytes(r)
                     )
                 out[k] = self._device_cache[ck]
+            return out
+
+        for ci in range(start_chunk, n_chunks):
+            if chunk_cached(ci):
+                yield chunk(ci)
+                continue
+            # a cache miss: the chunk's host conversion and upload, paid
+            # once per table while its columns stay cached
+            with tm.span("cache:upload", chunk=ci) as sp:
+                before = uploaded[0]
+                out = chunk(ci)
+                sp.set(bytes=uploaded[0] - before)
             yield out
 
     def clear_device_cache(self) -> None:

@@ -513,24 +513,26 @@ def finalize_row_sink(plan: RowSinkPlan, data, engine) -> EgressReport:
         # the deferred families re-read the source by nature
         # (uniqueness is global; untraceable assertions run per unique
         # value on the host) — the run honestly pays a second pass
-        tm.counter("engine.data_passes").inc()
-        cache = _OracleCache(data)
-        for d in plan.deferred:
-            try:
-                excluded = _where_pass(d.where, data, cache)
-                outcome = _outcome_for(
-                    d.analyzer, data, assertion=d.assertion,
-                    excluded=excluded, cache=cache,
-                )
-            except Exception as exc:  # noqa: BLE001 — oracle degrades
-                unsupported[d.name] = f"{type(exc).__name__}: {exc}"
-                continue
-            if outcome is None:
-                unsupported[d.name] = (
-                    "assertion raised per-value; no row-level column"
-                )
-                continue
-            deferred_outcomes[d.name] = (outcome, excluded)
+        with tm.traversal(
+            "egress", rows=int(data.num_rows), columns=len(plan.deferred)
+        ):
+            cache = _OracleCache(data)
+            for d in plan.deferred:
+                try:
+                    excluded = _where_pass(d.where, data, cache)
+                    outcome = _outcome_for(
+                        d.analyzer, data, assertion=d.assertion,
+                        excluded=excluded, cache=cache,
+                    )
+                except Exception as exc:  # noqa: BLE001 — degrades
+                    unsupported[d.name] = f"{type(exc).__name__}: {exc}"
+                    continue
+                if outcome is None:
+                    unsupported[d.name] = (
+                        "assertion raised per-value; no row-level column"
+                    )
+                    continue
+                deferred_outcomes[d.name] = (outcome, excluded)
         # columns the oracle degraded must not appear in the schema
         writer.deferred_names = [
             n for n in writer.deferred_names if n in deferred_outcomes

@@ -96,6 +96,8 @@ class MonotonicClock:
     """Production clock: ``time.monotonic``."""
 
     def now(self) -> float:
+        # lint-ok: telemetry-timing: the injected production clock of
+        # deadlines and stall detection, not a timing of work
         return time.monotonic()
 
     def queue_poll_s(self, stall_s: Optional[float] = None) -> float:

@@ -40,10 +40,11 @@ from __future__ import annotations
 
 import queue
 import threading
-import time
 import weakref
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterable, Iterator, Optional
+
+from deequ_tpu.telemetry import clock, get_telemetry
 
 # Every thread this module (or scan._prefetched) starts registers here;
 # tests assert the union is [] after teardown — the leak probe.
@@ -70,7 +71,10 @@ class IngestPoolStats:
     caller's work/commit closures; flushed as ONE ``ingest_pool``
     telemetry event on the consumer thread at teardown, so the
     per-stage busy fractions are diagnosable from the JSONL alone
-    (tools/obs_report.py "ingest pool" line)."""
+    (tools/obs_report.py "ingest pool" line). The caller's
+    ``decode_s``/``encode_s`` are the walls of its ``ingest:decode`` /
+    ``ingest:encode`` spans; the pool times the rest on
+    ``telemetry.clock()``."""
 
     workers: int = 0
     released: int = 0
@@ -257,17 +261,23 @@ def ordered_ingest(
     stats: Optional[IngestPoolStats] = None,
     sizer: Optional[Callable[[Any], int]] = None,
     emit_event: bool = True,
+    binding=None,
 ) -> Iterator[Any]:
     """Yield ``commit(work(item), item)`` for each item of ``items``,
     with ``work`` fanned out over ``workers`` threads and results
     released strictly in source order (see module docstring for the
     full ordering/teardown contract). ``sizer(result)`` (optional)
-    prices a finished result in bytes for the peak-in-flight gauge."""
+    prices a finished result in bytes for the peak-in-flight gauge.
+    The reader and workers run under ``binding``
+    (``Telemetry.thread_binding()``; default: what is open on the
+    consuming thread), so their spans join the consumer's run."""
     workers = max(1, int(workers))
     lookahead = max(workers, int(lookahead))
     stats = stats or IngestPoolStats()
     stats.workers = workers
-    started = time.monotonic()
+    started = clock()
+    if binding is None:
+        binding = get_telemetry().thread_binding()
 
     work_q: "queue.Queue" = queue.Queue(maxsize=lookahead)
     stop = threading.Event()
@@ -302,7 +312,7 @@ def ordered_ingest(
                 )
             cond.notify_all()
 
-    def reader() -> None:
+    def read() -> None:
         seq = 0
         try:
             for item in items:
@@ -331,15 +341,19 @@ def ordered_ingest(
             return
         deposit(seq, ("done", None, None, 0))
 
-    def worker_loop() -> None:
+    def reader() -> None:
+        with binding():
+            read()
+
+    def work_loop() -> None:
         while not stop.is_set():
-            t0 = time.monotonic()
+            t0 = clock()
             try:
                 seq, item = work_q.get(timeout=0.1)
             except queue.Empty:
-                stats.add("idle_s", time.monotonic() - t0)
+                stats.add("idle_s", clock() - t0)
                 continue
-            stats.add("idle_s", time.monotonic() - t0)
+            stats.add("idle_s", clock() - t0)
             try:
                 result = work(item)
                 nbytes = int(sizer(result)) if sizer is not None else 0
@@ -350,6 +364,10 @@ def ordered_ingest(
             # EXACTLY position seq — after every earlier item
             except BaseException as exc:  # noqa: BLE001 — re-raised
                 deposit(seq, ("error", exc, None, 0))
+
+    def worker_loop() -> None:
+        with binding():
+            work_loop()
 
     reader_t = register_ingest_thread(
         threading.Thread(
@@ -371,11 +389,9 @@ def ordered_ingest(
         t.start()
 
     def flush_stats() -> None:
-        stats.wall_s = time.monotonic() - started
+        stats.wall_s = clock() - started
         if not emit_event:
             return
-        from deequ_tpu.telemetry import get_telemetry
-
         # emitted on the CONSUMER (scan) thread: telemetry run
         # captures are thread-scoped
         get_telemetry().event("ingest_pool", **stats.to_event_fields())
@@ -386,14 +402,14 @@ def ordered_ingest(
             with cond:
                 entry = results.get(want)
                 if entry is None:
-                    t0 = time.monotonic()
+                    t0 = clock()
                     timeout = (
                         supervisor.poll_s()
                         if supervisor is not None
                         else 0.1
                     )
                     cond.wait(timeout=timeout)
-                    stats.stall_s += time.monotonic() - t0
+                    stats.stall_s += clock() - t0
                     entry = results.get(want)
                 if entry is not None:
                     del results[want]
@@ -408,11 +424,11 @@ def ordered_ingest(
                 return
             if supervisor is not None:
                 supervisor.note_arrival()
-            t0 = time.monotonic()
+            t0 = clock()
             released = (
                 commit(payload, item) if commit is not None else payload
             )
-            stats.commit_s += time.monotonic() - t0
+            stats.commit_s += clock() - t0
             stats.released += 1
             with cond:
                 state["released"] = want + 1

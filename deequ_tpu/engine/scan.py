@@ -39,7 +39,6 @@ from __future__ import annotations
 
 import math
 import threading
-import time
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -248,25 +247,29 @@ def _plan_cache_lookup(key, make_entry):
     set ``entry.fn`` (the entry exists first so the jitted closure can
     capture its own trace counter). Returns (entry, cache_hit)."""
     label = _shape_label(key)
-    entry = _PLAN_CACHE.get(key) if key is not None else None
-    if entry is not None:
-        _PLAN_CACHE.move_to_end(key)
-        tm = get_telemetry()
+    tm = get_telemetry()
+    with tm.span("plan:lookup") as sp:
+        entry = _PLAN_CACHE.get(key) if key is not None else None
+        hit = entry is not None
+        sp.set(hit=hit)
+        if hit:
+            _PLAN_CACHE.move_to_end(key)
+        else:
+            entry = _PlanEntry(None)
+            make_entry(entry)
+            if key is not None:
+                _PLAN_CACHE[key] = entry
+                while len(_PLAN_CACHE) > _PLAN_CACHE_CAP:
+                    _PLAN_CACHE.popitem(last=False)
+    if hit:
         tm.counter("engine.plan_cache.hits").inc()
         if label is not None:
             tm.counter(f"engine.plan_cache.per_shape.{label}.hits").inc()
-        return entry, True
-    entry = _PlanEntry(None)
-    make_entry(entry)
-    if key is not None:
-        _PLAN_CACHE[key] = entry
-        while len(_PLAN_CACHE) > _PLAN_CACHE_CAP:
-            _PLAN_CACHE.popitem(last=False)
-    tm = get_telemetry()
-    tm.counter("engine.plan_cache.misses").inc()
-    if label is not None:
-        tm.counter(f"engine.plan_cache.per_shape.{label}.misses").inc()
-    return entry, False
+    else:
+        tm.counter("engine.plan_cache.misses").inc()
+        if label is not None:
+            tm.counter(f"engine.plan_cache.per_shape.{label}.misses").inc()
+    return entry, hit
 
 
 def plan_token_of(key) -> Optional[str]:
@@ -322,7 +325,9 @@ def active_prefetch_workers():
     return ingest_mod.active_ingest_threads()
 
 
-def _prefetched(iterator, depth: Optional[int] = None, supervisor=None):
+def _prefetched(
+    iterator, depth: Optional[int] = None, supervisor=None, binding=None
+):
     """Run a batch iterator in a background thread with a bounded queue:
     the host-side read/convert of batch i+1 overlaps the device transfer
     and compute of batch i. Arrow/numpy work releases the GIL; all jax
@@ -343,7 +348,11 @@ def _prefetched(iterator, depth: Optional[int] = None, supervisor=None):
     item that DOES land re-arms the stall timer. Teardown releases the
     armed source-interrupt event — a worker blocked inside a hung read
     wakes, raises, notices ``stop``, and exits — then joins the worker
-    so a cancelled scan leaks no thread (``active_prefetch_workers``)."""
+    so a cancelled scan leaks no thread (``active_prefetch_workers``).
+
+    ``binding`` (``Telemetry.thread_binding()``, taken by the scan)
+    puts the worker into the scan's run capture under its traversal
+    span; without one the worker binds to whatever was open here."""
     import queue
     import threading
 
@@ -355,6 +364,8 @@ def _prefetched(iterator, depth: Optional[int] = None, supervisor=None):
         depth = max(1, int(config.options().ingest_depth))
     q: "queue.Queue" = queue.Queue(maxsize=depth)
     stop = threading.Event()
+    if binding is None:
+        binding = get_telemetry().thread_binding()
 
     def put(msg) -> bool:
         # bounded put that notices an abandoned consumer — a plain
@@ -368,20 +379,21 @@ def _prefetched(iterator, depth: Optional[int] = None, supervisor=None):
         return False
 
     def worker():
-        try:
-            for item in iterator:
-                # re-check AFTER a put too: the consumer's abort drain
-                # can let a blocked put succeed, and the next iteration
-                # would read+convert a whole wasted batch
-                if not put(("item", item)) or stop.is_set():
-                    return
-        # lint-ok: interrupt-swallow: the prefetch worker forwards the
-        # exception (interrupts included) through the queue; the
-        # consumer re-raises it on the scan thread
-        except BaseException as exc:  # noqa: BLE001 — re-raised below
-            put(("error", exc))
-        else:
-            put(("done", None))
+        with binding():
+            try:
+                for item in iterator:
+                    # re-check AFTER a put too: the consumer's abort
+                    # drain can let a blocked put succeed, and the next
+                    # iteration would read+convert a whole wasted batch
+                    if not put(("item", item)) or stop.is_set():
+                        return
+            # lint-ok: interrupt-swallow: the prefetch worker forwards
+            # the exception (interrupts included) through the queue;
+            # the consumer re-raises it on the scan thread
+            except BaseException as exc:  # noqa: BLE001 — re-raised below
+                put(("error", exc))
+            else:
+                put(("done", None))
 
     thread = threading.Thread(
         target=worker, daemon=True, name="deequ-tpu-prefetch"
@@ -1096,36 +1108,39 @@ class AnalysisEngine:
             return None
         from deequ_tpu import config
 
-        opts = config.options()
-        requests: List[ColumnRequest] = []
-        for analyzer, _ in analyzers_and_ops:
-            requests.extend(analyzer.device_requests(dataset))
-        ops = tuple(o for _, o in analyzers_and_ops)
-        host_slots = tuple(
-            i for i, op in enumerate(ops) if op.host_fold is not None
-        )
-        batch_size = self._resolve_batch_size(dataset.num_rows)
-        sharding = self._resolve_placement(P(None, self.dp_axis))
-        cache_budget = opts.device_cache_bytes
-        chunk_batches = min(CHUNK_BATCHES, dataset.num_batches(batch_size))
-        resident = bool(cache_budget) and dataset.estimated_device_bytes(
-            requests,
-            batch_size,
-            chunk_batches,
-            derive_lengths=sharding is None,
-        ) <= cache_budget
-        return ScanPlan(
-            ops=ops,
-            host_slots=host_slots,
-            requests=tuple(requests),
-            batch_size=batch_size,
-            sharding=sharding,
-            mode="resident" if resident else "streaming",
-            cache_budget=cache_budget if resident else 0,
-            cache_key=_plan_cache_key(ops, host_slots, sharding),
-            estimated_bytes=self.estimated_run_bytes(dataset),
-            wire_codecs=opts.wire_codecs,
-        )
+        with get_telemetry().span("plan:prepare"):
+            opts = config.options()
+            requests: List[ColumnRequest] = []
+            for analyzer, _ in analyzers_and_ops:
+                requests.extend(analyzer.device_requests(dataset))
+            ops = tuple(o for _, o in analyzers_and_ops)
+            host_slots = tuple(
+                i for i, op in enumerate(ops) if op.host_fold is not None
+            )
+            batch_size = self._resolve_batch_size(dataset.num_rows)
+            sharding = self._resolve_placement(P(None, self.dp_axis))
+            cache_budget = opts.device_cache_bytes
+            chunk_batches = min(
+                CHUNK_BATCHES, dataset.num_batches(batch_size)
+            )
+            resident = bool(cache_budget) and dataset.estimated_device_bytes(
+                requests,
+                batch_size,
+                chunk_batches,
+                derive_lengths=sharding is None,
+            ) <= cache_budget
+            return ScanPlan(
+                ops=ops,
+                host_slots=host_slots,
+                requests=tuple(requests),
+                batch_size=batch_size,
+                sharding=sharding,
+                mode="resident" if resident else "streaming",
+                cache_budget=cache_budget if resident else 0,
+                cache_key=_plan_cache_key(ops, host_slots, sharding),
+                estimated_bytes=self.estimated_run_bytes(dataset),
+                wire_codecs=opts.wire_codecs,
+            )
 
     def execute_plan(
         self, plan: "ScanPlan", dataset: Dataset
@@ -1144,8 +1159,18 @@ class AnalysisEngine:
         tm.counter("engine.scans").inc()
         # one traversal of the source, however many analyzers ride it
         # (spill collectors included) — the paper's scan-sharing claim,
-        # as a counter
-        tm.counter("engine.data_passes").inc()
+        # as a counter and a span
+        with tm.traversal(
+            "fused",
+            rows=dataset.num_rows,
+            columns=len({r.column for r in plan.requests}),
+        ):
+            return self._execute_plan(plan, dataset)
+
+    def _execute_plan(
+        self, plan: "ScanPlan", dataset: Dataset
+    ) -> List[Any]:
+        tm = get_telemetry()
         ops = list(plan.ops)
         host_slots = list(plan.host_slots)
         requests = list(plan.requests)
@@ -1264,18 +1289,25 @@ class AnalysisEngine:
         def fold_fetched(fetched: Dict[int, List[Any]]) -> None:
             """Fold already-FETCHED stacked per-chunk outputs into the
             host accumulators (the one copy of the unstack+fold loop)."""
-            for i in host_slots:
-                acc = host_accs[i]
-                for ys_chunk in fetched[i]:
-                    leaves, treedef = jax.tree_util.tree_flatten(ys_chunk)
-                    cb = leaves[0].shape[0] if leaves else 0
-                    for b in range(cb):
-                        acc = ops[i].host_fold(
-                            acc,
-                            treedef.unflatten([leaf[b] for leaf in leaves]),
+            if not host_slots:
+                return
+            with get_telemetry().span("scan:fold"):
+                for i in host_slots:
+                    acc = host_accs[i]
+                    for ys_chunk in fetched[i]:
+                        leaves, treedef = jax.tree_util.tree_flatten(
+                            ys_chunk
                         )
-                host_accs[i] = acc
-                pending[i] = []
+                        cb = leaves[0].shape[0] if leaves else 0
+                        for b in range(cb):
+                            acc = ops[i].host_fold(
+                                acc,
+                                treedef.unflatten(
+                                    [leaf[b] for leaf in leaves]
+                                ),
+                            )
+                    host_accs[i] = acc
+                    pending[i] = []
 
         # resilience: retry/quarantine at CHUNK granularity (the unit
         # the staging generator yields), checkpoint/resume at stable
@@ -1340,6 +1372,7 @@ class AnalysisEngine:
             return src
 
         clock = PhaseClock("resident")
+        clock.trace_counter = lambda: entry.traces
         self.phase_times = clock.times
 
         # memory pressure (engine/memory.py, docs/RESILIENCE.md): a
@@ -1523,33 +1556,12 @@ class AnalysisEngine:
     def _finish_scan_telemetry(self, clock: PhaseClock) -> None:
         """End-of-scan bookkeeping: the wall-decomposition event (one
         ``scan_phases`` record per run_scan, captured per-run and
-        JSONL-logged) plus retrace accounting. Traced runs additionally
-        get the decomposition as synthetic ``phase:compile`` /
-        ``phase:scan`` child spans — derived from the SAME PhaseClock
-        buckets at scan end, so an untraced run pays nothing per batch
-        beyond the clock it already ran."""
+        JSONL-logged; its buckets are the walls of the scan's live
+        ``scan:*`` spans) plus retrace accounting."""
         tm = get_telemetry()
         if self.trace_count:
             tm.counter("engine.traces").inc(self.trace_count)
         tm.event("scan_phases", **clock.times)
-        if tm.current_trace() is None:
-            return
-        times = clock.times
-        compile_s = float(times.get("first_step_s", 0.0) or 0.0)
-        scan_s = sum(
-            float(times.get(k, 0.0) or 0.0)
-            for k in ("dispatch_s", "sync_s", "host_wait_s", "put_s")
-        )
-        mode = times.get("mode", "")
-        if compile_s > 0:
-            # first step folds trace+compile (or a cache replay — the
-            # retrace count tells which) on top of one dispatch
-            tm.emit_span(
-                "phase:compile", compile_s, mode=mode,
-                retraces=self.trace_count,
-            )
-        if scan_s > 0:
-            tm.emit_span("phase:scan", scan_s, mode=mode)
 
     def _run_scan_streaming(
         self,
@@ -1564,6 +1576,9 @@ class AnalysisEngine:
         states = tuple(o.init() for o in ops)
         # LUTs transfer ONCE; passing host numpy would re-copy per batch
         consts = jax.device_put(tuple(op.consts or {} for op in ops))
+        # taken under the traversal span, before any scan:* span opens:
+        # ingest threads join this run and parent to the traversal
+        binding = get_telemetry().thread_binding()
 
         sharding = self._resolve_placement(P(self.dp_axis))
         # wire diet (skipped under explicit sharding, where packed
@@ -1811,18 +1826,27 @@ class AnalysisEngine:
                         w = wires_by_version[v] = build_wire(batch_size)
             return w
 
-        def pack_versioned(b):
-            """Ingest-thread pack under the CURRENT codec table. A
-            CodecViolation means a narrowing bet lost — widen the
-            table, rebuild the wire, re-pack the SAME batch. It must
-            never escape into resilient_batches, which would misread a
-            perfectly good batch as corruption and quarantine it."""
-            while True:
-                w = wire_for_current()
-                try:
-                    return w, w["pack"](b)
-                except wire_mod.CodecViolation as violation:
-                    codec_table.widen(violation.key, violation.required)
+        def pack_versioned(b, stats: Optional[IngestPoolStats] = None):
+            """Ingest-thread pack under the CURRENT codec table, as the
+            span ``ingest:encode`` (its wall is the pool's
+            ``encode_s``). A CodecViolation means a narrowing bet lost
+            — widen the table, rebuild the wire, re-pack the SAME
+            batch. It must never escape into resilient_batches, which
+            would misread a perfectly good batch as corruption and
+            quarantine it."""
+            with get_telemetry().span("ingest:encode") as sp:
+                while True:
+                    w = wire_for_current()
+                    try:
+                        packed = w, w["pack"](b)
+                        break
+                    except wire_mod.CodecViolation as violation:
+                        codec_table.widen(
+                            violation.key, violation.required
+                        )
+            if stats is not None:
+                stats.add("encode_s", sp.wall_s)
+            return packed
 
         # the jitted update and its lifetime trace counter live together
         # in a _PlanEntry (exactly like the resident path): a cache-hit
@@ -1874,12 +1898,15 @@ class AnalysisEngine:
         def fold_fetched(fetched: Dict[int, List[Any]]) -> None:
             """Fold already-FETCHED per-batch outputs into the host
             accumulators (the one copy of the fold loop)."""
-            for i in host_slots:
-                acc = host_accs[i]
-                for out in fetched[i]:
-                    acc = ops[i].host_fold(acc, out)
-                host_accs[i] = acc
-                pending[i] = []
+            if not host_slots:
+                return
+            with get_telemetry().span("scan:fold"):
+                for i in host_slots:
+                    acc = host_accs[i]
+                    for out in fetched[i]:
+                        acc = ops[i].host_fold(acc, out)
+                    host_accs[i] = acc
+                    pending[i] = []
 
         # resilience: retry/quarantine at BATCH granularity with
         # checkpoint/resume at absolute batch boundaries — see
@@ -2006,6 +2033,7 @@ class AnalysisEngine:
             return _prefetched(
                 ((pack_versioned(b), b) for b in src),
                 supervisor=supervisor,
+                binding=binding,
             )
 
         def make_pool_iter(i: int):
@@ -2020,14 +2048,11 @@ class AnalysisEngine:
                 # numpy reprs (+ per-batch uniques for delta columns),
                 # then the wire pack when the batch needs no ordered
                 # commit (no dictionary-coded columns to sequence)
-                t0 = time.monotonic()
-                decoded = item.decode()
-                t1 = time.monotonic()
-                stats.add("decode_s", t1 - t0)
+                with get_telemetry().span("ingest:decode") as sp:
+                    decoded = item.decode()
+                stats.add("decode_s", sp.wall_s)
                 if item.complete:
-                    packed = pack_versioned(decoded)
-                    stats.add("encode_s", time.monotonic() - t1)
-                    return packed, decoded
+                    return pack_versioned(decoded, stats), decoded
                 return None, decoded
 
             def commit(result, item):
@@ -2045,9 +2070,7 @@ class AnalysisEngine:
                     packed is None
                     or packed[0]["version"] != codec_table.version
                 ):
-                    t0 = time.monotonic()
-                    packed = pack_versioned(raw)
-                    stats.add("encode_s", time.monotonic() - t0)
+                    packed = pack_versioned(raw, stats)
                 return packed, raw
 
             def sizer(result) -> int:
@@ -2069,6 +2092,7 @@ class AnalysisEngine:
                 supervisor=supervisor,
                 stats=stats,
                 sizer=sizer,
+                binding=binding,
             )
 
         validate = None
@@ -2092,6 +2116,9 @@ class AnalysisEngine:
         from deequ_tpu.data.table import add_transfer_bytes
 
         clock = PhaseClock("streaming")
+        clock.trace_counter = lambda: sum(
+            e.traces for e, _ in entries_used
+        )
         self.phase_times = clock.times
 
         # memory pressure (engine/memory.py, docs/RESILIENCE.md): an
@@ -2157,18 +2184,20 @@ class AnalysisEngine:
                         for k, v in raw.items()
                         if not k.startswith(DICT_DELTA_PREFIX)
                     }
-                    while True:
-                        try:
-                            sub_pack, sub_fused = sub_plan(w)
-                            sub = sub_pack(sliced)
-                            break
-                        except wire_mod.CodecViolation as violation:
-                            # a sub-slice can trip a codec guard just
-                            # like a full batch: widen + rebuild, same
-                            # contract as pack_versioned
-                            codec_table.widen(
-                                violation.key, violation.required
-                            )
+                    with get_telemetry().span("ingest:encode"):
+                        while True:
+                            try:
+                                sub_pack, sub_fused = sub_plan(w)
+                                sub = sub_pack(sliced)
+                                break
+                            except wire_mod.CodecViolation as violation:
+                                # a sub-slice can trip a codec guard
+                                # just like a full batch: widen +
+                                # rebuild, same contract as
+                                # pack_versioned
+                                codec_table.widen(
+                                    violation.key, violation.required
+                                )
                     add_transfer_bytes(
                         sum(
                             np.asarray(v).nbytes  # sync-ok: host batch

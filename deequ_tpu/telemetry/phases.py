@@ -12,6 +12,12 @@ FIRST step's trace+compile is split out as ``first_step_s`` so a cold
 run doesn't read as dispatch overhead); ``sync_s`` — blocked on the
 device queue draining.
 
+With telemetry on, every timed interval is a live span on the scan
+thread (``scan:wait``, ``scan:put``, ``scan:dispatch``,
+``scan:first_step``, ``scan:sync``) and the bucket adds that span's
+wall, so the ``scan_phases`` event and the spans are the same
+intervals. With telemetry off the clock times on its own.
+
 Attribution caveat (measured, docs/PERF.md): when the host->device link
 saturates, backpressure and GIL contention smear waiting across
 buckets — the SUM (~= wall) and bytes_shipped/wall are the robust
@@ -21,29 +27,42 @@ signals; individual buckets are indicative.
 from __future__ import annotations
 
 import contextlib
-import time
-from typing import Dict, Iterator
+from typing import Callable, Dict, Iterator, Optional
+
+from deequ_tpu.telemetry.spans import clock
+
+SPAN_OF_BUCKET = {
+    "host_wait_s": "scan:wait",
+    "put_s": "scan:put",
+    "dispatch_s": "scan:dispatch",
+    "first_step_s": "scan:first_step",
+    "sync_s": "scan:sync",
+}
 
 
 class PhaseClock:
     def __init__(self, mode: str):
+        from deequ_tpu.telemetry.runtime import get_telemetry
+
         self.times: Dict[str, object] = {
             "host_wait_s": 0.0, "put_s": 0.0, "dispatch_s": 0.0,
             "first_step_s": 0.0, "sync_s": 0.0, "mode": mode,
         }
         self._steps = 0
+        self._tm = get_telemetry()
+        # the scan's lifetime trace count, read around the first step so
+        # its span says whether it traced (and compiled or loaded) anew
+        self.trace_counter: Optional[Callable[[], int]] = None
 
     def timed_iter(self, iterator) -> Iterator:
         """Yield from ``iterator``, accumulating time blocked in its
         ``__next__`` into host_wait_s (keeps the caller a for-loop)."""
         it = iter(iterator)
         while True:
-            t0 = time.perf_counter()
-            try:
-                item = next(it)
-            except StopIteration:
+            with self.phase("host_wait_s"):
+                item = next(it, _DONE)
+            if item is _DONE:
                 return
-            self.times["host_wait_s"] += time.perf_counter() - t0
             yield item
 
     @contextlib.contextmanager
@@ -52,8 +71,26 @@ class PhaseClock:
             self._steps += 1
             if self._steps == 1:
                 key = "first_step_s"
-        t0 = time.perf_counter()
+        if not self._tm.enabled:
+            t0 = clock()
+            try:
+                yield
+            finally:
+                self.times[key] += clock() - t0
+            return
+        counter = self.trace_counter if key == "first_step_s" else None
+        before = counter() if counter is not None else 0
+        sp = None
         try:
-            yield
+            with self._tm.span(SPAN_OF_BUCKET[key]) as sp:
+                try:
+                    yield
+                finally:
+                    if counter is not None:
+                        sp.set(retraces=counter() - before)
         finally:
-            self.times[key] += time.perf_counter() - t0
+            if sp is not None:
+                self.times[key] += sp.wall_s
+
+
+_DONE = object()

@@ -44,6 +44,11 @@ from deequ_tpu.telemetry.spans import (
 
 _NOOP_SCOPE = contextlib.nullcontext(None)
 
+
+def _noop_binding():
+    return _NOOP_SCOPE
+
+
 _run_ids = itertools.count(1)
 _UNSET = object()
 
@@ -241,6 +246,43 @@ class Telemetry:
         return self.tracer.span(
             name, on_finish=self._on_span_finish, **attributes
         )
+
+    @contextlib.contextmanager
+    def traversal(
+        self, kind: str, rows: int = 0, columns: int = 0
+    ) -> Iterator[Any]:
+        """One full traversal of a source: bumps ``engine.data_passes``
+        (always on, like every counter) and opens the span
+        ``traverse:<kind>`` around the work."""
+        self.metrics.counter("engine.data_passes").inc()
+        with self.span(
+            f"traverse:{kind}", rows=int(rows), columns=int(columns)
+        ) as sp:
+            yield sp
+
+    def thread_binding(self):
+        """Snapshot this thread's run captures, open span and ambient
+        trace; returns a factory of context managers that bind them onto
+        another thread. A worker that runs under ``binding()`` emits
+        spans and events into the same run captures, parented to the
+        span that was open here, with the same trace identity."""
+        if not self.enabled:
+            return _noop_binding
+        captures = list(self._captures())
+        parent = self.tracer.current()
+        trace = self.tracer.current_trace()
+
+        @contextlib.contextmanager
+        def binding() -> Iterator[None]:
+            prev = getattr(self._local, "captures", None)
+            self._local.captures = list(captures)
+            try:
+                with self.tracer.adopt(parent, trace):
+                    yield
+            finally:
+                self._local.captures = prev
+
+        return binding
 
     def _on_span_finish(self, sp: Span) -> None:
         self._ingest_record(sp.as_record())

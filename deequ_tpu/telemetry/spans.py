@@ -174,6 +174,23 @@ class Tracer:
         return getattr(self._local, "trace", None)
 
     @contextlib.contextmanager
+    def adopt(
+        self, parent: Optional[Span], ctx: Optional[TraceContext]
+    ) -> Iterator[None]:
+        """Run this thread under another thread's open ``parent`` span
+        and ambient trace: spans started here parent to ``parent`` and
+        carry its trace identity (worker threads of a scan)."""
+        prev_stack = getattr(self._local, "stack", None)
+        prev_trace = getattr(self._local, "trace", None)
+        self._local.stack = [parent] if parent is not None else []
+        self._local.trace = ctx
+        try:
+            yield
+        finally:
+            self._local.stack = prev_stack
+            self._local.trace = prev_trace
+
+    @contextlib.contextmanager
     def trace_scope(self, ctx: Optional[TraceContext]) -> Iterator[None]:
         """Make ``ctx`` the ambient trace on this thread: spans started
         with an empty stack parent to ``ctx.span_id`` and every span

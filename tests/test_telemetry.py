@@ -807,11 +807,12 @@ class TestTracingZeroCost:
         assert tm.current_trace() is None
 
     def test_untraced_run_emits_no_trace_spans(self):
-        """Without an ambient TraceContext the engine emits exactly the
-        classic span set — no phase/persist/egress spans, no trace_id
-        tagging — so tracing-off costs nothing beyond PhaseClock."""
+        """Without an ambient TraceContext the engine emits its live
+        spans (the traversal and its ``scan:*`` stages, whose walls are
+        the ``scan_phases`` buckets) and no synthetic ones — no
+        phase/persist/egress spans, no trace_id tagging."""
         with _SpanSink() as records:
-            AnalysisRunner.do_analysis_run(
+            ctx = AnalysisRunner.do_analysis_run(
                 df_numeric(), [Size(), Mean("att1")]
             )
         assert records
@@ -819,6 +820,14 @@ class TestTracingZeroCost:
         assert not any(n.startswith("phase:") for n in names)
         assert "persist" not in names and "egress" not in names
         assert all(r.get("trace_id") is None for r in records)
+        assert {"traverse:fused", "scan:first_step", "scan:sync"} <= names
+        (phases,) = [
+            e for e in ctx.run_metadata.events
+            if e.get("event") == "scan_phases"
+        ]
+        first = [r["wall_s"] for r in records
+                 if r["name"] == "scan:first_step"]
+        assert phases["first_step_s"] == pytest.approx(sum(first), abs=1e-5)
 
 
 class TestTraceReportTool:

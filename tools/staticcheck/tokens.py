@@ -10,7 +10,9 @@ crashing (the old scanner caught the nonexistent
 ``tokenize.TokenizeError`` — an AttributeError on first contact).
 
 - ``telemetry-timing``: own-clock/own-trace NAME tokens outside
-  ``deequ_tpu/telemetry/`` (docs/OBSERVABILITY.md).
+  ``deequ_tpu/telemetry/`` (docs/OBSERVABILITY.md); ``monotonic``
+  too, except in ``deequ_tpu/service/``, where ``service-time``
+  already bans it.
 - ``oom-taxonomy``: ad-hoc OOM classification (``MemoryError`` NAMEs,
   allocator marker strings) outside ``engine/memory.py``.
 - ``sync-discipline``: ``device_get``/``asarray`` in ``engine/``
@@ -43,6 +45,9 @@ HOT_PATH_DIRS = (
 FORBIDDEN_NAMES = frozenset(
     {"perf_counter", "start_trace", "stop_trace", "TraceAnnotation"}
 )
+# a second clock is as much an own clock as perf_counter; the service
+# layer's own rule (service-time) covers it there
+FORBIDDEN_CLOCK_NAMES = frozenset({"monotonic"})
 EXEMPT_PREFIX = "deequ_tpu/telemetry/"
 
 FORBIDDEN_OOM_NAMES = frozenset({"MemoryError"})
@@ -146,8 +151,13 @@ class TokenDisciplineAnalyzer(Analyzer):
             sync_checked = sf.rel.startswith(
                 SYNC_HOT_PREFIX
             ) and sf.rel not in SYNC_EXEMPT_FILES
+            timing_names = (
+                FORBIDDEN_NAMES
+                if sf.rel.startswith(SERVICE_PREFIX)
+                else FORBIDDEN_NAMES | FORBIDDEN_CLOCK_NAMES
+            )
             for tok in sf.tokens:
-                if tok.type == tokenize.NAME and tok.string in FORBIDDEN_NAMES:
+                if tok.type == tokenize.NAME and tok.string in timing_names:
                     yield Finding(
                         rule="telemetry-timing",
                         path=sf.rel,

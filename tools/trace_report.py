@@ -52,8 +52,7 @@ _STAGE_BY_NAME = {
     "queue_wait": "queue_wait",
     "coalesce_window": "coalesce_window",
     "lease_wait": "lease_wait",
-    "phase:compile": "compile",
-    "phase:scan": "scan",
+    "scan:first_step": "compile",
     "egress": "egress",
     "persist": "persist",
     "ticket": "finalize",
@@ -65,7 +64,7 @@ def _stage_for(name: str, parent_stage: str) -> str:
     stage = _STAGE_BY_NAME.get(name)
     if stage is not None:
         return stage
-    if name.startswith("pass:") or name.startswith("phase:"):
+    if name.startswith(("pass:", "traverse:")):
         return "scan"
     if name.startswith("run:"):
         return "finalize"
