@@ -506,20 +506,24 @@ def phase_stream(table, rows: int, out_dir: str, shards: int = STREAM_SHARDS):
 
 
 def phase_pallas(ds, ref: Reference) -> None:
-    """HLL registers through the Pallas scatter kernel equal the XLA
-    scatter's (``config.pallas_scatter``): on a TPU the kernel runs or
-    the run fails, never a silent fallback."""
+    """HLL registers through the Pallas scatter kernel equal the default
+    build's (``config.pallas_scatter``; the default is the MXU build on
+    a TPU, the XLA scatter elsewhere): on a TPU the kernel runs or the
+    run fails, never a silent fallback."""
+    import jax
+
     from deequ_tpu import config
     from deequ_tpu.analyzers import AnalysisRunner, ApproxCountDistinct
-    from deequ_tpu.sketches import pallas_scatter
+    from deequ_tpu.sketches import hll
 
     analyzers = [ApproxCountDistinct(c) for c in ("k1", "ext1", "c1", "m1")]
+    default = "mxu" if jax.default_backend() == "tpu" else "xla"
     runs = {}
     for flag in (False, True):
         with config.configure(pallas_scatter=flag):
             runs[flag] = AnalysisRunner.do_analysis_run(ds, analyzers)
-            token = pallas_scatter.impl_token()
-        if token != ("pallas" if flag else "xla"):
+            token = hll.register_build_path()
+        if token != ("pallas" if flag else default):
             raise Mismatch(f"pallas: pallas_scatter={flag} ran {token}")
     for a in analyzers:
         got = runs[True].metric(a).value.get()

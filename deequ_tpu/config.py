@@ -85,7 +85,8 @@ class Options:
     # route the HLL register scatter-max through the measured unroll-16
     # Pallas SMEM kernel (tools/scatter_probe.py: 1.1-1.15x over the XLA
     # scatter at (2^21, M=2^14)) on a TPU, where a kernel that fails
-    # to compile raises; off the TPU the XLA scatter runs. Registers
+    # to compile raises; off, a TPU builds them on the MXU
+    # (sketches/hll_mxu.py) and other backends scatter on XLA. Registers
     # are bit-identical either way (tests/test_fastpath_differential.py,
     # and on the chip chip_smoke.py's pallas phase). Off by default until
     # the production-shape probe artifact justifies flipping it

@@ -79,6 +79,7 @@ from deequ_tpu.engine.resilience import (
     resilient_batches,
     retry_transient,
 )
+from deequ_tpu.sketches import hll
 from deequ_tpu.telemetry import PhaseClock, get_telemetry
 
 DEFAULT_MAX_BATCH = 1 << 21  # 2M rows per fused-scan step (measured
@@ -188,15 +189,13 @@ def _plan_cache_key(ops, host_slots, placement):
             return None
         tokens.append(op.cache_token)
     # static engine decisions baked into a trace ride the key too: a
-    # plan traced with the Pallas register scatter must not be served
-    # to a run with the flag (or the backend probe) flipped
-    from deequ_tpu.sketches import pallas_scatter
-
+    # plan traced with one HLL register build must not be served to a
+    # run with the flag (or the backend probe) flipped
     key = (
         tuple(tokens),
         tuple(host_slots),
         _placement_shape(placement),
-        pallas_scatter.impl_token(),
+        hll.register_build_path(),
     )
     try:
         hash(key)
@@ -761,7 +760,6 @@ def coalesce_key_surface() -> tuple:
     between submissions can never smuggle two differently-planned runs
     into one superset scan."""
     from deequ_tpu import config
-    from deequ_tpu.sketches import pallas_scatter
 
     opts = config.options()
     return (
@@ -769,7 +767,7 @@ def coalesce_key_surface() -> tuple:
         opts.batch_size,
         opts.device_cache_bytes,
         opts.wire_codecs,
-        pallas_scatter.impl_token(),
+        hll.register_build_path(),
     )
 
 
@@ -1247,6 +1245,8 @@ class AnalysisEngine:
 
         self.trace_count = 0
 
+        mesh, axis = self.mesh, self.dp_axis
+
         def make_entry(entry: _PlanEntry) -> None:
             def scan_chunk(state_tuple, stacked, consts_tuple):
                 def body(carry, batch):
@@ -1258,7 +1258,8 @@ class AnalysisEngine:
                     ys = tuple(new[i] for i in host_slots)
                     return new, ys
 
-                return jax.lax.scan(body, state_tuple, stacked)
+                with hll.rows_sharded_over(mesh, axis):
+                    return jax.lax.scan(body, state_tuple, stacked)
 
             # state buffers round-trip between chunk calls; donating the
             # carry would corrupt host-folded ys that alias it (see the
@@ -1373,6 +1374,7 @@ class AnalysisEngine:
 
         clock = PhaseClock("resident")
         clock.trace_counter = lambda: entry.traces
+        clock.first_step_attrs["hll_path"] = hll.register_build_path()
         self.phase_times = clock.times
 
         # memory pressure (engine/memory.py, docs/RESILIENCE.md): a
@@ -1858,14 +1860,17 @@ class AnalysisEngine:
         settle = _step_settler(sharding)
         entries_used: List[Tuple[_PlanEntry, int]] = []
 
+        mesh, axis = self.mesh, self.dp_axis
+
         def install_fused(new_entry: _PlanEntry, unpack) -> None:
             def fused_update(state_tuple, batch, consts_tuple):
                 new_entry.traces += 1
                 batch = unpack(batch)
-                return tuple(
-                    op.apply_update(s, batch, c)
-                    for op, s, c in zip(ops, state_tuple, consts_tuple)
-                )
+                with hll.rows_sharded_over(mesh, axis):
+                    return tuple(
+                        op.apply_update(s, batch, c)
+                        for op, s, c in zip(ops, state_tuple, consts_tuple)
+                    )
 
             # host-folded outputs are retained across iterations, so
             # their buffers must not be donated back into the next
@@ -2119,6 +2124,7 @@ class AnalysisEngine:
         clock.trace_counter = lambda: sum(
             e.traces for e, _ in entries_used
         )
+        clock.first_step_attrs["hll_path"] = hll.register_build_path()
         self.phase_times = clock.times
 
         # memory pressure (engine/memory.py, docs/RESILIENCE.md): an
@@ -2149,10 +2155,13 @@ class AnalysisEngine:
 
                 def sub_update(state_tuple, batch, consts_tuple):
                     batch = sub_unpack(batch)
-                    return tuple(
-                        op.apply_update(s, batch, c)
-                        for op, s, c in zip(ops, state_tuple, consts_tuple)
-                    )
+                    with hll.rows_sharded_over(mesh, axis):
+                        return tuple(
+                            op.apply_update(s, batch, c)
+                            for op, s, c in zip(
+                                ops, state_tuple, consts_tuple
+                            )
+                        )
 
                 plan = (
                     sub_pack,

@@ -4,8 +4,10 @@ Reference: ``analyzers/catalyst/StatefulHyperloglogPlus`` (SURVEY.md
 §2.3): HLL++ registers as packed words updated per row inside Tungsten;
 merge = word-wise max. TPU design (per SURVEY's table): registers are an
 int32[m] device vector; the per-batch update is hash -> leading-zero
-count -> scatter-max; the merge is an elementwise max (a ``lax.max``
-all-reduce across the mesh / across persisted states).
+count -> register max (a scatter-max; on a TPU a banded one-hot
+matmul on the MXU, sketches/hll_mxu.py); the merge is an elementwise
+max (a ``lax.max`` all-reduce across the mesh / across persisted
+states).
 
 Hashing is built from 32-bit lanes ONLY — the TPU has no native 64-bit
 integer path (XLA's x64 rewriter refuses u64 bitcasts), and 32-bit
@@ -24,6 +26,8 @@ murmur-style mixing maps perfectly onto the VPU:
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import hashlib
 from typing import Tuple
 
@@ -117,55 +121,114 @@ def _index_and_rank(h1, h2, mask):
 
 
 REGISTER_DTYPE = jnp.int8  # rho <= 33 fits i8: 4x fewer wire bytes than
-# i32 when states cross to the host (the scatter itself runs in i32 —
-# narrow scatters lower poorly — and the result narrows after)
+# i32 when states cross to the host (every build runs in i32 — narrow
+# scatters lower poorly — and the result narrows after)
+
+
+def register_build_path() -> str:
+    """Which register build a freshly traced plan bakes in: ``pallas``
+    (the SMEM scatter kernel, when ``config.pallas_scatter`` is set and
+    can run), ``mxu`` (the banded one-hot matmul, the TPU's default) or
+    ``xla`` (the scatter-max, every other backend). Rides the engine
+    plan-cache key so a flag flip or another backend retraces instead
+    of aliasing a stale compile. All three give the same registers."""
+    from deequ_tpu.sketches import hll_mxu, pallas_scatter
+
+    if pallas_scatter.enabled():
+        return "pallas"
+    return "mxu" if hll_mxu.available() else "xla"
+
+
+# (mesh, axis) of the mesh whose rows a plan is being traced for: XLA
+# cannot partition a Mosaic kernel, so on a mesh each device builds the
+# registers of its own rows and a max all-reduce merges them (the engine
+# sets it around its row-sharded updates; None off a mesh)
+_ROW_MESH = contextvars.ContextVar("hll_row_mesh", default=None)
+
+
+@contextlib.contextmanager
+def rows_sharded_over(mesh, axis: str):
+    """Trace register builds inside as row-sharded over ``mesh``'s
+    ``axis`` (``mesh`` None: one device)."""
+    token = _ROW_MESH.set(None if mesh is None else (mesh, axis))
+    try:
+        yield
+    finally:
+        _ROW_MESH.reset(token)
+
+
+def _per_device(kernel, idx, rho):
+    """Run a kernel build per device over the rows of the mesh the plan
+    is traced for, merged with a max; B pads with (0, 0) no-op rows to
+    a multiple of the axis size."""
+    from jax.sharding import PartitionSpec
+
+    mesh, axis = _ROW_MESH.get()
+    n = mesh.shape[axis]
+    pad = -idx.shape[1] % n
+    if pad:
+        idx = jnp.pad(idx, ((0, 0), (0, pad)))
+        rho = jnp.pad(rho, ((0, 0), (0, pad)))
+    rows = PartitionSpec(None, axis)
+    return jax.shard_map(
+        lambda i, r: jax.lax.pmax(kernel(i, r), axis),
+        mesh=mesh.abstract_mesh,
+        in_specs=(rows, rows),
+        out_specs=PartitionSpec(),
+        check_vma=False,
+    )(idx, rho)
+
+
+def _build_registers(idx: jnp.ndarray, rho: jnp.ndarray) -> jnp.ndarray:
+    """(C, B) register indices and ranks -> (C, M) int8 registers, the
+    max rank per register, through the build this backend runs."""
+    from deequ_tpu.sketches import hll_mxu, pallas_scatter
+    from deequ_tpu.telemetry import get_telemetry
+
+    path = register_build_path()
+    get_telemetry().metrics.counter(f"hll.register_builds.{path}").inc()
+    if path != "xla":
+        kernel = (
+            (lambda i, r: pallas_scatter.scatter_max(i, r, M))
+            if path == "pallas"
+            else (lambda i, r: hll_mxu.registers(i, r, M))
+        )
+        regs = (
+            kernel(idx, rho)
+            if _ROW_MESH.get() is None
+            else _per_device(kernel, idx, rho)
+        )
+    else:  # one scatter-max into a flat (C*M,) file, col*M + idx
+        n_cols = idx.shape[0]
+        col_ids = jax.lax.broadcasted_iota(jnp.int32, idx.shape, 0)
+        regs = (
+            jnp.zeros(n_cols * M, dtype=jnp.int32)
+            .at[(col_ids * M + idx).ravel()]
+            .max(rho.ravel())
+            .reshape(n_cols, M)
+        )
+    return regs.astype(REGISTER_DTYPE)
 
 
 def registers_from_hash_pair(
     h1: jnp.ndarray, h2: jnp.ndarray, mask: jnp.ndarray
 ) -> jnp.ndarray:
-    """One batch of hash pairs -> int8[M] register vector (scatter-max).
+    """One batch of hash pairs -> int8[M] register vector.
 
     rho comes from h2's leading zeros (1..33) — supporting max register
     rank 33, ample for cardinalities far beyond 2^40."""
     idx, rho = _index_and_rank(h1, h2, mask)
-    from deequ_tpu.sketches import pallas_scatter
-
-    pallas = pallas_scatter.scatter_max(idx[None, :], rho[None, :], M)
-    if pallas is not None:
-        return pallas[0].astype(REGISTER_DTYPE)
-    return (
-        jnp.zeros(M, dtype=jnp.int32)
-        .at[idx]
-        .max(rho)
-        .astype(REGISTER_DTYPE)
-    )
+    return _build_registers(idx[None, :], rho[None, :])[0]
 
 
 def registers_from_hash_pair_stacked(
     h1: jnp.ndarray, h2: jnp.ndarray, mask: jnp.ndarray
 ) -> jnp.ndarray:
-    """Column-stacked variant: (C, B) hash pairs -> (C, M) registers via
-    ONE scatter-max into a flat (C*M,) vector (per-column register
-    blocks indexed by col*M + idx). Behind ``config.pallas_scatter``
-    the unroll-16 SMEM kernel takes over with a (C, G) grid (a flat
-    C*M register file exceeds SMEM) — bit-identical either way."""
+    """Column-stacked variant: (C, B) hash pairs -> (C, M) registers in
+    ONE build for the whole group (a (C, G) grid on the kernels, one
+    flat scatter on XLA) — bit-identical either way."""
     idx, rho = _index_and_rank(h1, h2, mask)
-    from deequ_tpu.sketches import pallas_scatter
-
-    pallas = pallas_scatter.scatter_max(idx, rho, M)
-    if pallas is not None:
-        return pallas.astype(REGISTER_DTYPE)
-    n_cols = idx.shape[0]
-    col_ids = jax.lax.broadcasted_iota(jnp.int32, idx.shape, 0)
-    flat = (col_ids * M + idx).ravel()
-    return (
-        jnp.zeros(n_cols * M, dtype=jnp.int32)
-        .at[flat]
-        .max(rho.ravel())
-        .reshape(n_cols, M)
-        .astype(REGISTER_DTYPE)
-    )
+    return _build_registers(idx, rho)
 
 
 # dict sizes up to this use the presence path (measured on v5e: the

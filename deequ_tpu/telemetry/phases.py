@@ -16,7 +16,8 @@ With telemetry on, every timed interval is a live span on the scan
 thread (``scan:wait``, ``scan:put``, ``scan:dispatch``,
 ``scan:first_step``, ``scan:sync``) and the bucket adds that span's
 wall, so the ``scan_phases`` event and the spans are the same
-intervals. With telemetry off the clock times on its own.
+intervals (the first step's span also carries ``retraces`` and
+``hll_path``). With telemetry off the clock times on its own.
 
 Attribution caveat (measured, docs/PERF.md): when the host->device link
 saturates, backpressure and GIL contention smear waiting across
@@ -53,6 +54,9 @@ class PhaseClock:
         # the scan's lifetime trace count, read around the first step so
         # its span says whether it traced (and compiled or loaded) anew
         self.trace_counter: Optional[Callable[[], int]] = None
+        # further attributes of the first step's span: what its plan
+        # baked in (the engine sets ``hll_path``)
+        self.first_step_attrs: Dict[str, object] = {}
 
     def timed_iter(self, iterator) -> Iterator:
         """Yield from ``iterator``, accumulating time blocked in its
@@ -86,6 +90,8 @@ class PhaseClock:
                 try:
                     yield
                 finally:
+                    if key == "first_step_s":
+                        sp.set(**self.first_step_attrs)
                     if counter is not None:
                         sp.set(retraces=counter() - before)
         finally:

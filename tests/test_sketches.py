@@ -783,3 +783,149 @@ class TestPooledPathEdges:
         )
         # where g=1 keeps values {1.0, 2.0}
         assert ctx.metric(a).value.get() == pytest.approx(2.0, rel=0.01)
+
+
+def _mxu_case(case):
+    """(idx, rho) i32 (C, B) inputs for the MXU register-build
+    differential, one named edge each."""
+    from deequ_tpu.sketches import hll
+
+    rng = np.random.default_rng(sum(map(ord, case)))
+
+    def hashed(cols, rows, valid=0.9):
+        h1, h2 = (
+            rng.integers(0, 1 << 32, (cols, rows), dtype=np.uint64)
+            .astype(np.uint32)
+            for _ in range(2)
+        )
+        idx, rho = hll._index_and_rank(h1, h2, rng.random((cols, rows)) < valid)
+        return np.asarray(idx), np.asarray(rho)
+
+    if case == "random":
+        return hashed(3, 8192)
+    if case == "one_register_at_band_tops":
+        # a whole tile in ONE register at each band's top rank (and the
+        # largest rank): the sum reaches 2^12 * 2^104, the bound itself
+        rho = np.repeat(np.array([[9], [18], [27], [33]], np.int32), 4096, 1)
+        return np.full_like(rho, 12345), rho
+    if case == "band_edges":
+        edges = np.array([9, 10, 18, 19, 27, 28, 33], np.int32)
+        rho = edges[rng.integers(0, len(edges), (2, 6000))]
+        idx = rng.integers(0, 24, (2, 6000)).astype(np.int32) * 700
+        return idx, rho
+    if case == "masked":
+        return hashed(2, 4096, valid=0.3)
+    if case == "ragged_rows":
+        return hashed(2, 5000)
+    if case == "one_column":
+        return hashed(1, 4096)
+    if case == "seventeen_columns":
+        return hashed(17, 2048)
+    if case == "dictionary_d_plus_3":
+        return hashed(1, hll.DEDUP_DICT_CAP + 3)
+    if case == "no_rows":
+        return hashed(2, 0)
+    raise ValueError(case)
+
+
+MXU_CASES = [
+    "random", "one_register_at_band_tops", "band_edges", "masked",
+    "ragged_rows", "one_column", "seventeen_columns", "dictionary_d_plus_3",
+    "no_rows",
+]
+
+
+class TestMXURegisters:
+    """The banded one-hot matmul build (sketches/hll_mxu.py) against
+    the XLA scatter-max, bit for bit: as the plain-jnp reference of the
+    math and as the Pallas kernel through the interpreter. On the chip
+    the kernel's probe repeats the band-top case against a numpy
+    scatter-max."""
+
+    @pytest.mark.parametrize("form", ["reference", "kernel"])
+    @pytest.mark.parametrize("case", MXU_CASES)
+    def test_bit_identical_to_scatter(self, case, form, monkeypatch):
+        from deequ_tpu.sketches import hll, hll_mxu
+
+        idx, rho = _mxu_case(case)
+        assert hll.register_build_path() == "xla"  # the CPU's build
+        want = np.asarray(hll._build_registers(idx, rho))
+        if form == "kernel":
+            monkeypatch.setenv("DEEQU_TPU_PALLAS_INTERPRET", "1")
+            got = hll_mxu.registers(idx, rho, hll.M)
+        else:
+            got = hll_mxu.reference_registers(idx, rho, hll.M)
+        got = np.asarray(got)
+        assert got.shape == want.shape == (idx.shape[0], hll.M)
+        np.testing.assert_array_equal(got.astype(np.int8), want)
+        assert want.max() == rho.max(initial=0)
+
+    def _force_mxu(self, monkeypatch):
+        from deequ_tpu.sketches import hll_mxu
+
+        monkeypatch.setenv("DEEQU_TPU_PALLAS_INTERPRET", "1")
+        monkeypatch.setattr(hll_mxu, "available", lambda: True)
+
+    def test_dispatch_counts_the_build(self, monkeypatch):
+        """With the MXU build available, both public builders take it,
+        count it under ``hll.register_builds.mxu`` and still equal the
+        scatter."""
+        from deequ_tpu.sketches import hll
+        from deequ_tpu.telemetry import get_telemetry
+
+        h1, h2, mask = (
+            np.random.default_rng(5).integers(0, 1 << 32, (3, 2, 3000))
+            .astype(np.uint32)
+        )
+        mask = mask % 4 != 0
+        want = np.asarray(hll.registers_from_hash_pair_stacked(h1, h2, mask))
+        want_one = np.asarray(
+            hll.registers_from_hash_pair(h1[0], h2[0], mask[0])
+        )
+        self._force_mxu(monkeypatch)
+        counter = get_telemetry().metrics.counter("hll.register_builds.mxu")
+        before = counter.value
+        assert hll.register_build_path() == "mxu"
+        got = np.asarray(hll.registers_from_hash_pair_stacked(h1, h2, mask))
+        got_one = np.asarray(
+            hll.registers_from_hash_pair(h1[0], h2[0], mask[0])
+        )
+        assert counter.value == before + 2
+        assert got.dtype == want.dtype == hll.REGISTER_DTYPE
+        np.testing.assert_array_equal(got, want)
+        np.testing.assert_array_equal(got_one, want_one)
+
+    @pytest.mark.parametrize("mesh", [False, True])
+    def test_engine_metrics_equal(self, mesh, monkeypatch, cpu_mesh):
+        """A profile-like run through the engine, resident and on the
+        8-device mesh (each device builds its rows' registers, a max
+        all-reduce merges them): the same counts as the scatter."""
+        from deequ_tpu import config
+
+        rng = np.random.default_rng(6)
+        data = {
+            "id": rng.integers(0, 1 << 40, 6000),
+            "price": rng.integers(0, 100_000, 6000).astype(np.int32),
+            "x": rng.normal(size=6000),
+        }
+        analyzers = [ApproxCountDistinct(c) for c in data] + [
+            ApproxQuantile("price", 0.5)
+        ]
+        engine = AnalysisEngine(mesh=cpu_mesh) if mesh else None
+
+        def run():
+            with config.configure(batch_size=2048):
+                ctx = AnalysisRunner.do_analysis_run(
+                    Dataset.from_pydict(data), analyzers,
+                    **({"engine": engine} if engine else {}),
+                )
+            return {a: value(ctx.metric(a)) for a in analyzers}
+
+        from deequ_tpu.telemetry import get_telemetry
+
+        want = run()
+        self._force_mxu(monkeypatch)
+        counter = get_telemetry().metrics.counter("hll.register_builds.mxu")
+        before = counter.value
+        assert run() == want
+        assert counter.value > before  # the plan was traced with the MXU

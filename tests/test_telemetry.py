@@ -830,6 +830,24 @@ class TestTracingZeroCost:
         assert phases["first_step_s"] == pytest.approx(sum(first), abs=1e-5)
 
 
+class TestFirstStepNamesItsRegisterBuild:
+    def test_hll_path_on_first_step_span(self):
+        """The first step's span names the register build its plan
+        baked in (the CPU's XLA scatter here)."""
+        from deequ_tpu.analyzers import ApproxCountDistinct
+        from deequ_tpu.sketches import hll
+
+        with _SpanSink() as records:
+            AnalysisRunner.do_analysis_run(
+                df_numeric(), [Size(), ApproxCountDistinct("att1")]
+            )
+        first = [r for r in records if r["name"] == "scan:first_step"]
+        assert first
+        assert {r["attributes"]["hll_path"] for r in first} == {
+            hll.register_build_path()
+        } == {"xla"}
+
+
 class TestTraceReportTool:
     def _span(self, trace, sid, parent, name, wall, start=0.0, **attrs):
         return {

@@ -100,6 +100,69 @@ def test_pallas_scatter(one_chip, cols, rows, m):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_fused_scan_step_mxu(one_chip, monkeypatch):
+    """The same step with the TPU's default register build: the MXU
+    kernel inside the fused program (the stacked group, the sorted-dedup
+    branches and the string dictionary)."""
+    import __graft_entry__
+    from deequ_tpu.sketches import hll_mxu
+
+    monkeypatch.setattr(hll_mxu, "available", lambda: True)
+    fn, (states, batch) = __graft_entry__.entry(ROWS)
+    compiled = (
+        jax.jit(fn)
+        .lower(_shapes(states, one_chip), _shapes(batch, one_chip))
+        .compile()
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize(
+    "cols, rows",
+    [
+        (17, ROWS),  # the profile's 17 high-cardinality columns
+        (1, (1 << 14) + 3),  # the sorted-dedup dictionary's D+3 entries
+    ],
+)
+def test_hll_mxu_kernel(one_chip, cols, rows):
+    """The banded one-hot matmul register build lowers through Mosaic."""
+    from deequ_tpu.sketches import hll, hll_mxu
+
+    x = jax.ShapeDtypeStruct((cols, rows), jnp.int32, sharding=one_chip)
+    compiled = (
+        jax.jit(
+            lambda i, r: hll_mxu._registers_call(i, r, hll.M, False)
+        )
+        .lower(x, x)
+        .compile()
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_hll_mxu_per_device_on_dp4(topo, monkeypatch):
+    """XLA cannot partition a Mosaic kernel: on a 4-chip ``dp`` mesh each
+    chip builds its own rows' registers and a max all-reduce merges
+    them, with no gather of the rows."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from deequ_tpu.sketches import hll, hll_mxu
+
+    monkeypatch.setattr(hll_mxu, "available", lambda: True)
+    mesh = Mesh(np.array(topo.devices[:4]), ("dp",))
+    x = jax.ShapeDtypeStruct(
+        (17, ROWS), jnp.int32,
+        sharding=NamedSharding(mesh, PartitionSpec(None, "dp")),
+    )
+
+    def build(idx, rho):
+        with hll.rows_sharded_over(mesh, "dp"):
+            return hll._build_registers(idx, rho)
+
+    text = jax.jit(build).lower(x, x).compile().as_text()
+    assert "tpu_custom_call" in text and "all-reduce" in text
+    assert "all-gather" not in text
+
+
 def test_spill_finalize_u64(one_chip):
     """The device-sort spill finalize for one u64 key lane at 2^21."""
     from deequ_tpu.analyzers import spill
