@@ -252,7 +252,7 @@ def make_cache_token(
     kinds = tuple(
         sorted(
             {
-                (r.column, dataset.schema.kind_of(r.column).value)
+                (r.column, dataset.kind_token(r.column))
                 for r in analyzer.device_requests(dataset)
             }
         )

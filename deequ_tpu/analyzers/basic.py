@@ -279,7 +279,9 @@ class PatternMatch(ScanShareableAnalyzer):
     analyzers/PatternMatch.scala). TPU design: the regex is evaluated
     host-side once over the column *dictionary* (small), producing a bool
     lookup table; the device pass is a gather + sum over codes — strings
-    never reach the accelerator (SURVEY.md §7 hard part #3)."""
+    never reach the accelerator (SURVEY.md §7 hard part #3). A
+    hashed-string lane column has no dictionary: each batch ships the
+    regex's bool plane instead (data/strings.py)."""
 
     column: str
     pattern: str
@@ -292,24 +294,54 @@ class PatternMatch(ScanShareableAnalyzer):
     def preconditions(self) -> List[Precondition]:
         return [has_column(self.column), is_string(self.column)]
 
+    def _value_repr(self, dataset: Dataset) -> str:
+        """Dictionary codes, or a lane column's plane of this regex."""
+        if dataset.string_lane(self.column):
+            from deequ_tpu.data.strings import match_repr
+
+            return match_repr(self.pattern)
+        return "codes"
+
     def device_requests(self, dataset: Dataset) -> List[ColumnRequest]:
         _, reqs = _compile_where(self.where, dataset)
         return [
-            ColumnRequest(self.column, "codes"),
+            ColumnRequest(self.column, self._value_repr(dataset)),
             ColumnRequest(self.column, "mask"),
         ] + reqs
 
     def make_ops(self, dataset: Dataset) -> ScanOps:
         from deequ_tpu.analyzers.base import pad_pow2
+        from deequ_tpu.data import strings
 
         where_fn, _ = _compile_where(self.where, dataset)
         col = self.column
+        if dataset.string_lane(col):
+            # a bad pattern fails this analyzer now, not the ingest
+            # worker that would build its plane mid-scan
+            re.compile(self.pattern)
+            plane = f"{col}::{self._value_repr(dataset)}"
+
+            # the hashed-string lane: each row ships its own match
+            def lane_update(
+                state: S.NumMatchesAndCount, batch
+            ) -> S.NumMatchesAndCount:
+                rows = _row_mask(batch, where_fn)
+                hits = batch[plane] & batch[f"{col}::mask"] & rows
+                return S.NumMatchesAndCount(
+                    state.num_matches + _mcount(hits),
+                    state.count + _mcount(rows),
+                )
+
+            return ScanOps(
+                S.NumMatchesAndCount.identity,
+                lane_update,
+                S.NumMatchesAndCount.merge,
+            )
         dictionary = dataset.dictionary(col)
-        prog = re.compile(self.pattern)
         table = np.zeros(max(len(dictionary), 1), dtype=bool)
-        for i, value in enumerate(dictionary):
-            if value is not None and prog.search(str(value)):
-                table[i] = True
+        table[: len(dictionary)] = strings.dictionary_matches(
+            dictionary, self.pattern
+        )
 
         # LUT enters the scan as a runtime input (pow2-padded), so the
         # compiled program is shared across datasets — see ScanOps.consts

@@ -31,7 +31,7 @@ from deequ_tpu.analyzers.base import (
 )
 from deequ_tpu.analyzers.basic import _compile_where, _row_mask
 from deequ_tpu.analyzers.states import DataTypeHistogram
-from deequ_tpu.data.table import ColumnRequest, Dataset, Kind
+from deequ_tpu.data.table import LANE_DTYPE, ColumnRequest, Dataset, Kind
 from deequ_tpu.metrics.distribution import (
     Distribution,
     DistributionValue,
@@ -84,6 +84,32 @@ def counts_from_code_presence(
     return counts.at[:, DataTypeHistogram.NULL].add(nulls)
 
 
+def dictionary_buckets(dictionary) -> np.ndarray:
+    """i32 bucket per dictionary entry (at least one slot), classified
+    in vectorized form by the rule the lane's ``dtype`` plane uses
+    (data/strings.classify), so both paths bucket a string alike."""
+    from deequ_tpu.data import strings
+
+    lut = np.zeros(max(len(dictionary), 1), dtype=np.int32)
+    if len(dictionary):
+        lut[: len(dictionary)] = strings.classify(
+            strings.dictionary_values(dictionary)
+        )
+    return lut
+
+
+def _bucket_counts(bucket, valid, rows) -> "jnp.ndarray":
+    """(6,) i64 counts of a batch's per-row buckets: invalid rows count
+    as null, padding rows not at all. i32 scatter, i64 result: int64
+    scatters are ~30x slower on TPU (emulated); batch counts fit i32."""
+    bucket = jnp.where(valid, bucket, DataTypeHistogram.NULL)
+    bucket = jnp.where(rows, bucket, 5)  # padding -> reserved
+    counts = jnp.zeros(7, dtype=jnp.int32).at[
+        bucket.astype(jnp.int32)
+    ].add(1)[:6]
+    return counts.astype(jnp.int64).at[5].set(0)
+
+
 @dataclass(frozen=True)
 class DataType(ScanShareableAnalyzer):
     """Inferred-type histogram of a column (reference: DataType.scala)."""
@@ -101,9 +127,13 @@ class DataType(ScanShareableAnalyzer):
     def device_requests(self, dataset: Dataset) -> List[ColumnRequest]:
         _, reqs = _compile_where(self.where, dataset)
         kind = dataset.schema.kind_of(self.column)
-        col_req = ColumnRequest(
-            self.column, "codes" if kind == Kind.STRING else "mask"
-        )
+        if kind != Kind.STRING:
+            value_repr = "mask"
+        elif dataset.string_lane(self.column):
+            value_repr = LANE_DTYPE
+        else:
+            value_repr = "codes"
+        col_req = ColumnRequest(self.column, value_repr)
         return [col_req, ColumnRequest(self.column, "mask")] + reqs
 
     def make_ops(self, dataset: Dataset) -> ScanOps:
@@ -115,17 +145,28 @@ class DataType(ScanShareableAnalyzer):
         # the two produce merge-compatible states, so the math must
         # stay single-sourced
 
+        if kind == Kind.STRING and dataset.string_lane(col):
+            plane = f"{col}::{LANE_DTYPE}"
+
+            # the hashed-string lane: each row ships its own bucket
+            def lane_update(
+                state: DataTypeHistogram, batch
+            ) -> DataTypeHistogram:
+                rows = _row_mask(batch, where_fn)
+                valid = batch[f"{col}::mask"] & rows
+                return DataTypeHistogram(
+                    state.counts + _bucket_counts(batch[plane], valid, rows)
+                )
+
+            return ScanOps(
+                DataTypeHistogram.identity,
+                lane_update,
+                DataTypeHistogram.merge,
+            )
         if kind == Kind.STRING:
             from deequ_tpu.analyzers.base import pad_pow2
 
-            dictionary = dataset.dictionary(col)
-            lut = np.zeros(max(len(dictionary), 1), dtype=np.int32)
-            for i, value in enumerate(dictionary):
-                lut[i] = (
-                    DataTypeHistogram.NULL
-                    if value is None
-                    else classify_string(str(value))
-                )
+            lut = dictionary_buckets(dataset.dictionary(col))
 
             # LUT as runtime input (pow2-padded): shared compiled scan
             # across datasets — see ScanOps.consts
@@ -149,16 +190,9 @@ class DataType(ScanShareableAnalyzer):
                         state.counts + counts.astype(jnp.int64)
                     )
                 bucket = table[jnp.clip(codes, 0, table.shape[0] - 1)]
-                bucket = jnp.where(valid, bucket, DataTypeHistogram.NULL)
-                bucket = jnp.where(rows, bucket, 5)  # padding -> reserved
-                # i32 scatter, i64 carry: int64 scatters are ~30x
-                # slower on TPU (emulated); batch counts fit i32
-                counts = jnp.zeros(7, dtype=jnp.int32).at[
-                    bucket.astype(jnp.int32)
-                ].add(1)[:6]
-                new = state.counts + counts.astype(jnp.int64)
-                new = new.at[5].set(0)
-                return DataTypeHistogram(new)
+                return DataTypeHistogram(
+                    state.counts + _bucket_counts(bucket, valid, rows)
+                )
 
             return ScanOps(
                 DataTypeHistogram.identity,

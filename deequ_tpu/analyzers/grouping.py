@@ -241,6 +241,7 @@ def plan_frequency_passes(
     plans: Sequence[FrequencyPlan],
     engine: Optional[AnalysisEngine] = None,
     events: Optional[List[dict]] = None,
+    keep_states: bool = False,
 ):
     """Split frequency plans into execution strategies WITHOUT running
     anything yet, so dense plans can ride the caller's shared scan:
@@ -258,7 +259,11 @@ def plan_frequency_passes(
       per-plan deferred re-scan spill (analyzers/spill.py) or the
       host Arrow fallback. Spill decisions are recorded in ``events``
       so a 100x-slower high-card pass is visible in run metadata
-      instead of silent (VERDICT r2 weak #8)."""
+      instead of silent (VERDICT r2 weak #8).
+
+    ``keep_states``: the run persists or merges its states, which must
+    then hold their values; a hashed-string lane plan, whose device
+    grouping keeps only counts, groups on the host instead."""
     from deequ_tpu import config
     from deequ_tpu.analyzers import spill as spill_mod
 
@@ -351,6 +356,22 @@ def plan_frequency_passes(
         collectors.append(spec)
 
     for plan in plans:
+        # a hashed-string lane column has no dictionary to probe: its
+        # plan sorts the row hashes on the device, or groups on the host
+        if any(_is_lane(dataset, c) for c in plan.columns):
+            if not keep_states and spill_mod.lane_spill_eligible(
+                dataset, plan
+            ):
+                make_collector(
+                    plan,
+                    lambda p=plan: spill_mod.single_collector_spec(
+                        dataset, p, engine
+                    ),
+                    make_arrow(plan),
+                )
+            else:
+                deferred[plan] = make_arrow(plan)
+            continue
         # a plan eligible for the device sort path never probes the
         # dictionary at all — no host-side distinct set is built for a
         # high-cardinality numeric key column
@@ -466,6 +487,15 @@ def plan_frequency_passes(
         else:
             deferred[plan] = make_arrow(plan)
     return dense, collectors, deferred
+
+
+def _is_lane(dataset: Dataset, column: str) -> bool:
+    from deequ_tpu.data.table import Kind
+
+    return (
+        dataset.schema.kind_of(column) == Kind.STRING
+        and dataset.string_lane(column)
+    )
 
 
 def finalize_dense_states(
@@ -602,6 +632,7 @@ def compute_many_frequencies(
     plans: Sequence[FrequencyPlan],
     engine: Optional[AnalysisEngine] = None,
     events: Optional[List[dict]] = None,
+    keep_states: bool = False,
 ) -> Dict[FrequencyPlan, FrequenciesAndNumRows]:
     """ALL dense frequency plans ride ONE fused scan (each plan is just a
     scatter-add over different codes, so K plans still cost one data
@@ -614,7 +645,7 @@ def compute_many_frequencies(
     plan_frequency_passes; this entry point runs them standalone.)"""
     engine = engine or AnalysisEngine()
     dense, collectors, deferred = plan_frequency_passes(
-        dataset, plans, engine, events
+        dataset, plans, engine, events, keep_states
     )
     results: Dict[FrequencyPlan, FrequenciesAndNumRows] = {
         plan: run() for plan, run in deferred.items()
@@ -981,6 +1012,9 @@ def run_grouping_analyzers(
             list(by_plan.keys()),
             engine,
             events=None if metadata is None else metadata.events,
+            keep_states=(
+                aggregate_with is not None or save_states_with is not None
+            ),
         )
     except Exception as exc:  # noqa: BLE001
         return {

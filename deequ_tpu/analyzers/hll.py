@@ -23,9 +23,17 @@ from deequ_tpu.analyzers.base import (
 )
 from deequ_tpu.analyzers.basic import _compile_where, _row_mask
 from deequ_tpu.analyzers.states import ApproxCountDistinctState
-from deequ_tpu.data.table import ColumnRequest, Dataset, Kind
+from deequ_tpu.data.table import LANE_HASH, ColumnRequest, Dataset, Kind
 from deequ_tpu.metrics.metric import DoubleMetric
 from deequ_tpu.sketches import hll
+
+
+def hll_value_repr(dataset: Dataset, column: str) -> str:
+    """What an HLL over ``column`` reads: numeric values, dictionary
+    codes, or a lane column's row hashes."""
+    if dataset.schema.kind_of(column) != Kind.STRING:
+        return "values"
+    return LANE_HASH if dataset.string_lane(column) else "codes"
 
 
 @dataclass(frozen=True)
@@ -42,10 +50,8 @@ class ApproxCountDistinct(ScanShareableAnalyzer):
 
     def device_requests(self, dataset: Dataset) -> List[ColumnRequest]:
         _, reqs = _compile_where(self.where, dataset)
-        kind = dataset.schema.kind_of(self.column)
-        value_repr = "codes" if kind == Kind.STRING else "values"
         return [
-            ColumnRequest(self.column, value_repr),
+            ColumnRequest(self.column, hll_value_repr(dataset, self.column)),
             ColumnRequest(self.column, "mask"),
         ] + reqs
 
@@ -61,7 +67,15 @@ class ApproxCountDistinct(ScanShareableAnalyzer):
                 np.zeros(hll.M, dtype=np.int8)
             )
 
-        if kind == Kind.STRING:
+        if kind == Kind.STRING and dataset.string_lane(col):
+            consts = None
+
+            def registers_of(batch, c, mask, prev):
+                # the hashed-string lane: each row ships its own hash
+                h1, h2 = hll.lane_hash_pair(batch[f"{col}::{LANE_HASH}"])
+                return hll.registers_from_hash_pair(h1, h2, mask)
+
+        elif kind == Kind.STRING:
             # hash LUTs as runtime inputs (pow2-padded): the compiled
             # scan is shared across datasets — see ScanOps.consts
             lut1_host, lut2_host = hll.dictionary_hash_pairs(

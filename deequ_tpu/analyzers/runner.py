@@ -568,12 +568,14 @@ def _plan_fused_pass(
     engine: AnalysisEngine,
     metadata=None,
     row_sink=None,
+    keep_states: bool = False,
 ) -> FusedPassPlan:
     """Phase 1 of the fused pass: vectorize the scan-shareable
     analyzers, plan the grouping frequency passes, and assemble the
     scan pairs. Per-analyzer plan failures (bad predicate, unknown
     column inside an expression) become failure metrics here without
-    aborting the shared pass."""
+    aborting the shared pass. ``keep_states``: the run persists or
+    merges states (plan_frequency_passes)."""
     from deequ_tpu.analyzers.grouping import (
         FrequencyScanAdapter,
         plan_frequency_passes,
@@ -595,6 +597,7 @@ def _plan_fused_pass(
                 list(by_plan.keys()),
                 engine,
                 events=None if metadata is None else metadata.events,
+                keep_states=keep_states,
             )
         except Exception as exc:  # noqa: BLE001 — planning failed for
             # the whole grouping family: every grouping analyzer fails
@@ -655,7 +658,10 @@ def _run_fused_pass(
     runner-layer compile/execute split."""
     with get_telemetry().span("plan:prepare"):
         pass_plan = _plan_fused_pass(
-            data, analyzers, grouping, engine, metadata, row_sink=row_sink
+            data, analyzers, grouping, engine, metadata, row_sink=row_sink,
+            keep_states=(
+                aggregate_with is not None or save_states_with is not None
+            ),
         )
     if pass_plan.empty:
         return pass_plan.metrics

@@ -82,6 +82,7 @@ import numpy as np
 
 from deequ_tpu.analyzers.grouping import FrequenciesAndNumRows
 from deequ_tpu.data.table import (
+    LANE_HASH,
     ColumnRequest,
     Dataset,
     Kind,
@@ -1626,6 +1627,188 @@ def _sharded_spill_frequencies(
 # --------------------------------------------------------------------------
 
 
+def lane_spill_eligible(dataset: Dataset, plan) -> bool:
+    """True when a frequency plan over a hashed-string lane column
+    (data/strings.py) groups on the device: its one column's u64 row
+    hashes ride the shared scan into the collector and sort there, and
+    ``exact_lane_frequencies`` settles equal hashes of unequal strings.
+    A lane column in any other plan (several columns, a where-filter,
+    Histogram's null bin) takes the host Arrow group-by, which is exact
+    as it stands. No resident cache is needed: the collector's buffer
+    is scan state."""
+    from deequ_tpu import config
+
+    opts = config.options()
+    return (
+        opts.one_pass_spill
+        and opts.device_spill_grouping
+        and opts.engine != "cpu"
+        and len(plan.columns) == 1
+        and plan.where is None
+        and not plan.include_nulls
+        and dataset.num_rows < 2**31
+    )
+
+
+class LaneFrequencies(FrequenciesAndNumRows):
+    """The exact frequencies of one hashed-string lane column, as the
+    counts ``exact_lane_frequencies`` corrected: the number of groups,
+    of groups seen once, and the entropy. It holds no values, so it
+    cannot be persisted or merged: a run that keeps its states groups
+    a lane column on the host instead (``plan_frequency_passes``)."""
+
+    def __init__(self, freq, plan, groups: int, unique: int,
+                 entropy: float, collisions: int):
+        self.columns = tuple(plan.columns)
+        self.num_rows = freq.num_rows
+        self._groups = int(groups)
+        self._unique = int(unique)
+        self._entropy = float(entropy)
+        self.collisions = int(collisions)
+
+    @property
+    def num_groups(self) -> int:
+        return self._groups
+
+    def count_unique_groups(self) -> int:
+        return self._unique
+
+    def entropy_nats(self) -> float:
+        from deequ_tpu.analyzers.base import EmptyStateException
+
+        if self.num_rows == 0:
+            raise EmptyStateException("Entropy over empty distribution.")
+        return self._entropy
+
+    def _no_values(self):
+        raise TypeError(
+            "a hashed-string lane grouping keeps counts, not values; a "
+            "run that persists or merges states groups such a column "
+            "on the host"
+        )
+
+    keys = property(_no_values)
+    counts = property(_no_values)
+
+    def non_null_group_mask(self) -> np.ndarray:
+        self._no_values()
+
+    def top_groups(self, k: int):
+        self._no_values()
+
+
+@functools.lru_cache(maxsize=None)
+def _repeated_keys_fn(size: int):
+    """Jitted: the group keys whose count exceeds one, compacted into
+    ``size`` slots (single-device finalize output)."""
+
+    def run(group_keys, counts, num_segments):
+        n = counts.shape[0]
+        repeated = (counts > 1) & (
+            jnp.arange(n, dtype=jnp.int32) < num_segments
+        )
+        slot = jnp.cumsum(repeated.astype(jnp.int32)) - 1
+        return (
+            jnp.zeros(size, dtype=group_keys.dtype)
+            .at[jnp.where(repeated, slot, size)]
+            .set(group_keys, mode="drop")
+        )
+
+    return jax.jit(run)
+
+
+def _repeated_hashes(freq, repeated: int) -> np.ndarray:
+    """The ``repeated`` row hashes that more than one row shares,
+    sorted."""
+    if isinstance(freq, ShardedDeviceFrequencies):
+        freq._fetch()
+        out = freq._keys_host[freq._counts_host > 1]
+    else:
+        from deequ_tpu.engine.pack import packed_device_get
+
+        group_keys, counts = freq._dev
+        (out,) = packed_device_get((
+            _repeated_keys_fn(_pow2_len(repeated))(
+                group_keys, counts, np.int32(freq._num_segments)
+            ),
+        ))
+        out = np.asarray(out)[:repeated]
+    return np.sort(out.astype(np.uint64))
+
+
+def _values_under(dataset, column: str, hashes: np.ndarray):
+    """One traversal of ``column``: the (hash, count) of every distinct
+    non-null value whose row hash is one of the sorted ``hashes``."""
+    import pyarrow as pa
+
+    from deequ_tpu.data import strings
+    from deequ_tpu.telemetry import get_telemetry
+
+    found_h, found_v = [], []
+    with get_telemetry().traversal(
+        "collisions", rows=dataset.num_rows, columns=1
+    ):
+        for record_batch in dataset.record_batches([column]):
+            values = strings.plain_strings(record_batch.column(0))
+            h = strings.hash64(values)
+            pos = np.minimum(np.searchsorted(hashes, h), len(hashes) - 1)
+            hit = hashes[pos] == h
+            if values.null_count:
+                hit &= ~values.is_null().to_numpy(zero_copy_only=False)
+            if hit.any():
+                found_h.append(h[hit])
+                found_v.append(
+                    values.filter(pa.array(hit)).cast(pa.large_string())
+                )
+    table = pa.table({
+        "h": pa.array(np.concatenate(found_h), pa.uint64()),
+        "v": pa.chunked_array(found_v, pa.large_string()),
+    })
+    pairs = table.group_by(["h", "v"]).aggregate([([], "count_all")])
+    return (pairs.column("h").to_numpy(),
+            pairs.column("count_all").to_numpy().astype(np.int64))
+
+
+def exact_lane_frequencies(freq, dataset, plan) -> LaneFrequencies:
+    """Make a lane column's hash grouping exact. A hash that only one
+    row has is one distinct value. A hash that several rows share may
+    stand for several unequal strings: those rows' values are re-read
+    from the source (one more traversal, taken only when some hash
+    repeats) and counted per (hash, value), which splits every true
+    collision into its own groups and corrects the distinct count, the
+    count of values seen once, and the entropy. The span
+    ``spill:collisions`` records the repeated ``hashes`` checked and
+    the ``collisions`` found."""
+    from deequ_tpu.telemetry import get_telemetry
+
+    column = plan.columns[0]
+    groups = freq.num_groups
+    unique = freq.count_unique_groups()
+    entropy = freq._entropy
+    repeated = groups - unique
+    with get_telemetry().span(
+        "spill:collisions", column=column, hashes=repeated
+    ) as sp:
+        collisions = 0
+        if repeated:
+            h, c = _values_under(
+                dataset, column, _repeated_hashes(freq, repeated)
+            )
+            collisions = len(h) - repeated
+            unique += int(np.count_nonzero(c == 1))
+            groups += collisions
+            # each repeated hash's entropy term becomes its values' terms
+            total = float(max(freq.num_rows, 1))
+            _, inverse = np.unique(h, return_inverse=True)
+            merged = np.bincount(inverse, weights=c) / total
+            split = c / total
+            entropy += float(
+                np.sum(merged * np.log(merged)) - np.sum(split * np.log(split))
+            )
+        sp.set(collisions=collisions)
+    return LaneFrequencies(freq, plan, groups, unique, entropy, collisions)
+
+
 class CollectorSpec:
     """One spill plan's ride on the shared fused scan.
 
@@ -1775,8 +1958,15 @@ def single_collector_spec(
     from deequ_tpu.sql.predicate import compile_predicate
 
     column = plan.columns[0]
-    values_dtype = dataset.request_dtype(ColumnRequest(column, "values"))
-    if values_dtype.kind != "f":
+    # a hashed-string lane column groups by its u64 row hashes, made
+    # exact after the sort by exact_lane_frequencies
+    lane = dataset.schema.kind_of(column) == Kind.STRING
+    values_dtype = dataset.request_dtype(
+        ColumnRequest(column, LANE_HASH if lane else "values")
+    )
+    if lane:
+        key_kind = "hash"
+    elif values_dtype.kind != "f":
         key_kind = "int"
     elif np.dtype(values_dtype).itemsize == 8:
         key_kind = "f64"
@@ -1789,7 +1979,10 @@ def single_collector_spec(
     host_bits = key_kind == "f64" and (
         _jax.default_backend() != "cpu" or _FORCE_HOST_F64_BITS
     )
-    value_req = ColumnRequest(column, "u64bits" if host_bits else "values")
+    value_req = ColumnRequest(
+        column,
+        LANE_HASH if lane else ("u64bits" if host_bits else "values"),
+    )
     requests = [value_req, ColumnRequest(column, "mask")]
     pred = None
     if plan.where is not None:
@@ -1797,13 +1990,15 @@ def single_collector_spec(
         requests += list(pred.requests)
 
     mesh, axis, ndev, local_cap = _collector_geometry(dataset, engine)
-    key_fn = None if host_bits else _chunk_key_fn(key_kind, include_nulls)
+    key_fn = (
+        None if host_bits or lane else _chunk_key_fn(key_kind, include_nulls)
+    )
 
     def batch_keys(batch, _consts):
         rows = batch[ROW_MASK]
         if pred is not None:
             rows = rows & pred.complies(batch)
-        if host_bits:
+        if host_bits or lane:
             k, s, null = _finish_keys(
                 batch[value_req.key], batch[f"{column}::mask"], rows,
                 include_nulls,
@@ -1834,10 +2029,13 @@ def single_collector_spec(
 
             def build(fetched):
                 scalars_h, n_null_h = fetched
-                return DeviceFrequencies(
+                freq = DeviceFrequencies(
                     plan.columns, values_dtype, scalars_h, group_keys,
                     counts, int(n_null_h), include_nulls,
                 )
+                if lane:
+                    return exact_lane_frequencies(freq, dataset, plan)
+                return freq
 
             return (scalars, nn), build
 
@@ -1865,6 +2063,8 @@ def single_collector_spec(
                     g_counts, int(n_null_h), include_nulls,
                 )
                 st._dev = (g_keys, g_counts, segs_h)
+                if lane:
+                    return exact_lane_frequencies(st, dataset, plan)
                 return st
 
             return (scalars, overflow, n_null_g, g_segs), build

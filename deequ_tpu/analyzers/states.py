@@ -265,9 +265,12 @@ class ApproxCountDistinctState(NamedTuple):
 # silently merged wrong. v2 of ApproxCountDistinctState: integral
 # columns hash the raw int64 payload (v1 float-canonicalized, colliding
 # above 2^53) — v1 registers place the same values in different
-# registers, so a v1+v2 max-merge would double-count.
+# registers, so a v1+v2 max-merge would double-count. v3: strings hash
+# with the vectorized 64-bit string hash (data/strings.hash64) that the
+# hashed-string lane ships per row; v2 hashed each dictionary entry
+# with blake2b-8, so its string registers place values elsewhere.
 STATE_FORMAT_VERSIONS: Dict[str, int] = {
-    "ApproxCountDistinctState": 2,
+    "ApproxCountDistinctState": 3,
 }
 
 

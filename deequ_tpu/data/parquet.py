@@ -48,6 +48,8 @@ from deequ_tpu.data.table import (
     _kind_of,
     convert_basic_repr,
     dictionary_to_numpy,
+    is_lane_repr,
+    lane_repr_dtype,
     narrow_codes,
 )
 from deequ_tpu.telemetry import get_telemetry
@@ -349,6 +351,8 @@ class ParquetDataset(Dataset):
         conversion/narrowing rules is reflected here automatically."""
         if req.repr == "mask":
             return np.dtype(bool)
+        if is_lane_repr(req.repr):
+            return lane_repr_dtype(req.repr)
         if req.repr == "codes" and self._dict_delta_mode(req.column):
             # delta-mode codes are canonical int32 on every path (the
             # wire codec layer narrows them on the wire); crucially
@@ -490,6 +494,8 @@ class ParquetDataset(Dataset):
         return self._integral_ranges[column]
 
     def dictionary_size_within(self, column: str, cap: int):
+        if self._lane_too_big(column, cap):
+            return None
         if column in self._dictionaries:
             n = len(self._dictionaries[column])
             return n if n <= cap else None
@@ -516,20 +522,37 @@ class ParquetDataset(Dataset):
 
     # -- full-column materialization (resident path only) ---------------
 
-    def _reprs_for_kind(self, kind: Kind) -> List[str]:
+    def _reprs_for_kind(self, kind: Kind, column: str) -> List[str]:
         """All reprs one scan can fill for a column of this kind —
         materializing any repr fills the others too, so callers needing
-        several (values+mask, codes+mask+lengths) cost ONE file scan."""
+        several (values+mask, codes+mask+lengths) cost ONE file scan. A
+        lane column fills its mask with each repr asked for (no codes:
+        it has no dictionary)."""
         if kind == Kind.STRING:
+            if self.string_lane(column):
+                return ["mask"]
             return ["codes", "mask", "lengths"]
         return ["values", "mask"]
+
+    def _lane_head(self, column: str):
+        from deequ_tpu.data.strings import LANE_PROBE_ROWS, PROBE_CHUNK_ROWS
+
+        scanner = self._source.scanner(
+            columns=[column], batch_size=PROBE_CHUNK_ROWS
+        )
+        left = LANE_PROBE_ROWS
+        for batch in _read_batches(scanner):
+            yield batch.column(0).slice(0, left)
+            left -= min(left, batch.num_rows)
+            if not left:
+                return
 
     def materialize(self, req: ColumnRequest) -> np.ndarray:
         key = req.key
         if key in self._materialized:
             return self._materialized[key]
         kind = self._schema.kind_of(req.column)
-        reprs = self._reprs_for_kind(kind)
+        reprs = self._reprs_for_kind(kind, req.column)
         if req.repr not in reprs:
             reprs = reprs + [req.repr]  # let the converter raise clearly
         value_set = (
@@ -801,6 +824,10 @@ class ParquetDataset(Dataset):
             self._read_batch_rows,
         )
         view._shard_tag = (int(process_index), int(process_count))
+        # one lane decision for the whole source: every shard ships the
+        # same representation of a column
+        view._string_lanes = self.__dict__.setdefault("_string_lanes", {})
+        view._lane_head = self._lane_head
         # count_rows() on a row-group-restricted fragment reports the
         # WHOLE file (pyarrow quirk; scans are correctly restricted) —
         # recount from the assigned row-group metadata

@@ -139,14 +139,15 @@ def narrow_codes(codes: np.ndarray, dict_size: int) -> np.ndarray:
 
 
 def dictionary_to_numpy(dictionary: pa.Array) -> np.ndarray:
-    """Dictionary values as numpy: object arrays for strings, NATIVE
-    dtype otherwise — a to_pylist object array costs seconds at 10M
-    distinct values. One definition for the in-memory and parquet paths."""
+    """Dictionary values as numpy: object arrays for strings (Arrow's
+    own conversion, no per-value Python), NATIVE dtype otherwise. One
+    definition for the in-memory and parquet paths."""
+    out = dictionary.to_numpy(zero_copy_only=False)
     if pa.types.is_string(dictionary.type) or pa.types.is_large_string(
         dictionary.type
     ):
-        return np.asarray(dictionary.to_pylist(), dtype=object)
-    return dictionary.to_numpy(zero_copy_only=False)
+        return out.astype(object, copy=False)
+    return out
 
 
 def dictionary_utf8_lengths(dictionary: pa.Array) -> np.ndarray:
@@ -160,6 +161,28 @@ def dictionary_utf8_lengths(dictionary: pa.Array) -> np.ndarray:
     return np.ascontiguousarray(
         lengths.to_numpy(zero_copy_only=False).astype(np.int32)
     )
+
+
+# the hashed-string lane's reprs (built by data/strings.py): the row
+# hash, the DataType bucket, and one bool plane per regex ("match:") or
+# value list ("in:")
+LANE_HASH = "hash"
+LANE_DTYPE = "dtype"
+LANE_PLANES = ("match:", "in:")
+
+
+def is_lane_repr(repr_name: str) -> bool:
+    return repr_name in (LANE_HASH, LANE_DTYPE) or repr_name.startswith(
+        LANE_PLANES
+    )
+
+
+def lane_repr_dtype(repr_name: str) -> np.dtype:
+    if repr_name == LANE_HASH:
+        return np.dtype(np.uint64)
+    if repr_name == LANE_DTYPE:
+        return np.dtype(np.int32)
+    return np.dtype(bool)
 
 
 def convert_basic_repr(col, kind: "Kind", repr_name: str) -> np.ndarray:
@@ -217,6 +240,10 @@ def convert_basic_repr(col, kind: "Kind", repr_name: str) -> np.ndarray:
         )
     if repr_name == "u64bits":
         return f64_canonical_u64_bits(convert_basic_repr(col, kind, "values"))
+    if kind == Kind.STRING and is_lane_repr(repr_name):
+        from deequ_tpu.data import strings
+
+        return strings.lane_repr_of(col, repr_name)
     raise ValueError(f"unknown column repr: {repr_name!r}")
 
 
@@ -347,7 +374,9 @@ class ColumnRequest:
 
     column: str
     # "values" | "mask" | "codes" | "lengths" | "u64bits" (host-packed
-    # canonical f64 key bits for the one-pass spill collector)
+    # canonical f64 key bits for the one-pass spill collector) | a
+    # hashed-string lane repr (data/strings.py: "hash", "dtype",
+    # "match:...", "in:...")
     repr: str
 
     @property
@@ -534,6 +563,8 @@ class Dataset:
             return np.dtype(bool)
         if req.repr == "u64bits":
             return np.dtype(np.uint64)
+        if is_lane_repr(req.repr):
+            return lane_repr_dtype(req.repr)
         return np.dtype(self.materialize(req).dtype)
 
     # -- batching -------------------------------------------------------
@@ -644,6 +675,8 @@ class Dataset:
             return 4
         if r.repr == "u64bits":
             return 8
+        if is_lane_repr(r.repr):
+            return lane_repr_dtype(r.repr).itemsize
         kind = self._schema.kind_of(r.column)
         if kind in (Kind.BOOLEAN, Kind.STRING):
             return 4
@@ -662,8 +695,65 @@ class Dataset:
         necessarily building the full dictionary (parquet sources bail
         out of the streaming pre-pass once the cap is passed, so a
         spilling plan never materializes an unbounded value set)."""
+        if self._lane_too_big(column, cap):
+            return None
         d = self.dictionary(column)
         return len(d) if len(d) <= cap else None
+
+    def _lane_too_big(self, column: str, cap: int) -> bool:
+        """A lane column holds more than dict_delta_capacity distinct
+        values, so more than any smaller cap: no dictionary needed to
+        say so."""
+        from deequ_tpu import config
+
+        return (
+            self._schema.kind_of(column) == Kind.STRING
+            and cap <= config.options().dict_delta_capacity
+            and self.string_lane(column)
+        )
+
+    # -- the hashed-string lane -------------------------------------------
+
+    def string_lane(self, column: str) -> bool:
+        """True when string ``column`` ships the hashed-string lane
+        (data/strings.py) instead of dictionary codes: its first
+        ``strings.LANE_PROBE_ROWS`` rows hold more distinct values than
+        ``config.dict_delta_capacity``. Decided once per dataset, from
+        the same rule on every kind of dataset."""
+        lanes = self.__dict__.setdefault("_string_lanes", {})
+        if column not in lanes:
+            lane = False
+            if self._schema.kind_of(column) == Kind.STRING:
+                from deequ_tpu import config
+                from deequ_tpu.data import strings
+                from deequ_tpu.telemetry import get_telemetry
+
+                lane = strings.lane_probe(
+                    self._lane_head(column),
+                    config.options().dict_delta_capacity,
+                )
+                if lane:
+                    get_telemetry().counter("strings.lane_columns").inc()
+            lanes[column] = lane
+        return lanes[column]
+
+    def _lane_head(self, column: str):
+        """The lane probe's rows of ``column``: its first
+        ``strings.LANE_PROBE_ROWS`` values, in consecutive chunks."""
+        from deequ_tpu.data.strings import LANE_PROBE_ROWS, PROBE_CHUNK_ROWS
+
+        col = self._table.column(column)
+        rows = min(len(col), LANE_PROBE_ROWS)
+        for start in range(0, rows, PROBE_CHUNK_ROWS):
+            yield col.slice(start, min(PROBE_CHUNK_ROWS, rows - start))
+
+    def kind_token(self, column: str) -> str:
+        """The column's kind as plan-cache tokens carry it: a lane
+        column's ops differ from a dictionary column's."""
+        kind = self._schema.kind_of(column)
+        if kind == Kind.STRING and self.string_lane(column):
+            return "String:lane"
+        return kind.value
 
     def integral_range(
         self, column: str

@@ -289,15 +289,14 @@ def _plane_for(
         return _PlaneSpec("", fn, reqs, excl)
 
     if isinstance(analyzer, PatternMatch):
-        import re
+        from deequ_tpu.data.strings import dictionary_matches
 
         col = analyzer.column
         dictionary = data.dictionary(col)
-        prog = re.compile(analyzer.pattern)
         lut = np.zeros(max(len(dictionary), 1) + 1, dtype=bool)
-        for i, value in enumerate(dictionary):
-            if value is not None and prog.search(str(value)):
-                lut[i] = True
+        lut[: len(dictionary)] = dictionary_matches(
+            dictionary, analyzer.pattern
+        )
         null_idx = len(lut) - 1
         key = f"__rl_lut_{len(consts)}"
         consts[key] = pad_pow2(lut)

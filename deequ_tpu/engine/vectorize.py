@@ -50,7 +50,7 @@ from deequ_tpu.analyzers.basic import (
     _row_mask,
     _acc_float,
 )
-from deequ_tpu.data.table import ColumnRequest, Dataset, Kind
+from deequ_tpu.data.table import LANE_HASH, ColumnRequest, Dataset, Kind
 from deequ_tpu.sketches import hll
 
 _F64 = jnp.float64
@@ -196,9 +196,7 @@ def _group_token(
 ) -> Optional[tuple]:
     if not _where_ok_for_token(where, dataset):
         return None
-    kinds = tuple(
-        (c, dataset.schema.kind_of(c).value) for c in columns
-    )
+    kinds = tuple((c, dataset.kind_token(c)) for c in columns)
     return ("vec", family, kinds, where, extra)
 
 
@@ -414,7 +412,7 @@ def _build_completeness_group(
 def _build_hll_group(
     dataset: Dataset,
     members: List[Any],
-    value_repr: str,  # "values" (numeric) | "codes" (string)
+    value_repr: str,  # "values" (numeric) | "codes" | "hash" (string)
     where: Optional[str],
     kll_pool_columns: Optional[Tuple[str, ...]] = None,
     runtime_gate_columns: Optional[Tuple[str, ...]] = None,
@@ -509,6 +507,12 @@ def _build_hll_group(
                 regs = hll.registers_from_hash_pair_stacked(
                     h1, h2, masks
                 )
+        elif value_repr == LANE_HASH:
+            # hashed-string lane columns: every row ships its hash
+            h1, h2 = hll.lane_hash_pair(
+                _shared_stack(batch, columns, LANE_HASH)
+            )
+            regs = hll.registers_from_hash_pair_stacked(h1, h2, masks)
         elif kll_pool_columns:
             # reuse the KLL group's sort through the shared-batch memo
             # (one sort per step for both units, no CSE reliance)
@@ -793,7 +797,7 @@ def _build_kll_group(
 def _build_datatype_group(
     dataset: Dataset, members: List[Any], where: Optional[str]
 ) -> ScanUnit:
-    from deequ_tpu.analyzers.datatype import classify_string
+    from deequ_tpu.analyzers.datatype import dictionary_buckets
 
     columns, member_cols = _index_members(members)
     where_fn, where_reqs = _compile_where(where, dataset)
@@ -804,26 +808,13 @@ def _build_datatype_group(
     ] + where_reqs
     C = len(columns)
 
-    def _classify(value) -> int:
-        return (
-            S.DataTypeHistogram.NULL
-            if value is None
-            else classify_string(str(value))
-        )
-
     consts = None
     host_delta = None
     # one-pass dictionary deltas: bucket LUT in STATE, classified
     # incrementally from each delta's values (see _build_hll_group)
     delta_cap = _delta_cap_of(dataset, columns)
     if delta_cap is None:
-        luts = []
-        for c in columns:
-            dictionary = dataset.dictionary(c)
-            lut = np.zeros(max(len(dictionary), 1), dtype=np.int32)
-            for i, value in enumerate(dictionary):
-                lut[i] = _classify(value)
-            luts.append(lut)
+        luts = [dictionary_buckets(dataset.dictionary(c)) for c in columns]
         consts = {"lut": _stack_luts(luts, S.DataTypeHistogram.STRING)}
 
     def init():
@@ -921,11 +912,7 @@ def _build_datatype_group(
                 if start == 0:  # full (re)ship: reset the mirror
                     row[:] = S.DataTypeHistogram.STRING
                 if n:
-                    row[start:start + n] = np.fromiter(
-                        (_classify(v) for v in values),
-                        dtype=np.int32,
-                        count=n,
-                    )
+                    row[start:start + n] = dictionary_buckets(values)[:n]
                 lut = _set_lut_row(lut, col_index[c], row)
             return {"counts": state["counts"], "lut": lut}
 
@@ -973,7 +960,7 @@ def plan_scan_units(
         Sum,
     )
     from deequ_tpu.analyzers.datatype import DataType
-    from deequ_tpu.analyzers.hll import ApproxCountDistinct
+    from deequ_tpu.analyzers.hll import ApproxCountDistinct, hll_value_repr
     from deequ_tpu.analyzers.kll import (
         ApproxQuantile,
         ApproxQuantiles,
@@ -997,17 +984,15 @@ def plan_scan_units(
             if t is Completeness:
                 return ("completeness", a.where)
             if t is ApproxCountDistinct:
-                kind = dataset.schema.kind_of(a.column)
-                if kind == Kind.STRING:
-                    dt = dataset.request_dtype(
-                        ColumnRequest(a.column, "codes")
-                    )
-                    return ("hll", "codes", str(dt), a.where)
-                dt = dataset.request_dtype(ColumnRequest(a.column, "values"))
-                return ("hll", "values", str(dt), a.where)
+                value_repr = hll_value_repr(dataset, a.column)
+                dt = dataset.request_dtype(
+                    ColumnRequest(a.column, value_repr)
+                )
+                return ("hll", value_repr, str(dt), a.where)
             if (
                 t is DataType
                 and dataset.schema.kind_of(a.column) == Kind.STRING
+                and not dataset.string_lane(a.column)
             ):
                 dt = dataset.request_dtype(ColumnRequest(a.column, "codes"))
                 return ("datatype", str(dt), a.where)

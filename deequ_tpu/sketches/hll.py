@@ -20,15 +20,18 @@ murmur-style mixing maps perfectly onto the VPU:
 - the word pair mixes through murmur3's 32-bit finalizer into two
   independent 32-bit hashes: h1 supplies the register index (top
   P bits), h2 supplies the leading-zero rank;
-- strings hash host-side ONCE per dictionary entry (blake2b-8, split
-  into two u32 words) into device lookup tables gathered by code.
+- strings hash host-side with one 64-bit string hash
+  (data/strings.hash64), split into its two u32 words: once per
+  dictionary entry into device lookup tables gathered by code, or once
+  per row of a hashed-string lane column, which ships the u64 itself
+  (:func:`lane_hash_pair`). Either way a string lands in the same
+  register.
 """
 
 from __future__ import annotations
 
 import contextlib
 import contextvars
-import hashlib
 from typing import Tuple
 
 import jax
@@ -92,21 +95,25 @@ def hash_pair_numeric(
 
 
 def dictionary_hash_pairs(
-    dictionary: np.ndarray,
+    dictionary,
 ) -> Tuple[np.ndarray, np.ndarray]:
-    """Stable (u32, u32) hash per dictionary entry (host-side, once)."""
-    n = max(len(dictionary), 1)
-    h1 = np.zeros(n, dtype=np.uint32)
-    h2 = np.zeros(n, dtype=np.uint32)
-    for i, value in enumerate(dictionary):
-        if value is None:
-            continue
-        digest = hashlib.blake2b(
-            str(value).encode("utf-8"), digest_size=8
-        ).digest()
-        words = np.frombuffer(digest, dtype=np.uint32)
-        h1[i], h2[i] = words[0], words[1]
-    return h1, h2
+    """Stable (u32, u32) hash per dictionary entry (host-side, once, in
+    vectorized form): the low and high words of the string hash every
+    path shares (data/strings.hash64). Null entries hash to (0, 0)."""
+    from deequ_tpu.data import strings
+
+    arr = strings.dictionary_values(dictionary)
+    if len(arr) == 0:
+        return np.zeros(1, dtype=np.uint32), np.zeros(1, dtype=np.uint32)
+    return strings.hash_pairs(arr)
+
+
+def lane_hash_pair(h: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Device: a hashed-string lane's u64 row hashes -> (h1, h2), the
+    same low and high words ``dictionary_hash_pairs`` gives the entry."""
+    lo = (h & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32)
+    hi = (h >> jnp.uint64(32)).astype(jnp.uint32)
+    return lo, hi
 
 
 def _index_and_rank(h1, h2, mask):

@@ -442,15 +442,23 @@ def _validate_date_literal(text: str) -> None:
         ) from exc
 
 
+_REGEX_META = frozenset("\\.^$|?*+()[]{}")
+
+
 def _sql_like_to_regex(pattern: str) -> str:
+    """SQL LIKE as an anchored regex in the syntax Python's ``re`` and
+    RE2 share: only regex metacharacters are escaped, so
+    data/strings.regex_plane can hand it to RE2."""
     out = []
     for ch in pattern:
         if ch == "%":
             out.append(".*")
         elif ch == "_":
             out.append(".")
+        elif ch in _REGEX_META:
+            out.append("\\" + ch)
         else:
-            out.append(re.escape(ch))
+            out.append(ch)
     return "^" + "".join(out) + "$"
 
 
@@ -497,11 +505,14 @@ class _PredicateData:
     dictionaries (weak — only string predicates dereference them, and
     only at trace time while the owning run holds the dataset)."""
 
-    __slots__ = ("schema", "_ref")
+    __slots__ = ("schema", "_ref", "lanes")
 
-    def __init__(self, schema, ref):
+    def __init__(self, schema, ref, lanes=frozenset()):
         self.schema = schema
         self._ref = ref
+        # hashed-string lane columns (data/strings.py): read through
+        # their per-row planes, never a dictionary
+        self.lanes = lanes
 
     def dictionary(self, column: str):
         dataset = self._ref()
@@ -538,10 +549,12 @@ class CompiledPredicate:
         dataset: Dataset,
         columns_used: Sequence[str],
         requests: Sequence[ColumnRequest],
+        lanes: frozenset = frozenset(),
     ):
         import weakref
 
         self._node = node
+        self._lanes = lanes
         # WEAK reference: compiled predicates end up inside jitted
         # closures that the cross-run plan cache retains — a strong ref
         # would pin the whole Arrow table for the cache's lifetime. The
@@ -568,7 +581,7 @@ class CompiledPredicate:
         # incl. on re-trace after the origin dataset is gone);
         # dictionaries resolve through the weakref (string predicates
         # only — those are never in cached cross-dataset plans)
-        return _PredicateData(self._schema, self._dataset_ref)
+        return _PredicateData(self._schema, self._dataset_ref, self._lanes)
 
     def evaluate(self, batch: Dict[str, jnp.ndarray]) -> Tuple[jnp.ndarray, jnp.ndarray]:
         val = _eval(self._node, batch, self._dataset)
@@ -596,21 +609,39 @@ def compile_predicate(expression: str, dataset: Dataset) -> CompiledPredicate:
     for c in cols:
         if not schema.has_column(c):
             raise KeyError(f"predicate references unknown column '{c}'")
+    lanes = {
+        c
+        for c in cols
+        if schema.kind_of(c) == Kind.STRING and dataset.string_lane(c)
+    }
+    # a lane column read other than through its per-row planes (an
+    # ordering, a string function, a comparison with another column)
+    # takes dictionary codes, as every string column did before lanes
+    lanes -= _dictionary_reads(node, lanes)
+    for c in cols:
         kind = schema.kind_of(c)
-        if kind == Kind.STRING:
+        if c in lanes:
+            pass
+        elif kind == Kind.STRING:
             requests.append(ColumnRequest(c, "codes"))
         else:
             requests.append(ColumnRequest(c, "values"))
         requests.append(ColumnRequest(c, "mask"))
     for col in _length_columns_of(node):
         requests.append(ColumnRequest(col, "lengths"))
+    if lanes:
+        requests += [
+            ColumnRequest(c, r) for c, r in sorted(_lane_planes(node, lanes))
+        ]
     # static type check NOW (make_ops/planning time) so a bad predicate
     # degrades to THAT analyzer's failure metric — a raise later, inside
     # the shared fused-scan trace, would poison every co-scheduled
     # analyzer in the pass
     _check_types(node, schema)
     _check_plan_budgets(node, dataset)
-    compiled = CompiledPredicate(node, dataset, cols, requests)
+    compiled = CompiledPredicate(
+        node, dataset, cols, requests, frozenset(lanes)
+    )
     cache[expression] = compiled
     return compiled
 
@@ -976,6 +1007,82 @@ def _children_of(node: Node):
         yield pair[1]
 
 
+def _lane_operand(node: Node, lanes) -> Optional[str]:
+    """The lane column a lane-readable node applies to, else None: IN
+    over string literals, ``=``/``!=`` against a string literal, LIKE
+    and RLIKE, each straight on the column."""
+    if isinstance(node, (InList, Like)):
+        operand = node.operand
+        if isinstance(node, InList) and not all(
+            isinstance(i, (StringLit, NullLit)) for i in node.items
+        ):
+            return None
+    elif isinstance(node, BinOp) and node.op in ("=", "!="):
+        if isinstance(node.right, StringLit):
+            operand = node.left
+        elif isinstance(node.left, StringLit):
+            operand = node.right
+        else:
+            return None
+    else:
+        return None
+    if isinstance(operand, ColumnRef) and operand.name in lanes:
+        return operand.name
+    return None
+
+
+def _lane_plane(node: Node) -> str:
+    """The bool plane repr (data/strings.py) a lane-readable node
+    reads."""
+    from deequ_tpu.data import strings
+
+    if isinstance(node, Like):
+        return strings.match_repr(
+            node.pattern if node.regex else _sql_like_to_regex(node.pattern)
+        )
+    if isinstance(node, InList):
+        return strings.in_repr(
+            [i.value for i in node.items if isinstance(i, StringLit)]
+        )
+    literal = node.right if isinstance(node.right, StringLit) else node.left
+    return strings.in_repr([literal.value])
+
+
+def _dictionary_reads(node: Node, lanes) -> set:
+    """The lane columns ``node`` reads other than through a bool plane,
+    the mask (IS NULL) or the lengths (LENGTH): they need codes."""
+    if _lane_operand(node, lanes) is not None:
+        return set()
+    if isinstance(node, IsNull) and isinstance(node.operand, ColumnRef):
+        return set()
+    if (
+        isinstance(node, FuncCall)
+        and node.name == "LENGTH"
+        and all(isinstance(a, ColumnRef) for a in node.args)
+    ):
+        return set()
+    if isinstance(node, ColumnRef):
+        return {node.name} & set(lanes)
+    out: set = set()
+    for child in _children_of(node):
+        out |= _dictionary_reads(child, lanes)
+    return out
+
+
+def _lane_planes(node: Node, lanes) -> set:
+    """(column, plane repr) for every lane-readable node over a column
+    in ``lanes``."""
+    column = _lane_operand(node, lanes)
+    if column is not None:
+        if isinstance(node, Like) and node.regex:
+            re.compile(node.pattern)  # a bad regex fails here, not mid-scan
+        return {(column, _lane_plane(node))}
+    out: set = set()
+    for child in _children_of(node):
+        out |= _lane_planes(child, lanes)
+    return out
+
+
 def _length_columns_of(node: Node) -> set:
     """Columns appearing as LENGTH(col) — they need the 'lengths' repr."""
     out: set = set()
@@ -1264,6 +1371,21 @@ def _date_literal_epoch(ds, column: str, literal: str) -> int:
     return int(pc.cast(arr, pa.int64())[0].as_py())
 
 
+def _eval_lane(node: Node, column: str, batch) -> _Val:
+    """A lane-readable node over its column's bool plane, with the
+    same SQL null semantics as the dictionary path."""
+    truth = batch[f"{column}::{_lane_plane(node)}"]
+    valid = batch[f"{column}::mask"]
+    if isinstance(node, InList) and any(
+        isinstance(i, NullLit) for i in node.items
+    ):
+        valid = valid & truth  # x IN (..., NULL): non-matches are NULL
+    negate = node.op == "!=" if isinstance(node, BinOp) else node.negate
+    if negate:
+        truth = ~truth
+    return _Val(truth, valid, is_bool=True)
+
+
 def _eval_stringish(node: Node, batch, ds):
     """Branch evaluation for CASE/COALESCE, where a bare string
     literal (or NULL) is a legal RESULT: literals become ('lit', s)
@@ -1331,6 +1453,15 @@ def _string_union(ds, branches):
 
 
 def _eval(node: Node, batch: Dict[str, jnp.ndarray], ds: Dataset) -> _Val:
+    lanes = getattr(ds, "lanes", ())
+    if lanes:
+        column = _lane_operand(node, lanes)
+        if column is not None:
+            return _eval_lane(node, column, batch)
+        if isinstance(node, ColumnRef) and node.name in lanes:
+            # only IS NULL and LENGTH reach here (_dictionary_reads)
+            mask = batch[f"{node.name}::mask"]
+            return _Val(jnp.zeros(mask.shape, jnp.int32), mask)
     if isinstance(node, ColumnRef):
         kind = ds.schema.kind_of(node.name)
         mask = batch[f"{node.name}::mask"]
@@ -1548,11 +1679,16 @@ def _eval(node: Node, batch: Dict[str, jnp.ndarray], ds: Dataset) -> _Val:
         pattern = (
             node.pattern if node.regex else _sql_like_to_regex(node.pattern)
         )
-        prog = re.compile(pattern)
+        re.compile(pattern)  # Python's verdict on a bad pattern
+        import pyarrow as pa
+
+        from deequ_tpu.data.strings import regex_plane
+
         table = np.zeros(len(view) + 1, dtype=bool)
-        for i, s in enumerate(view):
-            if s is not None and prog.search(s):
-                table[i] = True
+        if view:
+            table[: len(view)] = regex_plane(
+                pa.array(view, type=pa.large_string()), pattern
+            )
         lut = jnp.asarray(table)
         truth = lut[jnp.clip(base.values, -1, len(view) - 1)]
         truth = jnp.where(base.values < 0, False, truth)

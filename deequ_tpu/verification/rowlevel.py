@@ -190,16 +190,15 @@ def _outcome_for(
             jax.device_get(pred.complies(batch)), dtype=bool
         ).copy()
     elif isinstance(analyzer, PatternMatch):
-        import re
+        from deequ_tpu.data.strings import dictionary_matches
 
         codes = mat(ColumnRequest(analyzer.column, "codes"))
         mask = mat(ColumnRequest(analyzer.column, "mask"))
         dictionary = data.dictionary(analyzer.column)
-        prog = re.compile(analyzer.pattern)
         lut = np.zeros(max(len(dictionary), 1) + 1, dtype=bool)
-        for i, value in enumerate(dictionary):
-            if value is not None and prog.search(str(value)):
-                lut[i] = True
+        lut[: len(dictionary)] = dictionary_matches(
+            dictionary, analyzer.pattern
+        )
         idx = np.where(codes < 0, len(lut) - 1, codes)
         out = lut[np.clip(idx, 0, len(lut) - 1)] & np.asarray(
             mask, dtype=bool

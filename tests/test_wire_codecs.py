@@ -15,6 +15,7 @@ import os
 
 import numpy as np
 import pyarrow as pa
+import pyarrow.compute as pc
 import pyarrow.parquet as pq
 import pytest
 
@@ -412,30 +413,63 @@ class TestDictionaryDeltas:
             assert passes.value - before == 3  # scan + 2 dictionaries
 
     def test_oversized_dictionary_overflows_loudly(self, tmp_path):
-        """A first-run dictionary larger than dict_delta_capacity is a
-        hard error naming the knob — never a silent wrong metric."""
+        """A column whose vocabulary is larger than dict_delta_capacity
+        from its first rows on no longer overflows: it streams through
+        the hashed-string lane (data/strings.py) in the same single
+        pass and agrees with the resident run and the exact count. The
+        overflow still guards a dictionary that outgrows the capacity
+        only later in the stream."""
         rng = np.random.default_rng(3)
         n = 600
-        pq.write_table(
-            pa.table(
-                {
-                    "s": pa.array([f"u{j}" for j in range(n)]),
-                    "t": pa.array(
-                        [f"v{j}" for j in rng.integers(0, n, n)]
-                    ),
-                }
-            ),
-            str(tmp_path / "wide.parquet"),
+        table = pa.table(
+            {
+                "s": pa.array([f"u{j}" for j in range(n)]),
+                "t": pa.array(
+                    [f"v{j}" for j in rng.integers(0, n, n)]
+                ),
+            }
         )
+        pq.write_table(table, str(tmp_path / "wide.parquet"))
+        analyzers = [ApproxCountDistinct("s"), ApproxCountDistinct("t")]
+        tm = get_telemetry()
+        passes = tm.counter("engine.data_passes")
         with config.configure(
             device_cache_bytes=0,
             batch_size=256,
             dict_delta_capacity=64,
             scan_retry=RetryPolicy(max_attempts=1),
         ):
+            before = passes.value
+            lanes_before = tm.counter("strings.lane_columns").value
+            streamed = AnalysisRunner.do_analysis_run(
+                Dataset.from_parquet(str(tmp_path)), analyzers
+            )
+            assert passes.value - before == 1
+            assert tm.counter("strings.lane_columns").value == (
+                lanes_before + 2
+            )
+            resident = AnalysisRunner.do_analysis_run(
+                Dataset.from_arrow(table), analyzers
+            )
+        for a in analyzers:
+            got = streamed.metric(a).value.get()
+            assert got == resident.metric(a).value.get()
+            want = pc.count_distinct(table.column(a.column)).as_py()
+            assert abs(got - want) / want < 0.02
+
+        # first 2^20 rows within capacity, later rows past it: the
+        # dictionary path's overflow still fires, naming the knob
+        late = ["a"] * (1 << 20) + [f"w{j}" for j in range(100)]
+        grow = pa.table({"s": pa.array(late), "t": pa.array(late)})
+        pq.write_table(grow, str(tmp_path / "grow.parquet"))
+        with config.configure(
+            device_cache_bytes=0,
+            dict_delta_capacity=64,
+            scan_retry=RetryPolicy(max_attempts=1),
+        ):
             ctx = AnalysisRunner.do_analysis_run(
-                Dataset.from_parquet(str(tmp_path)),
-                [ApproxCountDistinct("s"), ApproxCountDistinct("t")],
+                Dataset.from_parquet(str(tmp_path / "grow.parquet")),
+                analyzers,
             )
         value = ctx.metric(ApproxCountDistinct("s")).value
         assert not value.is_success
