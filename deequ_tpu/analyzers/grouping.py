@@ -541,7 +541,7 @@ def finalize_collector_states(
     the fault-injection hook) downgrades to the plan's deferred re-scan
     path, which itself downgrades to host Arrow under pressure — the
     collector -> deferred -> Arrow chain, each rung recorded."""
-    from deequ_tpu.analyzers.spill import SpillOverflow
+    from deequ_tpu.analyzers.spill import SpillOverflow, _sort_span
     from deequ_tpu.engine.deadline import RunCancelled
     from deequ_tpu.engine.pack import packed_device_get
 
@@ -593,10 +593,8 @@ def finalize_collector_states(
                 f"({getattr(cancel, 'reason', None) or 'cancelled'})"
             )
         return out
-    from deequ_tpu.telemetry import get_telemetry
-
     # the launched sorts finish here: the host blocks on their scalars
-    with get_telemetry().span("spill:sort"):
+    with _sort_span():
         fetched = packed_device_get(tuple(pendings))
     for (spec, build), got in zip(launched, fetched):
         try:

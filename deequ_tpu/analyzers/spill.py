@@ -44,12 +44,19 @@ carrying drop/null flags as extra sort keys:
 Sorting by bits rather than value order is fine: grouping only needs
 EQUAL keys adjacent, and bit-equality is the grouping relation itself.
 
-Count-shaped metrics then finalize from ON-DEVICE scalars (#groups,
-#count==1, entropy, #rows) — a 10M-group state never leaves the
-device; Histogram fetches only its top-K bins via ``lax.top_k``. The
-full (keys, counts) arrays stay device-resident and are fetched lazily
-only if something actually needs the values (persistence, incremental
-merge).
+The count after the sort stays in SORTED-POSITION space and uses no
+scatter: a segment starts wherever any lane changes, and its count is
+the distance from its start to the next segment's start (a reverse
+cumulative min over the start positions), written at its first
+position and 0 everywhere else. Count-shaped metrics then finalize
+from ON-DEVICE scalars (#segments, #groups, #count==1, entropy, #rows)
+read off that array with compares and sums — a 10M-group state never
+leaves the device; Histogram fetches only its top-K bins via
+``lax.top_k`` over the position-space counts. The sorted keys and
+counts stay device-resident; only a reader of group VALUES
+(persistence, incremental merge, MutualInformation, a lane's
+collision check) compacts them to one entry per group, counted by
+``spill.compactions``.
 
 No dictionary is built: unlike the dense path (host Arrow
 dictionary_encode) the keys here are the column's own 64-bit values, so
@@ -254,17 +261,21 @@ def host_f64_u64_keys(
 
 
 def _segment_count_lanes(lanes, correction):
-    """Traced: sort flat u64 key LANES lexicographically, count segment
-    boundaries (a boundary wherever ANY lane changes), subtract
-    ``correction`` sentinel-valued entries from the trailing segment.
-    This is the ONE copy of the exactness-critical bookkeeping — the
-    single-device finalize (1 or 2 lanes) and the per-shard half of
-    the sharded shuffle all run it. Output arrays have length N+1
-    (slot N absorbs non-boundary scatter writes); segments occupy
-    [0, num_segments) and ``gmask`` marks those with a positive
-    corrected count. Counts are i32 (a chip processes < 2^31 rows per
-    state; merges widen). The sentinel is max on EVERY lane, so it
-    still sorts last regardless of lane count."""
+    """Traced: sort flat u64 key LANES lexicographically and count each
+    segment (a run of equal keys; a boundary wherever ANY lane changes)
+    in sorted-position space, with no scatter: ``count_at[i]`` is the
+    distance from a segment's first position ``i`` to the next
+    segment's first position (a reverse cumulative min over the start
+    positions), and 0 at every other position. ``correction``
+    sentinel-valued entries are taken off the last segment when it is
+    the sentinel on every lane (the sentinel is max on EVERY lane, so
+    it sorts last regardless of lane count). This is the ONE copy of
+    the exactness-critical bookkeeping — the single-device finalize (1
+    or 2 lanes) and the per-shard half of the sharded shuffle all run
+    it. Returns ``(num_segments, sorted_lanes, count_at, live)``;
+    ``live`` marks the first position of every segment with a positive
+    corrected count, which is exactly ``count_at > 0``. Counts are i32
+    (a chip processes < 2^31 rows per state; merges widen)."""
     n = lanes[0].shape[0]
     if len(lanes) == 1:
         sorted_lanes = (jnp.sort(lanes[0]),)
@@ -274,69 +285,67 @@ def _segment_count_lanes(lanes, correction):
     for k in sorted_lanes:
         changed = changed | (k[1:] != k[:-1])
     boundary = jnp.concatenate([jnp.ones(1, dtype=bool), changed])
-    seg = jnp.cumsum(boundary.astype(jnp.int32)) - 1
-    num_segments = seg[-1] + 1
-    counts = jnp.zeros(n + 1, dtype=jnp.int32).at[seg].add(1)
+    pos = jnp.arange(n, dtype=jnp.int32)
+    start = jnp.where(boundary, pos, n)
+    next_start = jnp.concatenate([
+        jax.lax.cummin(start, reverse=True)[1:],
+        jnp.full(1, n, dtype=jnp.int32),
+    ])
+    count_at = jnp.where(boundary, next_start - pos, 0)
     # sentinel-valued entries all sort to the end and share the last
     # segment; the caller knows exactly how many don't belong
     has_sentinel = jnp.ones((), dtype=bool)
     for k in sorted_lanes:
         has_sentinel = has_sentinel & (k[-1] == _SENTINEL)
-    counts = counts.at[seg[-1]].add(
-        -jnp.where(has_sentinel, correction, 0).astype(jnp.int32)
-    )
-    scatter_idx = jnp.where(boundary, seg, n)
-    group_lanes = tuple(
-        jnp.zeros(n + 1, dtype=k.dtype).at[scatter_idx].set(k)
-        for k in sorted_lanes
-    )
-    in_range = jnp.arange(n + 1, dtype=jnp.int32) < num_segments
-    gmask = in_range & (counts > 0)
-    return num_segments, counts, group_lanes, gmask
+    last = boundary & (next_start == n) & has_sentinel
+    count_at = count_at - jnp.where(last, correction, 0).astype(jnp.int32)
+    live = boundary & (count_at > 0)
+    num_segments = jnp.sum(boundary, dtype=jnp.int64)
+    return num_segments, sorted_lanes, count_at, live
 
 
 def _segment_count(keys, correction):
     """Single-lane wrapper over _segment_count_lanes (the sharded
     shuffle and the single-column finalize use this shape)."""
-    num_segments, counts, group_lanes, gmask = _segment_count_lanes(
+    num_segments, sorted_lanes, count_at, live = _segment_count_lanes(
         (keys,), correction
     )
-    return num_segments, counts, group_lanes[0], gmask
+    return num_segments, sorted_lanes[0], count_at, live
 
 
-def _entropy_term(counts, gmask, total):
-    """Traced: -sum(p log p) over masked groups against a GLOBAL total
+def _entropy_term(counts, live, total):
+    """Traced: -sum(p log p) over live groups against a GLOBAL total
     (partial term for psum in the sharded path; the whole sum in the
     single-device path)."""
-    c = jnp.where(gmask, counts, 0).astype(jnp.float64)
+    c = jnp.where(live, counts, 0).astype(jnp.float64)
     tot_f = jnp.maximum(total, 1).astype(jnp.float64)
     p = c / tot_f
     return -jnp.sum(jnp.where(c > 0, p * jnp.log(p), 0.0))
 
 
-def _spill_scalars(num_segments, counts, gmask, total):
+def _spill_scalars(num_segments, count_at, live, total):
     """The on-device scalar summary every finalize shape shares."""
     return {
-        "num_segments": num_segments.astype(jnp.int64),
-        "num_groups": jnp.sum(gmask, dtype=jnp.int64),
+        "num_segments": num_segments,
+        "num_groups": jnp.sum(live, dtype=jnp.int64),
         "total": total,
-        "unique": jnp.sum((counts == 1) & gmask, dtype=jnp.int64),
-        "entropy": _entropy_term(counts, gmask, total),
+        "unique": jnp.sum((count_at == 1) & live, dtype=jnp.int64),
+        "entropy": _entropy_term(count_at, live, total),
     }
 
 
 @functools.lru_cache(maxsize=None)
 def _finalize_fn():
-    """Jitted: flat u64 keys + sentinel count -> per-group arrays and
-    scalars (single-device path)."""
+    """Jitted: flat u64 keys + sentinel count -> scalars, the sorted
+    keys and their position-space counts (single-device path)."""
 
     def run(keys, n_sentinel):
-        num_segments, counts, group_keys, gmask = _segment_count(
+        num_segments, sorted_keys, count_at, live = _segment_count(
             keys, n_sentinel
         )
         total = (keys.shape[0] - n_sentinel).astype(jnp.int64)
-        scalars = _spill_scalars(num_segments, counts, gmask, total)
-        return scalars, group_keys, counts
+        scalars = _spill_scalars(num_segments, count_at, live, total)
+        return scalars, sorted_keys, count_at
 
     return jax.jit(run)
 
@@ -346,27 +355,57 @@ def _finalize2_fn():
     """Jitted two-lane finalize (joint keys past one u64 lane)."""
 
     def run(hi, lo, n_sentinel):
-        num_segments, counts, group_lanes, gmask = _segment_count_lanes(
+        num_segments, sorted_lanes, count_at, live = _segment_count_lanes(
             (hi, lo), n_sentinel
         )
         total = (hi.shape[0] - n_sentinel).astype(jnp.int64)
-        scalars = _spill_scalars(num_segments, counts, gmask, total)
-        return scalars, group_lanes[0], group_lanes[1], counts
+        scalars = _spill_scalars(num_segments, count_at, live, total)
+        return scalars, sorted_lanes[0], sorted_lanes[1], count_at
 
     return jax.jit(run)
 
 
-@functools.partial(jax.jit, static_argnums=(3,))
-def _topk_fn(counts, group_keys, num_segments, k):
-    # equal-count ties at the k-boundary resolve in ascending
-    # PACKED-KEY order here (segments are key-sorted) vs first-seen
-    # order on the dense/Arrow path — a documented divergence; see
-    # FrequenciesAndNumRows.top_groups (ADVICE r3)
-    in_range = (
-        jnp.arange(counts.shape[0], dtype=jnp.int32) < num_segments
-    )
-    tc, ti = jax.lax.top_k(jnp.where(in_range, counts, -1), k)
-    return tc, jnp.take(group_keys, ti)
+def _run_finalize(fn, *args):
+    """Dispatch one grouping's finalize (a jitted single-device or
+    sharded sort + segment count), counted by ``spill.finalizes``."""
+    from deequ_tpu.telemetry import get_telemetry
+
+    get_telemetry().counter("spill.finalizes").inc()
+    return fn(*args)
+
+
+def _live_groups(lanes, count_at):
+    """Host: compact fetched position-space arrays to one entry per
+    live group (its sorted key lanes and its count), in sorted-position
+    order. Every compaction of a grouping's group arrays comes through
+    here or ``_repeated_hashes``."""
+    _count_compaction()
+    live = count_at > 0
+    return tuple(k[live] for k in lanes), count_at[live].astype(np.int64)
+
+
+def _count_compaction():
+    """Bump ``spill.compactions``; a compaction made while a
+    ``spill:sort`` span is open marks that span ``compacted``."""
+    from deequ_tpu.telemetry import get_telemetry
+
+    tm = get_telemetry()
+    tm.counter("spill.compactions").inc()
+    open_span = tm.tracer.current() if tm.enabled else None
+    if open_span is not None and open_span.name == "spill:sort":
+        open_span.set(compacted=True)
+
+
+@functools.partial(jax.jit, static_argnums=(2,))
+def _topk_fn(count_at, sorted_keys, k):
+    # ``k`` is at most the number of live groups, and every other
+    # position holds 0, so the top k are all live. Equal-count ties at
+    # the k-boundary resolve to the lower position, i.e. in ascending
+    # PACKED-KEY order, vs first-seen order on the dense/Arrow path — a
+    # documented divergence; see FrequenciesAndNumRows.top_groups
+    # (ADVICE r3)
+    tc, ti = jax.lax.top_k(count_at, k)
+    return tc, jnp.take(sorted_keys, ti)
 
 
 def _pack_top_pairs(pairs, k: int, null_rows: int):
@@ -398,11 +437,13 @@ def _traversal(kind: str, dataset, requests):
 
 
 def _sort_span():
-    """The span ``spill:sort``: a single-device spill finalize (sort +
-    segment count) and the packed fetch of its scalars."""
+    """The span ``spill:sort``: a spill finalize (sort + segment count)
+    and the packed fetch of its scalars. Its attribute ``compacted``
+    turns true if group arrays are compacted while it is open (the
+    finalize itself never compacts)."""
     from deequ_tpu.telemetry import get_telemetry
 
-    return get_telemetry().span("spill:sort")
+    return get_telemetry().span("spill:sort", compacted=False)
 
 
 class SpillOverflow(Exception):
@@ -442,8 +483,9 @@ def _sharded_spill_fn(mesh, axis: str, cap: int):
     re-shards them so EQUAL keys land on the same device, then each
     device runs the SAME sort + segment-count as the single-device path
     (_segment_count) over its disjoint key range; scalars psum into
-    global metrics. Per-device memory is O(rows/ndev): group arrays
-    come back SHARDED (out_specs P(axis)), never replicated.
+    global metrics. Per-device memory is O(rows/ndev): the sorted keys
+    and their position-space counts come back SHARDED (out_specs
+    P(axis)), never replicated.
 
     Sentinel-valued rows (dropped rows AND any legit int64.max keys —
     indistinguishable by value) never enter the shuffle at all: their
@@ -471,7 +513,7 @@ def _sharded_spill_fn(mesh, axis: str, cap: int):
         )
 
         # the shared exactness-critical bookkeeping (spill.py's one copy)
-        num_segments, counts, group_keys, gmask = _segment_count(
+        num_segments, sorted_keys, count_at, live = _segment_count(
             recv, padding_received.astype(jnp.int64)
         )
 
@@ -481,13 +523,12 @@ def _sharded_spill_fn(mesh, axis: str, cap: int):
             jax.lax.psum(sv_local, axis) - n_sentinel_global
         )
         scalars = _sharded_scalar_block(
-            axis, num_segments, counts, gmask, legit_max
+            axis, num_segments, count_at, live, legit_max
         )
         return (
             scalars,
-            group_keys,  # sharded out: (ndev*(L+1),) global
-            counts,
-            num_segments.astype(jnp.int32)[None],  # (ndev,) global
+            sorted_keys,  # sharded out: (ndev*L,) global
+            count_at,
             overflow,
             n_null_global,
         )
@@ -496,7 +537,7 @@ def _sharded_spill_fn(mesh, axis: str, cap: int):
         per_shard,
         mesh=mesh,
         in_specs=(P(axis), P(), P()),
-        out_specs=(P(), P(axis), P(axis), P(axis), P(), P()),
+        out_specs=(P(), P(axis), P(axis), P(), P()),
         check_vma=False,
     )
     return jax.jit(sharded)
@@ -548,21 +589,21 @@ def _bucketed_all_to_all(axis: str, ndev: int, cap: int, bucket, lanes):
     return recv_lanes, padding_received, overflow
 
 
-def _sharded_scalar_block(axis, num_segments, counts, gmask, legit_max):
+def _sharded_scalar_block(axis, num_segments, count_at, live, legit_max):
     """The psum'd scalar summary every sharded spill shape shares
     (single-lane with its analytic int64.max group; two-lane joints
     pass legit_max = 0, as joint codes can never reach the sentinel)."""
     import jax
 
-    local_total = jnp.sum(jnp.where(gmask, counts, 0), dtype=jnp.int64)
+    local_total = jnp.sum(jnp.where(live, count_at, 0), dtype=jnp.int64)
     total = jax.lax.psum(local_total, axis) + legit_max
     num_groups = (
-        jax.lax.psum(jnp.sum(gmask, dtype=jnp.int64), axis)
+        jax.lax.psum(jnp.sum(live, dtype=jnp.int64), axis)
         + (legit_max > 0).astype(jnp.int64)
     )
     unique = (
         jax.lax.psum(
-            jnp.sum((counts == 1) & gmask, dtype=jnp.int64), axis
+            jnp.sum((count_at == 1) & live, dtype=jnp.int64), axis
         )
         + (legit_max == 1).astype(jnp.int64)
     )
@@ -570,16 +611,13 @@ def _sharded_scalar_block(axis, num_segments, counts, gmask, legit_max):
         jnp.float64
     )
     entropy = jax.lax.psum(
-        _entropy_term(counts, gmask, total), axis
+        _entropy_term(count_at, live, total), axis
     ) + jnp.where(
         legit_max > 0, -pm * jnp.log(jnp.maximum(pm, 1e-300)), 0.0
     )
     return {
-        # replicated upper bound; per-shard true values ride the
-        # sharded num_segments vector (sliced at fetch time)
-        "num_segments": jax.lax.pmax(num_segments, axis).astype(
-            jnp.int64
-        ),
+        # the largest shard's segment count (replicated)
+        "num_segments": jax.lax.pmax(num_segments, axis),
         "num_groups": num_groups,
         "total": total,
         "unique": unique,
@@ -612,18 +650,17 @@ def _sharded_spill2_fn(mesh, axis: str, cap: int):
         (r1, r2), padding_received, overflow = _bucketed_all_to_all(
             axis, ndev, cap, bucket, (k1, k2)
         )
-        num_segments, counts, group_lanes, gmask = _segment_count_lanes(
+        num_segments, sorted_lanes, count_at, live = _segment_count_lanes(
             (r1, r2), padding_received.astype(jnp.int64)
         )
         scalars = _sharded_scalar_block(
-            axis, num_segments, counts, gmask, jnp.int64(0)
+            axis, num_segments, count_at, live, jnp.int64(0)
         )
         return (
             scalars,
-            group_lanes[0],
-            group_lanes[1],
-            counts,
-            num_segments.astype(jnp.int32)[None],  # (ndev,) global
+            sorted_lanes[0],
+            sorted_lanes[1],
+            count_at,
             overflow,
         )
 
@@ -631,7 +668,7 @@ def _sharded_spill2_fn(mesh, axis: str, cap: int):
         per_shard,
         mesh=mesh,
         in_specs=(P(axis), P(axis)),
-        out_specs=(P(), P(axis), P(axis), P(axis), P(axis), P()),
+        out_specs=(P(), P(axis), P(axis), P(axis), P()),
         check_vma=False,
     )
     return jax.jit(sharded)
@@ -640,8 +677,11 @@ def _sharded_spill2_fn(mesh, axis: str, cap: int):
 class DeviceFrequencies(FrequenciesAndNumRows):
     """FrequenciesAndNumRows whose groups live ON DEVICE.
 
-    Count metrics read precomputed scalars; ``keys``/``counts`` fetch
-    and decode lazily (only persistence, incremental merge, and
+    Count metrics read precomputed scalars. The device holds the
+    finalize's position-space arrays — the sorted key lane(s) and
+    ``count_at``, a group's count at its first sorted position and 0
+    elsewhere; ``keys``/``counts`` fetch them and compact to one entry
+    per group lazily (only persistence, incremental merge, and
     MutualInformation ever need the values). The null group, if any, is
     a host scalar appended on access."""
 
@@ -650,8 +690,8 @@ class DeviceFrequencies(FrequenciesAndNumRows):
         columns: Tuple[str, ...],
         values_dtype: np.dtype,
         scalars: Dict[str, object],
-        group_keys,
-        counts,
+        sorted_keys,  # one sorted key lane, or a tuple of lanes
+        count_at,
         null_rows: int,
         include_nulls: bool,
         joint=None,  # (dictionaries, sizes): multi-column joint codes
@@ -664,7 +704,6 @@ class DeviceFrequencies(FrequenciesAndNumRows):
         # fetch and inherits keys/non_null_group_mask, incl. caching)
         self._keys = None
         self._lazy = None
-        self._num_segments = int(scalars["num_segments"])
         self._value_groups = int(scalars["num_groups"])
         self._unique = int(scalars["unique"])
         self._entropy = float(scalars["entropy"])
@@ -673,8 +712,11 @@ class DeviceFrequencies(FrequenciesAndNumRows):
         self.num_rows = int(scalars["total"]) + self._null_rows
         # sharded path only: analytically-reconstructed int64.max group
         self._legit_max = int(scalars.get("legit_max", 0))
-        self._dev = (group_keys, counts)
+        if not isinstance(sorted_keys, tuple):
+            sorted_keys = (sorted_keys,)
+        self._dev = (sorted_keys, count_at)
         self._keys_host: Optional[np.ndarray] = None
+        self._keys_host2: Optional[np.ndarray] = None  # two-lane joints
         self._counts_host: Optional[np.ndarray] = None
 
     # -- FrequenciesAndNumRows surface ---------------------------------
@@ -688,22 +730,32 @@ class DeviceFrequencies(FrequenciesAndNumRows):
         return self._value_groups + (1 if self._has_null_group else 0)
 
     def _fetch(self) -> None:
+        """One packed fetch of the position-space arrays (sharded ones
+        gather), compacted on the host: on a v5e chip that took 1.0 s
+        for 2^24 slots where a jitted compaction (a scatter) and its
+        fetch took 2.8 s (PERF.md §6)."""
         if self._counts_host is None:
             from deequ_tpu.engine.pack import packed_device_get
 
-            gk, c = packed_device_get(self._dev)
-            s = self._num_segments
-            raw_keys = np.asarray(gk)[:s]
-            raw_counts = np.asarray(c)[:s]
-            live = raw_counts > 0  # drops a zeroed sentinel segment
-            self._keys_host = raw_keys[live]
-            self._counts_host = raw_counts[live].astype(np.int64)
+            lanes, counts = _live_groups(*packed_device_get(self._dev))
+            if self._legit_max > 0:  # the sharded int64.max group
+                lanes = (np.append(lanes[0], _SENTINEL),)
+                counts = np.append(counts, np.int64(self._legit_max))
+            self._keys_host = lanes[0]
+            if len(lanes) > 1:
+                self._keys_host2 = lanes[1]
+            self._counts_host = counts
         self._set_joint_lazy()
 
     def _set_joint_lazy(self) -> None:
         """Arm the base class's cached joint decode over the fetched
-        keys (shared by the single-device and sharded fetches)."""
-        if self._joint is not None and self._lazy is None:
+        keys of a one-lane joint (two-lane joints decode in
+        TwoLaneDeviceFrequencies.keys)."""
+        if (
+            self._joint is not None
+            and self._lazy is None
+            and self._keys_host2 is None
+        ):
             dictionaries, sizes = self._joint
             self._lazy = (
                 self._keys_host.astype(np.int64),
@@ -781,19 +833,17 @@ class DeviceFrequencies(FrequenciesAndNumRows):
     def top_groups(self, k: int) -> Tuple[np.ndarray, np.ndarray]:
         if self._joint is not None:  # multi-column: host decode path
             return FrequenciesAndNumRows.top_groups(self, k)
-        gk, c = self._dev
-        kk = min(k, self._num_segments)
+        (sorted_keys,), count_at = self._dev
+        kk = min(k, self._value_groups)
         pairs = []
         if kk > 0:
             from deequ_tpu.engine.pack import packed_device_get
 
             tc, tkeys = packed_device_get(
-                _topk_fn(c, gk, np.int32(self._num_segments), kk)
+                _topk_fn(count_at, sorted_keys, kk)
             )
-            tc = np.asarray(tc)
-            live = tc > 0  # zeroed sentinel segment never bins
-            decoded = self._decode_keys(np.asarray(tkeys)[live])
-            pairs = list(zip(decoded, tc[live].astype(np.int64)))
+            decoded = self._decode_keys(np.asarray(tkeys))
+            pairs = list(zip(decoded, np.asarray(tc).astype(np.int64)))
         return _pack_top_pairs(
             pairs, k, self._null_rows if self._has_null_group else 0
         )
@@ -808,9 +858,9 @@ class TwoLaneDeviceFrequencies(DeviceFrequencies):
         self,
         columns,
         scalars,
-        group_hi,
-        group_lo,
-        counts,
+        sorted_hi,
+        sorted_lo,
+        count_at,
         dictionaries,
         sizes,
         split: int,
@@ -819,31 +869,13 @@ class TwoLaneDeviceFrequencies(DeviceFrequencies):
             columns,
             np.dtype(np.int64),
             scalars,
-            (group_hi, group_lo),
-            counts,
+            (sorted_hi, sorted_lo),
+            count_at,
             0,
             False,
             joint=(list(dictionaries), list(sizes)),
         )
         self._split = split
-        self._keys_host2: Optional[np.ndarray] = None
-
-    def _fetch(self) -> None:
-        if self._counts_host is None:
-            from deequ_tpu.engine.pack import packed_device_get
-
-            # one packed fetch for all three arrays
-            gh, gl, c = packed_device_get(
-                (self._dev[0][0], self._dev[0][1], self._dev[1])
-            )
-            s = self._num_segments
-            raw_hi = np.asarray(gh)[:s]
-            raw_lo = np.asarray(gl)[:s]
-            raw_counts = np.asarray(c)[:s]
-            live = raw_counts > 0
-            self._keys_host = raw_hi[live]
-            self._keys_host2 = raw_lo[live]
-            self._counts_host = raw_counts[live].astype(np.int64)
 
     @property
     def keys(self) -> np.ndarray:
@@ -887,39 +919,9 @@ class TwoLaneDeviceFrequencies(DeviceFrequencies):
     # instance with _joint set, which this class always has
 
 
-class ShardedTwoLaneDeviceFrequencies(TwoLaneDeviceFrequencies):
-    """TwoLaneDeviceFrequencies whose groups live SHARDED across a
-    mesh (joint key spaces > 2^62 under a mesh): both key lanes fetch
-    per shard, sliced at each shard's true segment count."""
-
-    def _fetch(self) -> None:
-        if self._counts_host is None:
-            (gh_flat, gl_flat), gc_flat = self._dev[0], self._dev[1]
-            gh = np.asarray(gh_flat)
-            gl = np.asarray(gl_flat)
-            gc = np.asarray(gc_flat)
-            segs = np.asarray(self._segs)
-            ndev = len(segs)
-            gh = gh.reshape(ndev, -1)
-            gl = gl.reshape(ndev, -1)
-            gc = gc.reshape(ndev, -1)
-            hi_parts, lo_parts, count_parts = [], [], []
-            for shard in range(ndev):
-                s = int(segs[shard])
-                live = gc[shard][:s] > 0
-                hi_parts.append(gh[shard][:s][live])
-                lo_parts.append(gl[shard][:s][live])
-                count_parts.append(gc[shard][:s][live])
-            self._keys_host = np.concatenate(hi_parts)
-            self._keys_host2 = np.concatenate(lo_parts)
-            self._counts_host = np.concatenate(count_parts).astype(
-                np.int64
-            )
-
-
 def _sharded_spill_joint2_frequencies(
     dataset: Dataset, plan, engine, dictionaries, sizes, split, pred
-) -> "ShardedTwoLaneDeviceFrequencies":
+) -> "TwoLaneDeviceFrequencies":
     """Meshed TWO-LANE joint spill (joint key spaces > 2^62 under a
     mesh — docs/COVERAGE.md known-gap, VERDICT r4 next #4): the same
     hash-bucket all_to_all shuffle with BOTH lanes riding the shared
@@ -949,10 +951,10 @@ def _sharded_spill_joint2_frequencies(
             sizes2,
         )
 
-    scalars, g_hi, g_lo, g_counts, segs_host = _sharded_shuffle2(
+    scalars, g_hi, g_lo, g_counts = _sharded_shuffle2(
         dataset, engine, needed, build, label=f"joint2 {columns!r}"
     )
-    state = ShardedTwoLaneDeviceFrequencies(
+    return TwoLaneDeviceFrequencies(
         plan.columns,
         scalars,
         g_hi,
@@ -962,8 +964,6 @@ def _sharded_spill_joint2_frequencies(
         list(sizes),
         split,
     )
-    state._segs = segs_host
-    return state
 
 
 def split_joint_lanes(sizes) -> Optional[int]:
@@ -991,36 +991,10 @@ def split_joint_lanes(sizes) -> Optional[int]:
 
 class ShardedDeviceFrequencies(DeviceFrequencies):
     """DeviceFrequencies whose groups live SHARDED across a mesh: each
-    device holds the (keys, counts, num_segments) of its disjoint hash
-    range (nothing is replicated); fetching is a filtered concatenation
-    plus the analytically-reconstructed int64.max group, if any."""
-
-    def _fetch(self) -> None:
-        if self._counts_host is None:
-            gk_flat, gc_flat, segs = (
-                np.asarray(x) for x in self._dev
-            )
-            ndev = len(segs)
-            gk = gk_flat.reshape(ndev, -1)
-            gc = gc_flat.reshape(ndev, -1)
-            keys_parts, count_parts = [], []
-            for shard in range(ndev):
-                s = int(segs[shard])
-                raw_k = gk[shard][:s]
-                raw_c = gc[shard][:s]
-                live = raw_c > 0
-                keys_parts.append(raw_k[live])
-                count_parts.append(raw_c[live])
-            if self._legit_max > 0:
-                keys_parts.append(np.array([_SENTINEL], dtype=np.uint64))
-                count_parts.append(
-                    np.array([self._legit_max], dtype=np.int64)
-                )
-            self._keys_host = np.concatenate(keys_parts)
-            self._counts_host = np.concatenate(count_parts).astype(
-                np.int64
-            )
-        self._set_joint_lazy()
+    device holds the sorted keys and position-space counts of its
+    disjoint hash range (nothing is replicated); the inherited fetch
+    compacts their shard-major concatenation and appends the
+    analytically-reconstructed int64.max group, if any."""
 
     def top_groups(self, k: int):
         if self._joint is not None:  # multi-column: host decode path
@@ -1194,7 +1168,7 @@ def _sharded_shuffle(
     bucketed all_to_all shuffle, and the overflow check.
     ``build(flat)`` -> (keys, n_sentinel, n_null).
 
-    Returns (scalars, g_keys, g_counts, segs_host, n_null_host);
+    Returns (scalars, g_keys, g_counts, n_null_host);
     raises SpillOverflow when a hash bucket exceeds its static
     capacity (the caller falls back to Arrow). Staging materializes
     every needed column: one traversal."""
@@ -1207,22 +1181,23 @@ def _sharded_shuffle(
             dataset, engine, needed, extra_arrays
         )
         keys, n_sentinel, n_null = jax.jit(build)(flat)
-        out = _sharded_spill_fn(mesh, axis, cap)(keys, n_sentinel, n_null)
-        scalars, g_keys, g_counts, g_segs, overflow, n_null_global = out
-        scalars, overflow_host, n_null_host, segs_host = packed_device_get(
-            (scalars, overflow, n_null_global, np.asarray(g_segs))
+        out = _run_finalize(
+            _sharded_spill_fn(mesh, axis, cap), keys, n_sentinel, n_null
+        )
+        scalars, g_keys, g_counts, overflow, n_null_global = out
+        scalars, overflow_host, n_null_host = packed_device_get(
+            (scalars, overflow, n_null_global)
         )
     if int(overflow_host) > 0:
         raise SpillOverflow(
             f"hash bucket exceeded capacity {cap} on {label}"
         )
-    return scalars, g_keys, g_counts, segs_host, int(n_null_host)
+    return scalars, g_keys, g_counts, int(n_null_host)
 
 
 def _sharded_shuffle2(dataset, engine, needed, build, label: str):
     """Two-lane twin of _sharded_shuffle: ``build(flat)`` ->
-    (k1, k2, n_sentinel). Returns (scalars, g_hi, g_lo, g_counts,
-    segs_host)."""
+    (k1, k2, n_sentinel). Returns (scalars, g_hi, g_lo, g_counts)."""
     import jax
 
     from deequ_tpu.engine.pack import packed_device_get
@@ -1232,16 +1207,14 @@ def _sharded_shuffle2(dataset, engine, needed, build, label: str):
             dataset, engine, needed
         )
         k1, k2, _ = jax.jit(build)(flat)
-        out = _sharded_spill2_fn(mesh, axis, cap)(k1, k2)
-        scalars, g_hi, g_lo, g_counts, g_segs, overflow = out
-        scalars, overflow_host, segs_host = packed_device_get(
-            (scalars, overflow, np.asarray(g_segs))
-        )
+        out = _run_finalize(_sharded_spill2_fn(mesh, axis, cap), k1, k2)
+        scalars, g_hi, g_lo, g_counts, overflow = out
+        scalars, overflow_host = packed_device_get((scalars, overflow))
     if int(overflow_host) > 0:
         raise SpillOverflow(
             f"hash bucket exceeded capacity {cap} on {label}"
         )
-    return scalars, g_hi, g_lo, g_counts, segs_host
+    return scalars, g_hi, g_lo, g_counts
 
 
 def _sharded_spill_joint_frequencies(
@@ -1279,10 +1252,10 @@ def _sharded_spill_joint_frequencies(
         )
         return keys, n_sentinel, jnp.int64(0)  # no null group (gated)
 
-    scalars, g_keys, g_counts, segs_host, _ = _sharded_shuffle(
+    scalars, g_keys, g_counts, _ = _sharded_shuffle(
         dataset, engine, needed, build, label=f"joint {columns!r}"
     )
-    state = ShardedDeviceFrequencies(
+    return ShardedDeviceFrequencies(
         plan.columns,
         np.dtype(np.int64),
         scalars,
@@ -1292,8 +1265,6 @@ def _sharded_spill_joint_frequencies(
         False,
         joint=(list(dictionaries), list(sizes)),
     )
-    state._dev = (g_keys, g_counts, segs_host)
-    return state
 
 
 def device_spill_joint_frequencies(
@@ -1394,8 +1365,8 @@ def device_spill_joint_frequencies(
                     [keys2, jnp.full(pad, _SENTINEL, dtype=keys2.dtype)]
                 )
             with _sort_span():
-                scalars, group_hi, group_lo, counts = _finalize2_fn()(
-                    keys, keys2, n_sentinel
+                scalars, group_hi, group_lo, counts = _run_finalize(
+                    _finalize2_fn(), keys, keys2, n_sentinel
                 )
                 scalars = packed_device_get(scalars)
             return TwoLaneDeviceFrequencies(
@@ -1410,7 +1381,9 @@ def device_spill_joint_frequencies(
             )
 
         with _sort_span():
-            scalars, group_keys, counts = _finalize_fn()(keys, n_sentinel)
+            scalars, group_keys, counts = _run_finalize(
+                _finalize_fn(), keys, n_sentinel
+            )
             scalars = packed_device_get(scalars)
         return DeviceFrequencies(
             plan.columns,
@@ -1536,7 +1509,9 @@ def device_spill_frequencies(
         from deequ_tpu.engine.pack import packed_device_get
 
         with _sort_span():
-            scalars, group_keys, counts = _finalize_fn()(keys, n_sentinel)
+            scalars, group_keys, counts = _run_finalize(
+                _finalize_fn(), keys, n_sentinel
+            )
             scalars, n_null_host = packed_device_get((scalars, n_null))
         return DeviceFrequencies(
             plan.columns,
@@ -1605,11 +1580,11 @@ def _sharded_spill_frequencies(
             batch[f"{column}::values"], batch[f"{column}::mask"], rows
         )
 
-    scalars, g_keys, g_counts, segs_host, n_null_host = _sharded_shuffle(
+    scalars, g_keys, g_counts, n_null_host = _sharded_shuffle(
         dataset, engine, needed, build, label=repr(column),
         extra_arrays=extra,
     )
-    state = ShardedDeviceFrequencies(
+    return ShardedDeviceFrequencies(
         plan.columns,
         values_dtype,
         scalars,
@@ -1618,8 +1593,6 @@ def _sharded_spill_frequencies(
         n_null_host,
         bool(plan.include_nulls),
     )
-    state._dev = (g_keys, g_counts, segs_host)
-    return state
 
 
 # --------------------------------------------------------------------------
@@ -1699,19 +1672,16 @@ class LaneFrequencies(FrequenciesAndNumRows):
 
 @functools.lru_cache(maxsize=None)
 def _repeated_keys_fn(size: int):
-    """Jitted: the group keys whose count exceeds one, compacted into
-    ``size`` slots (single-device finalize output)."""
+    """Jitted: the sorted keys whose segment count exceeds one,
+    compacted into ``size`` slots (single-device finalize output)."""
 
-    def run(group_keys, counts, num_segments):
-        n = counts.shape[0]
-        repeated = (counts > 1) & (
-            jnp.arange(n, dtype=jnp.int32) < num_segments
-        )
+    def run(sorted_keys, count_at):
+        repeated = count_at > 1
         slot = jnp.cumsum(repeated.astype(jnp.int32)) - 1
         return (
-            jnp.zeros(size, dtype=group_keys.dtype)
+            jnp.zeros(size, dtype=sorted_keys.dtype)
             .at[jnp.where(repeated, slot, size)]
-            .set(group_keys, mode="drop")
+            .set(sorted_keys, mode="drop")
         )
 
     return jax.jit(run)
@@ -1726,11 +1696,10 @@ def _repeated_hashes(freq, repeated: int) -> np.ndarray:
     else:
         from deequ_tpu.engine.pack import packed_device_get
 
-        group_keys, counts = freq._dev
+        _count_compaction()
+        (sorted_keys,), count_at = freq._dev
         (out,) = packed_device_get((
-            _repeated_keys_fn(_pow2_len(repeated))(
-                group_keys, counts, np.int32(freq._num_segments)
-            ),
+            _repeated_keys_fn(_pow2_len(repeated))(sorted_keys, count_at),
         ))
         out = np.asarray(out)[:repeated]
     return np.sort(out.astype(np.uint64))
@@ -2025,7 +1994,9 @@ def single_collector_spec(
             (buf,), off, ns, nn = state
             # unwritten pow2 tail slots hold the sentinel from init
             ns_total = ns + (jnp.int64(local_cap) - off)
-            scalars, group_keys, counts = _finalize_fn()(buf, ns_total)
+            scalars, group_keys, counts = _run_finalize(
+                _finalize_fn(), buf, ns_total
+            )
 
             def build(fetched):
                 scalars_h, n_null_h = fetched
@@ -2046,13 +2017,14 @@ def single_collector_spec(
             (buf,), off, ns, nn = state
             # per-shard unwritten slots x ndev shards
             ns_total = ns + (jnp.int64(ndev * local_cap) - off * ndev)
-            out = _sharded_spill_fn(mesh, axis, cap)(
-                buf.reshape(-1), ns_total, nn
+            out = _run_finalize(
+                _sharded_spill_fn(mesh, axis, cap),
+                buf.reshape(-1), ns_total, nn,
             )
-            scalars, g_keys, g_counts, g_segs, overflow, n_null_g = out
+            scalars, g_keys, g_counts, overflow, n_null_g = out
 
             def build(fetched):
-                scalars_h, overflow_h, n_null_h, segs_h = fetched
+                scalars_h, overflow_h, n_null_h = fetched
                 if int(overflow_h) > 0:
                     raise SpillOverflow(
                         f"hash bucket exceeded capacity {cap} on "
@@ -2062,12 +2034,11 @@ def single_collector_spec(
                     plan.columns, values_dtype, scalars_h, g_keys,
                     g_counts, int(n_null_h), include_nulls,
                 )
-                st._dev = (g_keys, g_counts, segs_h)
                 if lane:
                     return exact_lane_frequencies(st, dataset, plan)
                 return st
 
-            return (scalars, overflow, n_null_g, g_segs), build
+            return (scalars, overflow, n_null_g), build
 
     return CollectorSpec(plan, requests, ops, "device-sort", dispatch)
 
@@ -2140,8 +2111,8 @@ def joint_collector_spec(
             def dispatch(state):
                 (b1, b2), off, ns, _nn = state
                 ns_total = ns + (jnp.int64(local_cap) - off)
-                scalars, g_hi, g_lo, counts = _finalize2_fn()(
-                    b1, b2, ns_total
+                scalars, g_hi, g_lo, counts = _run_finalize(
+                    _finalize2_fn(), b1, b2, ns_total
                 )
 
                 def build(fetched):
@@ -2157,8 +2128,8 @@ def joint_collector_spec(
             def dispatch(state):
                 (buf,), off, ns, _nn = state
                 ns_total = ns + (jnp.int64(local_cap) - off)
-                scalars, group_keys, counts = _finalize_fn()(
-                    buf, ns_total
+                scalars, group_keys, counts = _run_finalize(
+                    _finalize_fn(), buf, ns_total
                 )
 
                 def build(fetched):
@@ -2177,26 +2148,25 @@ def joint_collector_spec(
                 (b1, b2), _off, _ns, _nn = state
                 # the 2-lane shuffle drops sentinel rows itself; no
                 # correction scalar enters (matching _sharded_shuffle2)
-                out = _sharded_spill2_fn(mesh, axis, cap)(
-                    b1.reshape(-1), b2.reshape(-1)
+                out = _run_finalize(
+                    _sharded_spill2_fn(mesh, axis, cap),
+                    b1.reshape(-1), b2.reshape(-1),
                 )
-                scalars, g_hi, g_lo, g_counts, g_segs, overflow = out
+                scalars, g_hi, g_lo, g_counts, overflow = out
 
                 def build(fetched):
-                    scalars_h, overflow_h, segs_h = fetched
+                    scalars_h, overflow_h = fetched
                     if int(overflow_h) > 0:
                         raise SpillOverflow(
                             f"hash bucket exceeded capacity {cap} on "
                             f"joint2 {columns!r}"
                         )
-                    st = ShardedTwoLaneDeviceFrequencies(
+                    return TwoLaneDeviceFrequencies(
                         plan.columns, scalars_h, g_hi, g_lo, g_counts,
                         joint[0], joint[1], split,
                     )
-                    st._segs = segs_h
-                    return st
 
-                return (scalars, overflow, g_segs), build
+                return (scalars, overflow), build
 
         else:
 
@@ -2205,26 +2175,25 @@ def joint_collector_spec(
                 ns_total = ns + (
                     jnp.int64(ndev * local_cap) - off * ndev
                 )
-                out = _sharded_spill_fn(mesh, axis, cap)(
-                    buf.reshape(-1), ns_total, jnp.int64(0)
+                out = _run_finalize(
+                    _sharded_spill_fn(mesh, axis, cap),
+                    buf.reshape(-1), ns_total, jnp.int64(0),
                 )
-                scalars, g_keys, g_counts, g_segs, overflow, _nng = out
+                scalars, g_keys, g_counts, overflow, _nng = out
 
                 def build(fetched):
-                    scalars_h, overflow_h, segs_h = fetched
+                    scalars_h, overflow_h = fetched
                     if int(overflow_h) > 0:
                         raise SpillOverflow(
                             f"hash bucket exceeded capacity {cap} on "
                             f"joint {columns!r}"
                         )
-                    st = ShardedDeviceFrequencies(
+                    return ShardedDeviceFrequencies(
                         plan.columns, np.dtype(np.int64), scalars_h,
                         g_keys, g_counts, 0, False, joint=joint,
                     )
-                    st._dev = (g_keys, g_counts, segs_h)
-                    return st
 
-                return (scalars, overflow, g_segs), build
+                return (scalars, overflow), build
 
     return CollectorSpec(
         plan, requests, ops, "device-sort-joint", dispatch
@@ -2252,33 +2221,24 @@ class MultihostDeviceFrequencies(ShardedDeviceFrequencies):
     Distinctness/Entropy) are replicated and safe to read anywhere."""
 
     def _local_live_pairs(self):
-        """(keys, counts) concatenated over THIS process's shards."""
-        g_keys, g_counts, g_segs = self._dev
-        segs_by_dev = {
-            s.device: int(np.asarray(s.data)[0])
-            for s in g_segs.addressable_shards
-        }
+        """(keys, counts) of the live groups over THIS process's
+        shards."""
+        (g_keys,), g_counts = self._dev
         counts_by_dev = {
             s.device: np.asarray(s.data)
             for s in g_counts.addressable_shards
         }
-        keys_parts, count_parts = [], []
-        for s in g_keys.addressable_shards:
-            seg = segs_by_dev[s.device]
-            raw_k = np.asarray(s.data)[:seg]
-            raw_c = counts_by_dev[s.device][:seg]
-            live = raw_c > 0
-            keys_parts.append(raw_k[live])
-            count_parts.append(raw_c[live].astype(np.int64))
-        if not keys_parts:
+        shards = g_keys.addressable_shards
+        if not shards:
             return (
                 np.zeros(0, np.uint64),
                 np.zeros(0, np.int64),
             )
-        return (
-            np.concatenate(keys_parts),
-            np.concatenate(count_parts),
+        (keys,), counts = _live_groups(
+            (np.concatenate([np.asarray(s.data) for s in shards]),),
+            np.concatenate([counts_by_dev[s.device] for s in shards]),
         )
+        return keys, counts
 
     @staticmethod
     def _allgather_varlen(keys: np.ndarray, counts: np.ndarray):
@@ -2498,12 +2458,13 @@ def multihost_spill_frequencies(
             sharding, np.asarray(keys_local)
         )
         cap = 1 << max(8, ((4 * per_dev) // ndev - 1).bit_length())
-        out = _sharded_spill_fn(mesh, axis, cap)(
+        out = _run_finalize(
+            _sharded_spill_fn(mesh, axis, cap),
             g_keys,
             jax.numpy.int64(n_sent),
             jax.numpy.int64(n_null),
         )
-        scalars, gk, gc, g_segs, overflow, _ = out
+        scalars, gk, gc, overflow, _ = out
         host_scalars = {
             k: np.asarray(jax.device_get(v)) for k, v in scalars.items()
         }
@@ -2512,7 +2473,7 @@ def multihost_spill_frequencies(
                 f"hash bucket exceeded capacity {cap} on {column!r} "
                 "(multihost)"
             )
-        state = MultihostDeviceFrequencies(
+        return MultihostDeviceFrequencies(
             plan.columns,
             values_dtype,
             host_scalars,
@@ -2521,5 +2482,3 @@ def multihost_spill_frequencies(
             n_null,
             bool(plan.include_nulls),
         )
-        state._dev = (gk, gc, g_segs)
-        return state

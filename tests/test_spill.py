@@ -619,8 +619,10 @@ class TestMeshedTwoLaneJoint:
                 plan
             ]
         assert isinstance(
-            dev, spill_mod.ShardedTwoLaneDeviceFrequencies
+            dev, spill_mod.TwoLaneDeviceFrequencies
         ), type(dev)
+        # the groups stayed sharded across the mesh until the fetch
+        assert len(dev._dev[1].sharding.device_set) == cpu_mesh.size
         with config.configure(device_spill_grouping=False):
             host = compute_many_frequencies(ds, [plan])[plan]
         got = sorted(

@@ -172,6 +172,18 @@ def test_spill_finalize_u64(one_chip):
     spill._finalize_fn().lower(keys, n_sentinel).compile()
 
 
+def test_spill_finalize_u64_lane_grouping(one_chip):
+    """The same finalize at 2^24 keys, the shape of a lane grouping over
+    12M rows (TPC-DS customer at SF1000): it compiles, and the count in
+    sorted-position space leaves no scatter in the chip's program."""
+    from deequ_tpu.analyzers import spill
+
+    keys = jax.ShapeDtypeStruct((1 << 24,), jnp.uint64, sharding=one_chip)
+    n_sentinel = jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip)
+    text = spill._finalize_fn().lower(keys, n_sentinel).compile().as_text()
+    assert "scatter" not in text
+
+
 def test_f64_spill_collector_tpu_branch(one_chip, monkeypatch):
     """An f64 grouping key on the TPU branch: canonical u64 bits are
     packed on the host (the chip has no f64 bitcast) and the collector
