@@ -8,7 +8,6 @@ Drives the entry points a user calls (``VerificationSuite``,
 plain numpy/pyarrow reference computed from the same arrays:
 
 - ``verify``: one mixed-constraint check, run as one fused scan;
-- ``pallas``: HLL through the Pallas scatter kernel, equal to XLA's;
 - ``profile``: the column profiler over all 50 columns;
 - ``stream``: a 2M-row slice written to parquet shards and streamed
   back with the device cache off; equal to the resident run.
@@ -502,38 +501,6 @@ def phase_stream(table, rows: int, out_dir: str, shards: int = STREAM_SHARDS):
         "resident run")
 
 
-# -- phase: pallas ----------------------------------------------------------
-
-
-def phase_pallas(ds, ref: Reference) -> None:
-    """HLL registers through the Pallas scatter kernel equal the default
-    build's (``config.pallas_scatter``; the default is the MXU build on
-    a TPU, the XLA scatter elsewhere): on a TPU the kernel runs or the
-    run fails, never a silent fallback."""
-    import jax
-
-    from deequ_tpu import config
-    from deequ_tpu.analyzers import AnalysisRunner, ApproxCountDistinct
-    from deequ_tpu.sketches import hll
-
-    analyzers = [ApproxCountDistinct(c) for c in ("k1", "ext1", "c1", "m1")]
-    default = "mxu" if jax.default_backend() == "tpu" else "xla"
-    runs = {}
-    for flag in (False, True):
-        with config.configure(pallas_scatter=flag):
-            runs[flag] = AnalysisRunner.do_analysis_run(ds, analyzers)
-            token = hll.register_build_path()
-        if token != ("pallas" if flag else default):
-            raise Mismatch(f"pallas: pallas_scatter={flag} ran {token}")
-    for a in analyzers:
-        got = runs[True].metric(a).value.get()
-        compare(f"pallas {a}", got, runs[False].metric(a).value.get(),
-                _exact)
-        compare(f"pallas {a}", got, ref.distinct(a.column), _hll)
-    log(f"[pallas] kernel registers equal the XLA scatter's on "
-        f"{len(analyzers)} columns")
-
-
 # -- phase: mesh (--chips 4) -------------------------------------------------
 
 
@@ -593,7 +560,6 @@ def main(argv=None) -> int:
         timed_phase("mesh", meter, phase_mesh, ds, ref, jax.devices()[:4])
     else:
         timed_phase("verify", meter, phase_verify, ds, ref)
-        timed_phase("pallas", meter, phase_pallas, ds, ref)
         timed_phase("profile", meter, phase_profile, ds, ref)
         timed_phase("stream", meter, phase_stream, table, STREAM_ROWS,
                     OUT_DIR)

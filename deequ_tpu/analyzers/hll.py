@@ -70,7 +70,7 @@ class ApproxCountDistinct(ScanShareableAnalyzer):
         if kind == Kind.STRING and dataset.string_lane(col):
             consts = None
 
-            def registers_of(batch, c, mask, prev):
+            def registers_of(batch, c, mask):
                 # the hashed-string lane: each row ships its own hash
                 h1, h2 = hll.lane_hash_pair(batch[f"{col}::{LANE_HASH}"])
                 return hll.registers_from_hash_pair(h1, h2, mask)
@@ -83,7 +83,7 @@ class ApproxCountDistinct(ScanShareableAnalyzer):
             )
             consts = {"h1": pad_pow2(lut1_host), "h2": pad_pow2(lut2_host)}
 
-            def registers_of(batch, c, mask, prev):
+            def registers_of(batch, c, mask):
                 lut1, lut2 = c["h1"], c["h2"]
                 if lut1.shape[0] <= hll.PRESENCE_DICT_CAP:
                     # small dictionary: presence compare-reduce beats
@@ -104,20 +104,13 @@ class ApproxCountDistinct(ScanShareableAnalyzer):
         else:
             consts = None
 
-            def registers_of(batch, c, mask, prev):
-                # adaptive C=1 group: sorted-dedup when the carried
-                # registers say mid-cardinality (sketches/hll.py)
-                return hll.numeric_registers_adaptive(
-                    batch[f"{col}::values"][None, :],
-                    mask[None, :],
-                    prev[None, :],
-                )[0]
+            def registers_of(batch, c, mask):
+                h1, h2 = hll.hash_pair_numeric(batch[f"{col}::values"])
+                return hll.registers_from_hash_pair(h1, h2, mask)
 
         def update(state: ApproxCountDistinctState, batch, consts_in=None):
             mask = batch[f"{col}::mask"] & _row_mask(batch, where_fn)
-            regs = registers_of(
-                batch, consts_in, mask, state.registers
-            )
+            regs = registers_of(batch, consts_in, mask)
             return ApproxCountDistinctState(
                 jnp.maximum(state.registers, regs)
             )

@@ -82,29 +82,6 @@ class Options:
     # re-scan path — kept for differential testing and as an escape
     # hatch
     one_pass_spill: bool = True
-    # route the HLL register scatter-max through the measured unroll-16
-    # Pallas SMEM kernel (tools/scatter_probe.py: 1.1-1.15x over the XLA
-    # scatter at (2^21, M=2^14)) on a TPU, where a kernel that fails
-    # to compile raises; off, a TPU builds them on the MXU
-    # (sketches/hll_mxu.py) and other backends scatter on XLA. Registers
-    # are bit-identical either way (tests/test_fastpath_differential.py,
-    # and on the chip chip_smoke.py's pallas phase). Off by default until
-    # the production-shape probe artifact justifies flipping it
-    # (docs/PERF.md "Pallas scatter")
-    pallas_scatter: bool = (
-        os.environ.get("DEEQU_TPU_PALLAS_SCATTER", "0") == "1"
-    )
-    # widened sorted-dedup HLL gate (sketches/hll.py): integer columns
-    # whose O(1) range probe FAILS (unknown or wide declared range) may
-    # still ride the shared KLL sort's sorted-dedup register builder
-    # when their carried-register cardinality estimate says
-    # mid-cardinality AND the batch's values fit the f32 mantissa
-    # (both checked in-kernel; a mispredicted estimate falls back to
-    # the full scatter inside the branch). False restores the
-    # range-probe-only gate — kept as the differential reference
-    hll_dedup_widening: bool = (
-        os.environ.get("DEEQU_TPU_HLL_DEDUP_WIDENING", "1") != "0"
-    )
     # per-column wire codecs on the streamed packed wire
     # (engine/wire.py, docs/PERF.md "Wire diet"): each column's wire
     # dtype is resolved ONCE per run from parquet statistics or a
@@ -304,7 +281,7 @@ class Options:
     # scan coalescing (docs/SERVICE.md "Scan coalescing"): compatible
     # queued runs targeting the same dataset_key share ONE superset
     # scan, each tenant's AnalyzerContext sliced back out. Opt-in (like
-    # pallas_scatter/isolated_execution): default-off keeps existing
+    # isolated_execution): default-off keeps existing
     # solo-run latency/ordering semantics untouched
     service_coalesce: bool = (
         os.environ.get("DEEQU_TPU_SERVICE_COALESCE", "0") == "1"

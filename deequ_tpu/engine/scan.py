@@ -190,7 +190,7 @@ def _plan_cache_key(ops, host_slots, placement):
         tokens.append(op.cache_token)
     # static engine decisions baked into a trace ride the key too: a
     # plan traced with one HLL register build must not be served to a
-    # run with the flag (or the backend probe) flipped
+    # run whose backend probe resolves the other
     key = (
         tuple(tokens),
         tuple(host_slots),

@@ -42,7 +42,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deequ_tpu import config
 from deequ_tpu.analyzers import states as S
 from deequ_tpu.analyzers.base import ScanOps, pad_pow2
 from deequ_tpu.analyzers.basic import (
@@ -414,26 +413,7 @@ def _build_hll_group(
     members: List[Any],
     value_repr: str,  # "values" (numeric) | "codes" | "hash" (string)
     where: Optional[str],
-    kll_pool_columns: Optional[Tuple[str, ...]] = None,
-    runtime_gate_columns: Optional[Tuple[str, ...]] = None,
 ) -> ScanUnit:
-    """``kll_pool_columns``: when a KLL group with the same ``where``
-    shares the scan and covers this group's (f32-storage) columns, the
-    planner passes the KLL group's column order — the update then
-    reuses the KLL sort via the SAME memoized _kll_sorted_stack (one
-    sort per step, shared through the batch dict) and every statically
-    qualified column takes the sorted-dedup register builder
-    unconditionally: mid-cardinality columns win from batch 1,
-    high-cardinality ones pay only the unique-count probe.
-
-    ``runtime_gate_columns``: the widened gate (config
-    .hll_dedup_widening) — pooled integer columns whose O(1) range
-    probe could NOT statically prove them; they dispatch per batch on
-    the carried-register cardinality estimate plus an in-kernel f32
-    mantissa-exactness check (sketches/hll.py
-    gated_column_registers_from_sorted), falling back to the plain
-    scatter whenever either check — or the inner U<=D probe — says
-    no."""
     columns, member_cols = _index_members(members)
     where_fn, where_reqs = _compile_where(where, dataset)
     requests = [
@@ -441,14 +421,6 @@ def _build_hll_group(
         for c in columns
         for r in (ColumnRequest(c, value_repr), ColumnRequest(c, "mask"))
     ] + where_reqs
-    if kll_pool_columns:
-        # the pooled sort reads EVERY kll column: request them so the
-        # batch stays complete even if the kll unit itself degrades
-        requests += [
-            r
-            for c in kll_pool_columns
-            for r in (ColumnRequest(c, "values"), ColumnRequest(c, "mask"))
-        ]
     C = len(columns)
 
     consts = None
@@ -513,38 +485,11 @@ def _build_hll_group(
                 _shared_stack(batch, columns, LANE_HASH)
             )
             regs = hll.registers_from_hash_pair_stacked(h1, h2, masks)
-        elif kll_pool_columns:
-            # reuse the KLL group's sort through the shared-batch memo
-            # (one sort per step for both units, no CSE reliance)
-            sorted_all, _, _ = _kll_sorted_stack(
-                batch, kll_pool_columns, where_fn, where
-            )
-            row_of = {c: i for i, c in enumerate(kll_pool_columns)}
-            gated = frozenset(runtime_gate_columns or ())
-            regs = jnp.stack(
-                [
-                    hll.gated_column_registers_from_sorted(
-                        sorted_all[row_of[c]],
-                        batch[f"{c}::values"],
-                        masks[i],
-                        registers[i],
-                    )
-                    if c in gated
-                    else hll.dedup_column_registers_from_sorted(
-                        sorted_all[row_of[c]],
-                        batch[f"{c}::values"],
-                        masks[i],
-                    )
-                    for i, c in enumerate(columns)
-                ]
-            )
         else:
-            x = _shared_stack(batch, columns, "values")
-            # adaptive: sorted-dedup for mid-cardinality groups (gated
-            # by the carried registers), full scatter otherwise
-            regs = hll.numeric_registers_adaptive(
-                x, masks, registers
+            h1, h2 = hll.hash_pair_numeric(
+                _shared_stack(batch, columns, "values")
             )
+            regs = hll.registers_from_hash_pair_stacked(h1, h2, masks)
         new_regs = jnp.maximum(registers, regs)
         if delta_cap is not None:
             return {
@@ -624,8 +569,7 @@ def _build_hll_group(
         dataset,
         columns,
         where,
-        extra=(value_repr, kll_pool_columns, runtime_gate_columns,
-               delta_cap),
+        extra=(value_repr, delta_cap),
     )
     return ScanUnit(
         members,
@@ -648,13 +592,11 @@ def _build_hll_group(
 
 
 def _kll_sorted_stack(batch, columns, where_fn, where=None):
-    """THE one construction of the KLL group's masked f32 sort — also
-    consumed by the HLL sorted-dedup path when both units share a scan.
-    Memoized into the shared batch dict (keyed by columns + where), so
-    the two units PROVABLY run one sort per step — previously both
-    emitted the construction and XLA HLO CSE was trusted to merge the
-    structurally identical subgraphs, which held only while nothing
-    perturbed either copy. Returns (sorted_x (C, B), masks, x)."""
+    """THE one construction of the KLL group's masked f32 sort,
+    memoized into the shared batch dict (keyed by columns + where), so
+    KLL groups that differ only in their sketch parameters run one
+    sort per step instead of trusting XLA's CSE to merge identical
+    subgraphs. Returns (sorted_x (C, B), masks, x)."""
     key = (
         _SHARED_PREFIX
         + "kllsort:"
@@ -1017,16 +959,6 @@ def plan_scan_units(
             groups.setdefault(key, []).append(a)
 
     units: List[ScanUnit] = []
-    # KLL groups' column orders, per where-clause: an f32 HLL group
-    # whose columns a same-where KLL group covers rides that group's
-    # sort (see _build_hll_group's kll_pool_columns)
-    kll_pools: Dict[Optional[str], Tuple[str, ...]] = {}
-    for key, members in groups.items():
-        if key[0] == "kll" and len(members) > 1:
-            cols, _ = _index_members(members)
-            prev = kll_pools.get(key[3])
-            if prev is None or len(cols) > len(prev):
-                kll_pools[key[3]] = tuple(cols)
     for key, members in groups.items():
         if len(members) == 1:
             singles.extend(members)
@@ -1041,112 +973,9 @@ def plan_scan_units(
                     _build_completeness_group(dataset, members, key[1])
                 )
             elif key[0] == "hll":
-                pool = None
-                runtime_gated: Tuple[str, ...] = ()
-                pooled_members, plain_members = members, []
-                candidate = kll_pools.get(key[3])
-                if (
-                    key[1] == "values"
-                    and candidate is not None
-                    and key[2]
-                    in ("float32", "int8", "int16", "int32", "int64")
-                ):
-                    if key[2] == "float32":
-                        cols, _ = _index_members(members)
-                        if set(cols) <= set(candidate):
-                            pool = candidate
-                    else:
-                        # integer storage rides the f32-cast pool
-                        # STATICALLY when the column's RANGE both fits
-                        # the 24-bit mantissa (cast exact; dict entries
-                        # cast back before the integral hash —
-                        # sketches/hll.py) and BOUNDS the cardinality
-                        # near the dict cap, so guaranteed-high-card
-                        # key columns keep the one stacked scatter
-                        # instead of per-column probes. Coverage is
-                        # judged per POOLED column (an unbounded
-                        # group-mate must not veto its bounded
-                        # neighbors).
-                        lim = 4 * hll.DEDUP_DICT_CAP
-                        exact = 1 << 24  # f32 mantissa
-                        cand_set = set(candidate)
-
-                        def poolable(c):
-                            r = dataset.integral_range(c)
-                            return (
-                                c in cand_set
-                                and r is not None
-                                and (r[1] - r[0]) < lim
-                                and -exact <= r[0]
-                                and r[1] <= exact
-                            )
-
-                        pooled_idx = {
-                            i
-                            for i, a in enumerate(members)
-                            if poolable(a.column)
-                        }
-                        # widened gate: KLL-covered integer columns
-                        # the probe could NOT qualify (unknown/wide
-                        # range) still join the pooled unit, gated at
-                        # RUNTIME on the carried-register estimate +
-                        # an in-batch mantissa check (sketches/hll.py
-                        # gated_column_registers_from_sorted) — the
-                        # probe's range is a cardinality PROXY; the
-                        # registers measure cardinality directly
-                        if config.options().hll_dedup_widening:
-                            gated_idx = {
-                                i
-                                for i, a in enumerate(members)
-                                if i not in pooled_idx
-                                and a.column in cand_set
-                            }
-                            if gated_idx:
-                                seen = set()
-                                runtime_gated = tuple(
-                                    a.column
-                                    for i, a in enumerate(members)
-                                    if i in gated_idx
-                                    and a.column not in seen
-                                    and not seen.add(a.column)
-                                )
-                                pooled_idx |= gated_idx
-                        if pooled_idx:
-                            pool = candidate
-                            pooled_members = [
-                                a
-                                for i, a in enumerate(members)
-                                if i in pooled_idx
-                            ]
-                            plain_members = [
-                                a
-                                for i, a in enumerate(members)
-                                if i not in pooled_idx
-                            ]
-                # build EVERY unit before appending ANY: a failure
-                # mid-way would otherwise leave the already-appended
-                # half ALSO planned as singles by the except below —
-                # the same analyzer computed twice per batch (review
-                # finding)
-                new_units = []
-                if plain_members:
-                    new_units.append(
-                        _build_hll_group(
-                            dataset, plain_members, key[1], key[3]
-                        )
-                    )
-                if pooled_members:
-                    new_units.append(
-                        _build_hll_group(
-                            dataset,
-                            pooled_members,
-                            key[1],
-                            key[3],
-                            kll_pool_columns=pool,
-                            runtime_gate_columns=runtime_gated or None,
-                        )
-                    )
-                units.extend(new_units)
+                units.append(
+                    _build_hll_group(dataset, members, key[1], key[3])
+                )
             elif key[0] == "kll":
                 units.append(
                     _build_kll_group(dataset, members, key[3])

@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import functools
 from typing import Tuple
 
 import jax
@@ -133,16 +134,13 @@ REGISTER_DTYPE = jnp.int8  # rho <= 33 fits i8: 4x fewer wire bytes than
 
 
 def register_build_path() -> str:
-    """Which register build a freshly traced plan bakes in: ``pallas``
-    (the SMEM scatter kernel, when ``config.pallas_scatter`` is set and
-    can run), ``mxu`` (the banded one-hot matmul, the TPU's default) or
-    ``xla`` (the scatter-max, every other backend). Rides the engine
-    plan-cache key so a flag flip or another backend retraces instead
-    of aliasing a stale compile. All three give the same registers."""
-    from deequ_tpu.sketches import hll_mxu, pallas_scatter
+    """Which register build a freshly traced plan bakes in: ``mxu``
+    (the banded one-hot matmul, on a TPU) or ``xla`` (the scatter-max,
+    every other backend). Rides the engine plan-cache key so another
+    backend retraces instead of aliasing a stale compile. Both give the
+    same registers."""
+    from deequ_tpu.sketches import hll_mxu
 
-    if pallas_scatter.enabled():
-        return "pallas"
     return "mxu" if hll_mxu.available() else "xla"
 
 
@@ -189,17 +187,13 @@ def _per_device(kernel, idx, rho):
 def _build_registers(idx: jnp.ndarray, rho: jnp.ndarray) -> jnp.ndarray:
     """(C, B) register indices and ranks -> (C, M) int8 registers, the
     max rank per register, through the build this backend runs."""
-    from deequ_tpu.sketches import hll_mxu, pallas_scatter
+    from deequ_tpu.sketches import hll_mxu
     from deequ_tpu.telemetry import get_telemetry
 
     path = register_build_path()
     get_telemetry().metrics.counter(f"hll.register_builds.{path}").inc()
-    if path != "xla":
-        kernel = (
-            (lambda i, r: pallas_scatter.scatter_max(i, r, M))
-            if path == "pallas"
-            else (lambda i, r: hll_mxu.registers(i, r, M))
-        )
+    if path == "mxu":
+        kernel = functools.partial(hll_mxu.registers, m=M)
         regs = (
             kernel(idx, rho)
             if _ROW_MESH.get() is None
@@ -296,266 +290,6 @@ def tiled_code_presence(
             hits.sum(axis=2, dtype=jnp.int32) if count else hits.any(axis=2)
         )
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
-
-
-# ------------------------------------------------------------------
-# adaptive sorted-dedup update for numeric columns (r5)
-# ------------------------------------------------------------------
-
-# Registers only see DISTINCT values (register = max over duplicates),
-# so a column whose per-batch distinct count U fits a static dictionary
-# can sort the batch, compact the uniques, and scatter U elements
-# instead of B. Measured on v5e (docs/PERF.md r5 table): sort 3.6 ms +
-# compaction 2.9 ms vs 15.2 ms for the full per-row scatter at
-# B = 2^21 — 2.3x for mid-cardinality columns (TPC-DS quantities,
-# cent-denominated prices). High-cardinality columns keep the full
-# scatter: the path is gated per GROUP by a linear-counting estimate
-# from the CARRIED registers, so batch 1 (empty state) and any
-# high-cardinality history never pay the sort.
-DEDUP_DICT_CAP = 16384
-
-# zeros > gate  <=>  linear-counting estimate -M*ln(zeros/M) < ~12k
-# (margin below DEDUP_DICT_CAP so the inner exact U <= D check rarely
-# has to fall back mid-branch)
-_DEDUP_ZEROS_GATE = int(M * np.exp(-0.75))
-
-
-def dedup_gate(registers: jnp.ndarray) -> jnp.ndarray:
-    """(..., M) carried registers -> (...,) bool: the state's linear-
-    counting estimate says this column is mid-cardinality. All-zero
-    registers (first batch / empty column) gate FALSE: with no
-    history the full scatter is the safe choice."""
-    zeros = jnp.sum(registers == 0, axis=-1)
-    return (zeros < M) & (zeros > _DEDUP_ZEROS_GATE)
-
-
-def _dedup_supported(dtype) -> bool:
-    """Sorted dedup needs a total order and a free sentinel: any real
-    float or integer dtype qualifies (bool is NOT an integer subtype,
-    so two-value boolean columns keep the plain scatter)."""
-    return jnp.issubdtype(dtype, jnp.floating) or jnp.issubdtype(
-        dtype, jnp.integer
-    )
-
-
-def dedup_column_registers(
-    xc: jnp.ndarray,  # (B,) values
-    maskc: jnp.ndarray,  # (B,) validity
-) -> jnp.ndarray:
-    """(M,) batch registers for ONE column via sort + unique
-    compaction. Bit-identical to the per-row scatter: the dictionary
-    entries are the batch's own values, hashed by the SAME
-    hash_pair_numeric, and max over duplicates == single occurrence.
-
-    Sentinel discipline: masked slots sort as ``sentval`` (+inf for
-    floats, iinfo.max for ints), which excludes them from the unique
-    run — a REAL sentinel-valued element (or NaN, floats only) is
-    re-added as a flagged extra dictionary slot. Exotic NaN payloads
-    collapse to the canonical NaN here (the per-row path hashes raw
-    payload bits); both orderings count NaN as one value on canonical
-    data, and states from the two paths still max-merge safely.
-
-    A column whose ACTUAL U exceeds the cap falls back to its own full
-    scatter inside the branch (correctness never depends on the
-    caller's gate estimate)."""
-    (B,) = xc.shape
-    floating = jnp.issubdtype(xc.dtype, jnp.floating)
-    D = min(DEDUP_DICT_CAP, B)
-    if floating:
-        sentval = jnp.asarray(jnp.inf, xc.dtype)
-        nan_mask = jnp.isnan(xc)
-        keys = jnp.where(maskc & ~nan_mask, xc, sentval)
-        sent_flag = jnp.any((xc == sentval) & maskc)
-        nan_flag = jnp.any(nan_mask & maskc)
-        nan_entry = jnp.asarray(jnp.nan, xc.dtype)
-    else:
-        sentval = jnp.asarray(jnp.iinfo(xc.dtype).max, xc.dtype)
-        keys = jnp.where(maskc, xc, sentval)
-        sent_flag = jnp.any((xc == sentval) & maskc)
-        nan_flag = jnp.asarray(False)
-        nan_entry = sentval  # dead slot (flag stays False)
-
-    s = jnp.sort(keys)
-    uniq = jnp.concatenate(
-        [jnp.ones((1,), dtype=bool), s[1:] != s[:-1]]
-    )
-    real_u = uniq & (s < sentval)  # NaN compares False too
-    U = jnp.sum(real_u).astype(jnp.int32)
-
-    def dict_path():
-        targets = jnp.arange(1, D + 1, dtype=jnp.int32)
-        slot = jnp.arange(D, dtype=jnp.int32)
-        ranks = jnp.cumsum(real_u.astype(jnp.int32))
-        pos = jnp.searchsorted(ranks, targets)
-        entries = s[jnp.clip(pos, 0, B - 1)]
-        full = jnp.concatenate(
-            [entries, jnp.stack([sentval, nan_entry])]
-        )
-        valid = jnp.concatenate(
-            [slot < U, jnp.stack([sent_flag, nan_flag])]
-        )
-        h1, h2 = hash_pair_numeric(full)
-        return registers_from_hash_pair(h1, h2, valid)
-
-    def scatter_path():
-        return _scatter_column(xc, maskc)
-
-    return jax.lax.cond(U <= D, dict_path, scatter_path)
-
-
-def dedup_column_registers_from_sorted(
-    s: jnp.ndarray,  # (B,) PRE-SORTED keys: invalid/non-finite -> +inf
-    xc: jnp.ndarray,  # (B,) raw values (flag probes + fallback scatter)
-    maskc: jnp.ndarray,  # (B,) validity
-) -> jnp.ndarray:
-    """(M,) batch registers from an ALREADY-SORTED key array — the
-    KLL group's masked f32 sort (engine/vectorize._kll_sorted_stack),
-    which maps nulls AND every non-finite value to the +inf sentinel.
-    The three non-finite values (+inf, -inf, NaN) are therefore absent
-    from the unique run and re-enter as flagged extra dictionary
-    slots, probed from the raw column. Bit-identity caveats match
-    dedup_column_registers (canonical-NaN collapse).
-
-    INTEGER columns may ride the same f32 pool when the planner has
-    proven their range fits the 24-bit mantissa (f32 cast exact):
-    dictionary entries cast BACK to the raw dtype before hashing, so
-    they take hash_pair_numeric's integral path bit-identically to the
-    per-row scatter; the non-finite extras are impossible for int data
-    (their flags are always False) and their cast garbage is masked."""
-    (B,) = s.shape
-    D = min(DEDUP_DICT_CAP, B)
-    sentval = jnp.asarray(jnp.inf, s.dtype)
-    uniq = jnp.concatenate(
-        [jnp.ones((1,), dtype=bool), s[1:] != s[:-1]]
-    )
-    real_u = uniq & (s < sentval)
-    U = jnp.sum(real_u).astype(jnp.int32)
-    integral = not jnp.issubdtype(xc.dtype, jnp.floating)
-    if integral:
-        false = jnp.asarray(False)
-        pos_inf = neg_inf = nan_flag = false
-    else:
-        pos_inf = jnp.any((xc == jnp.inf) & maskc)
-        neg_inf = jnp.any((xc == -jnp.inf) & maskc)
-        nan_flag = jnp.any(jnp.isnan(xc) & maskc)
-
-    def dict_path():
-        targets = jnp.arange(1, D + 1, dtype=jnp.int32)
-        slot = jnp.arange(D, dtype=jnp.int32)
-        ranks = jnp.cumsum(real_u.astype(jnp.int32))
-        pos = jnp.searchsorted(ranks, targets)
-        entries = s[jnp.clip(pos, 0, B - 1)]
-        extras = jnp.asarray([jnp.inf, -jnp.inf, jnp.nan], s.dtype)
-        full = jnp.concatenate([entries, extras]).astype(xc.dtype)
-        valid = jnp.concatenate(
-            [slot < U, jnp.stack([pos_inf, neg_inf, nan_flag])]
-        )
-        h1, h2 = hash_pair_numeric(full)
-        return registers_from_hash_pair(h1, h2, valid)
-
-    def scatter_path():
-        return _scatter_column(xc, maskc)
-
-    return jax.lax.cond(U <= D, dict_path, scatter_path)
-
-
-def gated_column_registers_from_sorted(
-    s: jnp.ndarray,  # (B,) shared-pool sorted f32 keys for this column
-    xc: jnp.ndarray,  # (B,) raw values
-    maskc: jnp.ndarray,  # (B,) validity
-    prev_registers: jnp.ndarray,  # (M,) carried state for this column
-) -> jnp.ndarray:
-    """Runtime-widened sorted-dedup dispatch for ONE column the planner
-    could NOT statically qualify (the O(1) range probe failed, or the
-    declared range was too wide to prove anything). The column still
-    rides the shared KLL sort — already paid for — and takes the dict
-    path only when BOTH runtime checks pass:
-
-    - the carried-register linear-counting estimate says
-      mid-cardinality (``dedup_gate``), and
-    - for integer data, every valid value in THIS batch fits the f32
-      24-bit mantissa, so the pool's f32 sort keys are exact and the
-      dict entries round-trip to the raw dtype bit-identically.
-
-    Correctness never depends on the gate being right: a mispredicted
-    estimate (actual batch U > D) falls back to the scatter INSIDE
-    dedup_column_registers_from_sorted, and a non-qualifying batch
-    pays only the two cheap checks on top of its plain scatter."""
-    gate = dedup_gate(prev_registers)
-    if jnp.issubdtype(xc.dtype, jnp.floating):
-        qualifies = gate
-    else:
-        lim = 1 << 24  # f32 mantissa: int casts are exact in ±2^24
-        xi = xc.astype(jnp.int64)
-        in_mantissa = jnp.all(
-            jnp.where(maskc, (xi >= -lim) & (xi <= lim), True)
-        )
-        qualifies = gate & in_mantissa
-    return jax.lax.cond(
-        qualifies,
-        lambda: dedup_column_registers_from_sorted(s, xc, maskc),
-        lambda: _scatter_column(xc, maskc),
-    )
-
-
-def registers_from_sorted_dedup_stacked(
-    x: jnp.ndarray,  # (C, B) values, one dtype
-    masks: jnp.ndarray,  # (C, B) validity
-) -> jnp.ndarray:
-    """(C, M) batch registers, every column through the sorted-dedup
-    builder (no gating) — the differential-test surface for
-    dedup_column_registers."""
-    return jnp.stack(
-        [
-            dedup_column_registers(x[c], masks[c])
-            for c in range(x.shape[0])
-        ]
-    )
-
-
-def numeric_registers_adaptive(
-    x: jnp.ndarray,  # (C, B) values
-    masks: jnp.ndarray,  # (C, B) validity
-    prev_registers: jnp.ndarray,  # (C, M) carried state
-) -> jnp.ndarray:
-    """THE numeric register builder. Default: ONE stacked flat scatter
-    for the whole group. When the carried state says ANY column is
-    mid-cardinality, the group switches to per-column dispatch where
-    each gated column pays ITS OWN sort + unique compaction (~8 ms vs
-    ~15 ms scatter at B=2^21) and ungated columns keep a plain scatter
-    — a high-cardinality column never pays for its mid-card neighbors
-    (the r5 batched-sort-for-the-whole-group variant measured a net
-    LOSS on mixed groups for exactly that reason). Both layouts
-    scatter at the same per-element rate (PERF.md r4: banked splits ==
-    stacked)."""
-    if not _dedup_supported(x.dtype):
-        h1, h2 = hash_pair_numeric(x)
-        return registers_from_hash_pair_stacked(h1, h2, masks)
-    C = x.shape[0]
-    gate = dedup_gate(prev_registers)
-
-    def scatter_all():
-        h1, h2 = hash_pair_numeric(x)
-        return registers_from_hash_pair_stacked(h1, h2, masks)
-
-    def per_column():
-        outs = []
-        for c in range(C):
-            outs.append(
-                jax.lax.cond(
-                    gate[c],
-                    lambda c=c: dedup_column_registers(x[c], masks[c]),
-                    lambda c=c: _scatter_column(x[c], masks[c]),
-                )
-            )
-        return jnp.stack(outs)
-
-    return jax.lax.cond(jnp.any(gate), per_column, scatter_all)
-
-
-def _scatter_column(xc: jnp.ndarray, maskc: jnp.ndarray) -> jnp.ndarray:
-    h1, h2 = hash_pair_numeric(xc)
-    return registers_from_hash_pair(h1, h2, maskc)
 
 
 _Q = 32  # h2 supplies 32 bits => register ranks 0..Q+1

@@ -39,15 +39,11 @@ described v5e chip.
 from __future__ import annotations
 
 import functools
+import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from deequ_tpu.sketches.pallas_scatter import (
-    _interpret_forced,
-    _pow2_at_least,
-)
 
 LO_BITS = 7
 LO = 1 << LO_BITS  # one-hot width of the low index bits (MXU lanes)
@@ -90,6 +86,17 @@ def _decode(sums, hi_count: int):
         rank = jnp.where(s > 0, rank, jnp.int32(0))
         out = rank if out is None else jnp.maximum(out, rank)
     return out
+
+
+def _interpret_forced() -> bool:
+    return os.environ.get("DEEQU_TPU_PALLAS_INTERPRET", "0") == "1"
+
+
+def _pow2_at_least(n: int) -> int:
+    p = 1
+    while p < n:
+        p <<= 1
+    return p
 
 
 def _tile_for(b: int) -> int:

@@ -13,9 +13,10 @@ acceptance):
    the analytics tenant's parked batch run (no priority inversion);
 4. resubmission: the same suite runs again and the warm plan survives
    (still zero compiles);
-5. a ``hll_dedup_widening`` flag flip compiles under a DISTINCT
-   plan-cache entry — engine options are part of the plan fingerprint,
-   so a flipped production run never poisons the warm cache;
+5. a ``device_cache_bytes=0`` flip (stream instead of staying
+   resident) compiles under a DISTINCT plan-cache entry — engine
+   options are part of the plan fingerprint, so a flipped production
+   run never poisons the warm cache;
 6. the JSONL telemetry artifact renders the operator's ``service:``
    section (tools/obs_report.py).
 
@@ -55,8 +56,6 @@ def make_orders() -> Dataset:
     rng = np.random.default_rng(42)
     return Dataset.from_pydict(
         {
-            # wide int64s (beyond the f32-exact range): the schema
-            # shape whose pooled-HLL unit the widening flag changes
             "order_id": rng.integers(0, 1 << 40, ROWS, dtype=np.int64),
             "txn_hash": rng.integers(0, 1 << 40, ROWS, dtype=np.int64),
             "amount": np.abs(
@@ -207,7 +206,7 @@ def main() -> None:
         before_flip = set(plan_cache_snapshot())
         ColumnProfiler.profile(dataset)
         mid_flip = set(plan_cache_snapshot())
-        with config.configure(hll_dedup_widening=False):
+        with config.configure(device_cache_bytes=0):
             ColumnProfiler.profile(dataset)
         after_flip = set(plan_cache_snapshot())
     finally:
@@ -215,7 +214,7 @@ def main() -> None:
     flipped_new = after_flip - mid_flip - before_flip
     assert flipped_new, "flag flip did not produce a distinct plan"
     print(
-        f"hll_dedup_widening flip compiled {len(flipped_new)} distinct "
+        f"device_cache_bytes flip compiled {len(flipped_new)} distinct "
         f"plan entr{'ies' if len(flipped_new) > 1 else 'y'}"
     )
 

@@ -89,3 +89,18 @@ def big_numeric(n: int = 100_000, seed: int = 7) -> Dataset:
             "y": rng.integers(0, 50, n),
         }
     )
+
+
+def host_hll_registers(values, valid) -> np.ndarray:
+    """int8[M] HLL registers of one column on the host: ``np.maximum.at``
+    over the one (index, rank) derivation, the reference every engine
+    path's register build must equal bit for bit."""
+    import jax.numpy as jnp
+
+    from deequ_tpu.sketches import hll
+
+    h1, h2 = hll.hash_pair_numeric(jnp.asarray(values))
+    idx, rho = hll._index_and_rank(h1, h2, jnp.asarray(valid))
+    registers = np.zeros(hll.M, np.int32)
+    np.maximum.at(registers, np.asarray(idx), np.asarray(rho))
+    return registers.astype(hll.REGISTER_DTYPE)

@@ -60,18 +60,6 @@ def test_stream_phase(table, tmp_path):
     assert os.listdir(tmp_path) == []  # parquet shards cleaned up
 
 
-def test_pallas_phase(ds_ref, monkeypatch):
-    from deequ_tpu.sketches import pallas_scatter
-
-    monkeypatch.setenv("DEEQU_TPU_PALLAS_INTERPRET", "1")
-    pallas_scatter._reset_probe_for_tests()
-    try:
-        chip_smoke.phase_pallas(*ds_ref)
-    finally:
-        monkeypatch.delenv("DEEQU_TPU_PALLAS_INTERPRET")
-        pallas_scatter._reset_probe_for_tests()
-
-
 def test_mesh_phase_on_four_devices(ds_ref, monkeypatch):
     """Five steps on a fresh plan cache: a carry whose sharding changes
     after step 1 (the retrace found on 4 chips in PR 21) fails it."""

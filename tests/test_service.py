@@ -579,59 +579,6 @@ class TestScanPlan:
         assert estimated_run_bytes(ds) == AnalysisEngine(
         ).estimated_run_bytes(ds) > 0
 
-    def test_pallas_flag_flip_same_key_on_cpu(self):
-        from deequ_tpu import Dataset, config
-        from deequ_tpu.analyzers import ApproxCountDistinct
-        from deequ_tpu.engine import AnalysisEngine
-        from deequ_tpu.sketches import pallas_scatter
-
-        with config.configure(pallas_scatter=True):
-            if pallas_scatter.impl_token() != "xla":
-                pytest.skip("pallas kernel available on this host")
-        ds = Dataset.from_pydict(
-            {"k": list(np.arange(256, dtype=np.int64))}
-        )
-        engine = AnalysisEngine()
-        pairs = self._pairs(ds, [ApproxCountDistinct("k")])
-        baseline = engine.prepare_scan(ds, pairs)
-        with config.configure(pallas_scatter=True):
-            flipped = engine.prepare_scan(ds, pairs)
-        # the key carries the RESOLVED scatter impl token: flipping
-        # the flag where the kernel can't run changes nothing, so the
-        # warm plan is correctly reused
-        assert flipped.cache_key == baseline.cache_key
-
-    def test_hll_widening_flip_yields_distinct_entry(self):
-        # the acceptance flag-flip: hll_dedup_widening changes the
-        # pooled-HLL unit (runtime-gated lax.cond vs scatter-only), so
-        # the same profile compiles under a DISTINCT plan-cache entry
-        from deequ_tpu import Dataset, config
-        from deequ_tpu.engine.scan import plan_cache_snapshot
-        from deequ_tpu.profiles.profiler import ColumnProfiler
-
-        rng = np.random.default_rng(7)
-        ds = Dataset.from_pydict({
-            "svc_flip_a": list(
-                rng.integers(0, 1 << 40, 2048).astype(np.int64)
-            ),
-            "svc_flip_b": list(
-                rng.integers(0, 1 << 40, 2048).astype(np.int64)
-            ),
-        })
-        before = set(plan_cache_snapshot())
-        with config.configure(hll_dedup_widening=True):
-            ColumnProfiler.profile(ds)
-        mid = set(plan_cache_snapshot())
-        with config.configure(hll_dedup_widening=False):
-            ColumnProfiler.profile(ds)
-        after = set(plan_cache_snapshot())
-        assert len(mid - before) >= 1
-        assert len(after - mid) >= 1  # the flip compiled a NEW plan
-        # and re-running under the first flag is warm (no new entries)
-        with config.configure(hll_dedup_widening=True):
-            ColumnProfiler.profile(ds)
-        assert set(plan_cache_snapshot()) == after
-
 
 class TestWarmPlans:
     def test_warm_plans_reports_tokens_then_idempotent(self):

@@ -390,34 +390,88 @@ class TestPresenceDTiling:
         np.testing.assert_array_equal(got, want)
 
 
-class TestSortedDedupRegisters:
-    """r5 adaptive numeric-HLL path: the sorted-dedup branch must
-    produce BIT-IDENTICAL registers to the per-row scatter (states
-    from the two paths max-merge, so divergence corrupts merges).
-    Covers the sentinel discipline: nulls, real +inf (the float
-    sentinel value), real iinfo.max (the int sentinel value),
-    canonical NaN, -0.0/+0.0, exactly-at-cap and over-cap fallback."""
+ENGINE_PATHS = ["resident", "streamed", "mesh"]
 
-    def _scatter_ref(self, x, masks):
-        from deequ_tpu.sketches import hll
 
-        h1, h2 = hll.hash_pair_numeric(jnp.asarray(x))
-        return np.asarray(
-            hll.registers_from_hash_pair_stacked(
-                h1, h2, jnp.asarray(masks)
-            )
+def _engine_registers(columns, path, request):
+    """Each column's ``ApproxCountDistinct`` state registers after a run
+    in 1,024-row batches on one engine path: resident, streamed with
+    the device cache off, or on the 8-device CPU mesh. ``columns``:
+    name -> (values, valid); nulls ride Arrow's validity."""
+    import pyarrow as pa
+
+    from deequ_tpu import config
+
+    table = pa.table(
+        {c: pa.array(v, mask=~valid) for c, (v, valid) in columns.items()}
+    )
+    analyzers = [ApproxCountDistinct(c) for c in columns]
+    options = {"batch_size": 1024}
+    if path == "streamed":
+        options["device_cache_bytes"] = 0
+    engine = (
+        AnalysisEngine(mesh=request.getfixturevalue("cpu_mesh"))
+        if path == "mesh"
+        else None
+    )
+    provider = InMemoryStateProvider()
+    with config.configure(**options):
+        AnalysisRunner.do_analysis_run(
+            Dataset.from_arrow(table), analyzers, save_states_with=provider,
+            **({"engine": engine} if engine else {}),
+        )
+    return {
+        c: np.asarray(provider.load(ApproxCountDistinct(c)).registers)
+        for c in columns
+    }
+
+
+def _assert_registers_match_host(columns, path, request):
+    from fixtures import host_hll_registers
+
+    got = _engine_registers(columns, path, request)
+    for c, (values, valid) in columns.items():
+        np.testing.assert_array_equal(
+            got[c], host_hll_registers(values, valid), err_msg=c
         )
 
-    def _dedup(self, x, masks):
-        from deequ_tpu.sketches import hll
 
-        return np.asarray(
-            hll.registers_from_sorted_dedup_stacked(
-                jnp.asarray(x), jnp.asarray(masks)
-            )
+def _dtype_columns(dtype, n=4096, seed=0):
+    """A mid- and a high-cardinality column of ``dtype``, ~5% null."""
+    rng = np.random.default_rng(seed)
+    if np.issubdtype(dtype, np.integer):
+        info = np.iinfo(dtype)
+        mid = rng.integers(1, min(info.max, 300), n)  # no 0: a null's fill
+        high = rng.integers(info.min, info.max, n, endpoint=True)
+    else:
+        mid = np.round(rng.normal(0, 40, n)) / 4
+        high = rng.normal(0, 1e6, n)
+    return {
+        name: (v.astype(dtype), rng.random(n) > 0.05)
+        for name, v in (("mid", mid), ("high", high))
+    }
+
+
+class TestRegisterBuild:
+    """The one numeric register build (the XLA scatter here; the MXU
+    kernel on a TPU, held to the same registers by TestMXURegisters)
+    through the engine, against a host ``np.maximum.at``: every storage
+    dtype on every engine path, and the edge values (nulls, +inf, -inf,
+    NaN, -0.0/+0.0, iinfo.max)."""
+
+    @pytest.mark.parametrize("path", ENGINE_PATHS)
+    @pytest.mark.parametrize(
+        "dtype",
+        [np.int8, np.int16, np.int32, np.int64, np.float32, np.float64],
+    )
+    def test_engine_registers_equal_host(self, dtype, path, request):
+        _assert_registers_match_host(
+            _dtype_columns(dtype, seed=np.dtype(dtype).itemsize),
+            path, request,
         )
 
-    def test_float_edges_match_scatter(self):
+    @pytest.mark.parametrize("path", ENGINE_PATHS)
+    def test_float_edges_match_scatter(self, path, request):
         rng = np.random.default_rng(31)
         B = 4096
         rows = [
@@ -433,135 +487,41 @@ class TestSortedDedupRegisters:
             # constant column
             np.full(B, 42.0, dtype=np.float32),
         ]
-        x = np.stack(rows)
-        masks = rng.random((3, B)) > 0.1
-        got = self._dedup(x, masks)
-        want = self._scatter_ref(x, masks)
-        np.testing.assert_array_equal(got, want)
+        columns = {
+            f"f{i}": (x, rng.random(B) > 0.1) for i, x in enumerate(rows)
+        }
+        _assert_registers_match_host(columns, path, request)
 
-    def test_int_edges_match_scatter(self):
+    @pytest.mark.parametrize("path", ENGINE_PATHS)
+    def test_int_edges_match_scatter(self, path, request):
         rng = np.random.default_rng(32)
         B = 4096
-        x = np.stack(
-            [
-                rng.integers(0, 100, B),
-                # include the int sentinel value as REAL data
+        columns = {
+            # int16 beside int32: two groups of one, so the
+            # single-analyzer build runs too
+            "small": (
+                rng.integers(1, 100, B).astype(np.int16),
+                rng.random(B) > 0.2,
+            ),
+            "sentinel": (
                 np.where(
                     rng.random(B) < 0.1,
                     np.iinfo(np.int32).max,
                     rng.integers(-50, 50, B),
-                ),
-            ]
-        ).astype(np.int32)
-        masks = rng.random((2, B)) > 0.2
-        got = self._dedup(x, masks)
-        want = self._scatter_ref(x, masks)
-        np.testing.assert_array_equal(got, want)
-
-    def test_over_cap_falls_back_exactly(self):
-        """B must exceed DEDUP_DICT_CAP so U > D actually happens and
-        the inner scatter fallback (the correctness safety net the
-        gate design relies on) really executes."""
-        from deequ_tpu.sketches import hll
-
-        cap = hll.DEDUP_DICT_CAP
-        B = cap + 4096
-        x = np.stack(
-            [
-                np.arange(B, dtype=np.float32),  # U = B > cap: fallback
-                np.concatenate(
-                    [
-                        np.arange(cap, dtype=np.float32),
-                        np.zeros(B - cap, dtype=np.float32),
-                    ]
-                ),  # U exactly == cap: dict path at the boundary
-            ]
-        )
-        masks = np.ones((2, B), dtype=bool)
-        got = self._dedup(x, masks)
-        want = self._scatter_ref(x, masks)
-        np.testing.assert_array_equal(got, want)
-
-    def test_all_null_and_empty_gate(self):
-        from deequ_tpu.sketches import hll
-
-        B = 1024
-        x = np.zeros((1, B), dtype=np.float32)
-        masks = np.zeros((1, B), dtype=bool)
-        assert (self._dedup(x, masks) == 0).all()
-        # gate: empty registers -> False; mid-card registers -> True;
-        # saturated registers -> False
-        empty = np.zeros((1, hll.M), np.int8)
-        assert not bool(np.asarray(hll.dedup_gate(jnp.asarray(empty)))[0])
-        mid = np.zeros((1, hll.M), np.int8)
-        mid[0, : hll.M // 16] = 3  # ~1k registers touched
-        assert bool(np.asarray(hll.dedup_gate(jnp.asarray(mid)))[0])
-        full = np.full((1, hll.M), 3, np.int8)
-        assert not bool(np.asarray(hll.dedup_gate(jnp.asarray(full)))[0])
-
-    def test_adaptive_end_to_end_two_batches(self):
-        """Through the public analyzer: batch 1 (scatter, empty state)
-        then batch 2 (gated dedup) must equal a one-shot run and the
-        exact distinct count within HLL error."""
-        from deequ_tpu.analyzers import AnalysisRunner, ApproxCountDistinct
-        from deequ_tpu.data import Dataset
-
-        rng = np.random.default_rng(34)
-        n = 40_000
-        vals = np.round(rng.normal(100, 5, n) * 100) / 100  # ~3.5k uniq
-        ds = Dataset.from_pydict({"x": vals.astype(np.float32)})
-        with __import__("deequ_tpu").config.configure(batch_size=16_384):
-            ctx = AnalysisRunner.do_analysis_run(
-                ds, [ApproxCountDistinct("x")]
-            )
-        got = ctx.metric(ApproxCountDistinct("x")).value.get()
-        exact = len(np.unique(vals))
-        assert abs(got - exact) / exact < 0.05, (got, exact)
+                ).astype(np.int32),
+                rng.random(B) > 0.2,
+            ),
+        }
+        _assert_registers_match_host(columns, path, request)
 
 
 class TestDedupFromSortedPool:
-    """The pooled variant (dedup from the KLL group's pre-sorted keys,
-    where nulls AND every non-finite value sort as +inf) must also be
-    bit-identical to the per-row scatter — incl. real -inf, which the
-    pool sort hides and the flag path must re-add."""
-
-    def test_pool_variant_matches_scatter(self):
-        from deequ_tpu.sketches import hll
-
-        rng = np.random.default_rng(41)
-        B = 8192
-        rows = [
-            np.round(rng.normal(100, 25, B) * 100).astype(np.float32)
-            / 100,
-            np.array(
-                [np.inf, -np.inf, np.nan, -0.0, 0.0, 7.25] * (B // 6)
-                + [7.25] * (B % 6),
-                dtype=np.float32,
-            ),
-            rng.normal(0, 1, B).astype(np.float32),  # high-card
-        ]
-        for xc in rows:
-            maskc = rng.random(B) > 0.15
-            s = np.sort(
-                np.where(
-                    maskc & np.isfinite(xc), xc, np.float32(np.inf)
-                )
-            )
-            got = np.asarray(
-                hll.dedup_column_registers_from_sorted(
-                    jnp.asarray(s), jnp.asarray(xc), jnp.asarray(maskc)
-                )
-            )
-            h1, h2 = hll.hash_pair_numeric(jnp.asarray(xc))
-            want = np.asarray(
-                hll.registers_from_hash_pair(h1, h2, jnp.asarray(maskc))
-            )
-            np.testing.assert_array_equal(got, want)
+    """An HLL column profiled beside its quantiles reports the same
+    count as the analyzer run alone."""
 
     def test_profiler_pool_equality_end_to_end(self):
-        """A profile with KLL + HLL co-planned (the pool fires) must
-        report the same ApproxCountDistinct as a run with the analyzer
-        alone (scatter path)."""
+        """A profile with KLL + HLL co-planned must report the same
+        ApproxCountDistinct as a run with the analyzer alone."""
         from deequ_tpu.analyzers import (
             AnalysisRunner,
             ApproxCountDistinct,
@@ -598,41 +558,11 @@ class TestDedupFromSortedPool:
 
 
 class TestIntPoolDedup:
-    """Range-gated integer columns ride the f32 KLL pool (r5): the
-    dictionary entries cast back to the raw dtype before hashing, so
-    registers stay bit-identical to the per-row integral scatter."""
-
-    def test_int_pool_variant_matches_scatter(self):
-        from deequ_tpu.sketches import hll
-
-        rng = np.random.default_rng(51)
-        B = 8192
-        for vals in (
-            rng.integers(1, 101, B).astype(np.int32),  # quantity-like
-            rng.integers(-(1 << 24), 1 << 24, B).astype(
-                np.int32
-            ),  # full f32-exact range
-        ):
-            maskc = rng.random(B) > 0.1
-            s = np.sort(
-                np.where(maskc, vals.astype(np.float32), np.float32(np.inf))
-            )
-            got = np.asarray(
-                hll.dedup_column_registers_from_sorted(
-                    jnp.asarray(s),
-                    jnp.asarray(vals),
-                    jnp.asarray(maskc),
-                )
-            )
-            h1, h2 = hll.hash_pair_numeric(jnp.asarray(vals))
-            want = np.asarray(
-                hll.registers_from_hash_pair(h1, h2, jnp.asarray(maskc))
-            )
-            np.testing.assert_array_equal(got, want)
+    """The same for integer columns, bounded and wide."""
 
     def test_end_to_end_int_pool_equality(self):
-        """Quantity-style int column profiled WITH quantiles (pool
-        fires) must report the same ApproxCountDistinct as alone."""
+        """Quantity-style int column profiled WITH quantiles must
+        report the same ApproxCountDistinct as alone."""
         from deequ_tpu.analyzers import (
             AnalysisRunner,
             ApproxCountDistinct,
@@ -668,47 +598,11 @@ class TestIntPoolDedup:
             ApproxCountDistinct("qty")
         ).value.get() == pytest.approx(100, abs=2)
 
-    def test_high_magnitude_narrow_range_not_pooled(self):
-        """A narrow-RANGE int32 column at high magnitude (~2^30) must
-        NOT ride the f32 pool (the cast is inexact there): its
-        estimate must match the analyzer run alone (review finding)."""
-        from deequ_tpu.analyzers import (
-            AnalysisRunner,
-            ApproxCountDistinct,
-            ApproxQuantiles,
-        )
-        from deequ_tpu.data import Dataset
 
-        rng = np.random.default_rng(53)
-        n = 20_000
-        base = 1 << 30
-        vals = base + rng.integers(0, 77, n)  # width 77, magnitude 2^30
-        ds = Dataset.from_pydict(
-            {"a": vals, "b": rng.integers(1, 50, n)}
-        )
-        together = AnalysisRunner.do_analysis_run(
-            ds,
-            [
-                ApproxCountDistinct("a"),
-                ApproxCountDistinct("b"),
-                ApproxQuantiles("a", [0.5]),
-                ApproxQuantiles("b", [0.5]),
-            ],
-        )
-        alone = AnalysisRunner.do_analysis_run(
-            ds, [ApproxCountDistinct("a")]
-        )
-        a = together.metric(ApproxCountDistinct("a")).value.get()
-        b = alone.metric(ApproxCountDistinct("a")).value.get()
-        assert a == b, (a, b)
-        assert a == pytest.approx(77, abs=2)
-
-
-class TestPooledPathEdges:
-    """Degenerate shapes through the r5 pooled/dedup machinery: empty
-    tables, all-null pooled columns, single rows, and where-filtered
-    HLL co-planned with an unfiltered KLL group (different where ->
-    no pool, adaptive path)."""
+class TestHLLBesideKLLEdges:
+    """Degenerate shapes for HLL groups planned beside KLL groups:
+    empty tables, all-null columns, single rows, and a where-filtered
+    HLL beside an unfiltered KLL group."""
 
     def test_empty_dataset_profiles(self):
         import pyarrow as pa
@@ -726,7 +620,7 @@ class TestPooledPathEdges:
         p = ColumnProfilerRunner().on_data(ds).run()
         assert sorted(p.profiles) == ["q", "x"]
 
-    def test_all_null_pooled_column(self):
+    def test_all_null_column(self):
         import pyarrow as pa
 
         from deequ_tpu.data import Dataset
@@ -753,7 +647,7 @@ class TestPooledPathEdges:
             ApproxCountDistinct("b")
         ).value.get() == pytest.approx(1.0, rel=0.01)
 
-    def test_single_row_pooled(self):
+    def test_single_row(self):
         from deequ_tpu.data import Dataset
 
         ds = Dataset.from_pydict({"x": [2.5], "y": [3]})
@@ -821,8 +715,8 @@ def _mxu_case(case):
         return hashed(1, 4096)
     if case == "seventeen_columns":
         return hashed(17, 2048)
-    if case == "dictionary_d_plus_3":
-        return hashed(1, hll.DEDUP_DICT_CAP + 3)
+    if case == "dictionary_d_plus_3":  # three rows past a tile multiple
+        return hashed(1, 16387)
     if case == "no_rows":
         return hashed(2, 0)
     raise ValueError(case)

@@ -76,34 +76,10 @@ def test_fused_scan_step(one_chip):
     assert compiled.memory_analysis() is not None
 
 
-@pytest.mark.parametrize(
-    "cols, rows, m",
-    [
-        (8, ROWS, 1 << 14),  # production: C columns of one 2^21 batch
-        (3, 5000, 1 << 14),  # a short tail batch, padded to the tile
-        (2, 4096, 1024),  # the availability probe's own shape
-    ],
-)
-def test_pallas_scatter(one_chip, cols, rows, m):
-    """The repaired HLL scatter kernel lowers through Mosaic."""
-    from deequ_tpu.sketches import hll, pallas_scatter
-
-    assert hll.M == 1 << 14
-    idx = jax.ShapeDtypeStruct((cols, rows), jnp.int32, sharding=one_chip)
-    compiled = (
-        jax.jit(
-            lambda i, r: pallas_scatter._scatter_max_call(i, r, m, False)
-        )
-        .lower(idx, idx)
-        .compile()
-    )
-    assert "tpu_custom_call" in compiled.as_text()
-
-
 def test_fused_scan_step_mxu(one_chip, monkeypatch):
-    """The same step with the TPU's default register build: the MXU
-    kernel inside the fused program (the stacked group, the sorted-dedup
-    branches and the string dictionary)."""
+    """The same step with the TPU's register build: the MXU kernel
+    inside the fused program (the stacked groups and the string
+    dictionary)."""
     import __graft_entry__
     from deequ_tpu.sketches import hll_mxu
 
@@ -121,7 +97,7 @@ def test_fused_scan_step_mxu(one_chip, monkeypatch):
     "cols, rows",
     [
         (17, ROWS),  # the profile's 17 high-cardinality columns
-        (1, (1 << 14) + 3),  # the sorted-dedup dictionary's D+3 entries
+        (1, 16387),  # one column, three rows past a tile multiple
     ],
 )
 def test_hll_mxu_kernel(one_chip, cols, rows):
@@ -240,9 +216,11 @@ def test_dp4_mesh_step_returns_its_carry(topo, monkeypatch):
     monkeypatch.setattr(scan, "_plan_cache_lookup", capturing)
     table = chip_smoke.build_table(20_000, chip_smoke.COLS, seed=0)
     cpu_mesh = Mesh(np.array(jax.devices("cpu")[:4]), ("dp",))
+    # held until the recompile below: the captured step retraces, and a
+    # string predicate traces only while its dataset lives
+    ds = Dataset.from_arrow(table)
     chip_smoke.phase_verify(
-        Dataset.from_arrow(table), chip_smoke.Reference(table), "dp4",
-        AnalysisEngine(mesh=cpu_mesh),
+        ds, chip_smoke.Reference(table), "dp4", AnalysisEngine(mesh=cpu_mesh),
     )
     fn, args = calls[0]
 

@@ -29,16 +29,6 @@ the same STATIC decisions production data will:
 ``--suite`` additionally warms a VerificationSuite-shaped plan
 (completeness/uniqueness/compliance per column) on top of the default
 ColumnProfiler plan.
-
-Two engine options are part of the plan fingerprint (r6) and get their
-own warm pass automatically when they would change the compiled
-program: ``pallas_scatter`` (the plan-cache key carries the resolved
-impl token, so the Pallas-scatter program is distinct — warmed only
-where the kernel is actually available, i.e. on a TPU host) and
-``hll_dedup_widening`` (off compiles the scatter-only pooled HLL unit
-instead of the runtime-gated ``lax.cond`` unit — warmed whenever the
-schema has an int column, so a production flag-flip never eats a
-compile).
 """
 
 from __future__ import annotations
@@ -178,24 +168,11 @@ def warm_once(schema, rows, nullable, wide_ints, suite: bool,
     return time.time() - t0
 
 
-def default_engine_variants(schema) -> list:
-    """Engine-option variants that change the compiled program for
-    this schema on THIS host (each is a distinct plan-cache
-    fingerprint; see engine/scan.py ``_plan_cache_key``). The default
-    pass warms (xla scatter, widening on); extra passes only run when
-    they would actually compile something different."""
-    from deequ_tpu import config
-    from deequ_tpu.sketches import pallas_scatter
-
+def default_engine_variants() -> list:
+    """Engine-option variants that change the compiled program (each
+    is a distinct plan-cache fingerprint; see engine/scan.py
+    ``_plan_cache_key``)."""
     variants = [{}]
-    if any(k in ("int32", "int64") for k in schema.values()):
-        # dedup-gate branch: widening off is the scatter-only pooled
-        # HLL unit — warm it so flipping the escape hatch in
-        # production is free
-        variants.append({"hll_dedup_widening": False})
-    with config.configure(pallas_scatter=True):
-        if pallas_scatter.impl_token() == "pallas":
-            variants.append({"pallas_scatter": True})
     # streaming wire, codecs on AND off: the codec-table token rides
     # the streaming plan fingerprint (engine/scan.py), so the codec-on
     # wire and the codecs-off differential oracle are two distinct
@@ -284,7 +261,7 @@ def warm_plans(
     if not has_string:
         high_card_strings = (False,)
     if engine_variants is None:
-        engine_variants = default_engine_variants(schema)
+        engine_variants = default_engine_variants()
 
     engines = _mesh_engines(mesh_shapes)
     before = set(plan_cache_snapshot())
