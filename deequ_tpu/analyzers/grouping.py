@@ -170,7 +170,12 @@ class FrequenciesAndNumRows:
         survive differs. A canonical cross-path tie order would need a
         type-aware secondary sort (numeric vs code vs lexicographic)
         on both paths for marginal value; callers needing stability
-        should raise max_detail_bins above the distinct count."""
+        should raise max_detail_bins above the distinct count. With the
+        device cache off the path follows the source too: an integer
+        key read from an in-memory table groups dense, the same key
+        streamed from parquet past one read batch of range groups in
+        the sort collector (spill.streamed_spill_eligible), so their
+        boundary bins can differ."""
         order = np.argsort(-self.counts, kind="stable")[:k]
         return self.keys[order, 0], self.counts[order]
 
@@ -333,16 +338,18 @@ def plan_frequency_passes(
 
         return run
 
-    def make_collector(plan, build_spec, deferred_thunk):
+    def make_collector(plan, build_spec, fallback):
         """Route a spill plan onto the shared fused scan: build its
         CollectorSpec and wire the three exits — success telemetry,
-        SpillOverflow -> host Arrow, shared-scan failure -> the plan's
-        own deferred re-scan thunk. A spec BUILD failure (geometry or
-        key-builder trace issues) quietly keeps the deferred twin."""
+        SpillOverflow -> host Arrow, shared-scan or finalize failure ->
+        ``fallback``, the ``(path, run)`` pair of the plan's deferred
+        re-scan or, with no resident cache to re-scan, its host Arrow
+        group-by. A spec BUILD failure (geometry or key-builder trace
+        issues) quietly keeps that ``run``."""
         try:
             spec = build_spec()
         except Exception:  # noqa: BLE001
-            deferred[plan] = deferred_thunk
+            deferred[plan] = fallback[1]
             return
 
         spec.on_success = lambda: note(plan, spec.path)
@@ -352,23 +359,33 @@ def plan_frequency_passes(
             return _arrow_frequencies(dataset, plan)
 
         spec.overflow_fallback = overflow_fallback
-        spec.scan_fallback = deferred_thunk
+        spec.scan_fallback = fallback
         collectors.append(spec)
 
-    for plan in plans:
+    def uncached_collector(plan):
+        """A single-column collector with no resident cache to re-scan:
+        its failure groups on the host."""
+        make_collector(
+            plan,
+            lambda: spill_mod.single_collector_spec(dataset, plan, engine),
+            ("host-arrow", make_arrow(plan)),
+        )
+
+    lane = {
+        plan: any(_is_lane(dataset, c) for c in plan.columns)
+        for plan in plans
+    }
+    # lane plans route first: they take no dense budget, and the
+    # streamed route's memory gate counts every collector the scan
+    # already holds
+    for plan in sorted(plans, key=lambda p: not lane[p]):
         # a hashed-string lane column has no dictionary to probe: its
         # plan sorts the row hashes on the device, or groups on the host
-        if any(_is_lane(dataset, c) for c in plan.columns):
+        if lane[plan]:
             if not keep_states and spill_mod.lane_spill_eligible(
                 dataset, plan
             ):
-                make_collector(
-                    plan,
-                    lambda p=plan: spill_mod.single_collector_spec(
-                        dataset, p, engine
-                    ),
-                    make_arrow(plan),
-                )
+                uncached_collector(plan)
             else:
                 deferred[plan] = make_arrow(plan)
             continue
@@ -382,10 +399,19 @@ def plan_frequency_passes(
                     lambda p=plan: spill_mod.single_collector_spec(
                         dataset, p, engine
                     ),
-                    make_spill(plan),
+                    ("deferred", make_spill(plan)),
                 )
             else:
                 deferred[plan] = make_spill(plan)
+            continue
+        # a streamed integral key whose range passes one read batch
+        # never probes its dictionary either: the probe would stream a
+        # batch's worth of uniques at least, and each batch would encode
+        # against a hash table larger than itself
+        if spill_mod.streamed_spill_eligible(
+            dataset, plan, engine, held=len(collectors)
+        ):
+            uncached_collector(plan)
             continue
         # capped distinct counts first: a spilling plan must never
         # materialize an unbounded value set on the host (probe with the
@@ -480,7 +506,7 @@ def plan_frequency_passes(
                             dataset, p, engine, d, s
                         )
                     ),
-                    make_joint(plan, dictionaries, sizes),
+                    ("deferred", make_joint(plan, dictionaries, sizes)),
                 )
             else:
                 deferred[plan] = make_joint(plan, dictionaries, sizes)
@@ -538,9 +564,11 @@ def finalize_collector_states(
     reports the cancellation as its own failure, otherwise
     ``RunCancelled`` propagates. A finalize whose sort buffers OOM
     (``MemoryPressureError`` via engine/memory.py — ``oom_probe`` is
-    the fault-injection hook) downgrades to the plan's deferred re-scan
-    path, which itself downgrades to host Arrow under pressure — the
-    collector -> deferred -> Arrow chain, each rung recorded."""
+    the fault-injection hook) downgrades to the plan's scan fallback:
+    the deferred re-scan over the resident cache, which itself
+    downgrades to host Arrow under pressure (the collector -> deferred
+    -> Arrow chain), or host Arrow at once for a plan that has no
+    cache to re-scan (a lane or streamed key); each rung recorded."""
     from deequ_tpu.analyzers.spill import SpillOverflow, _sort_span
     from deequ_tpu.engine.deadline import RunCancelled
     from deequ_tpu.engine.pack import packed_device_get
@@ -571,10 +599,10 @@ def finalize_collector_states(
             # collector -> deferred rung of the chain)
             if classify_memory_pressure(exc) is not None:
                 record_spill_downgrade(
-                    "finalize", spec.plan.columns, "deferred"
+                    "finalize", spec.plan.columns, spec.scan_fallback[0]
                 )
             try:
-                out[spec.plan] = spec.scan_fallback()
+                out[spec.plan] = spec.scan_fallback[1]()
             except Exception as fallback_exc:  # noqa: BLE001
                 if not isolate:
                     raise
@@ -612,10 +640,10 @@ def finalize_collector_states(
                 # host-side result construction hit pressure: re-scan
                 # via the deferred twin (which can itself downgrade)
                 record_spill_downgrade(
-                    "finalize", spec.plan.columns, "deferred"
+                    "finalize", spec.plan.columns, spec.scan_fallback[0]
                 )
                 try:
-                    out[spec.plan] = spec.scan_fallback()
+                    out[spec.plan] = spec.scan_fallback[1]()
                     continue
                 except Exception as fallback_exc:  # noqa: BLE001
                     exc = fallback_exc

@@ -24,7 +24,11 @@ from deequ_tpu.analyzers.base import (
     wrap_if_necessary,
 )
 from deequ_tpu.data.table import Dataset, Schema
-from deequ_tpu.engine.memory import oom_probe_of
+from deequ_tpu.engine.memory import (
+    classify_memory_pressure,
+    oom_probe_of,
+    record_spill_downgrade,
+)
 from deequ_tpu.engine.scan import AnalysisEngine
 from deequ_tpu.metrics.metric import Metric
 from deequ_tpu.telemetry import get_telemetry, merge_summaries
@@ -699,7 +703,28 @@ def _execute_fused_pass(
     if scan_pairs:
         try:
             if row_sink is None:
-                states = engine.run_scan(data, scan_pairs)
+                try:
+                    states = engine.run_scan(data, scan_pairs)
+                except Exception as exc:  # noqa: BLE001 — classified
+                    if not collectors or classify_memory_pressure(
+                        exc
+                    ) is None:
+                        raise
+                    # the collectors' key buffers are the scan's largest
+                    # states: each takes its scan fallback, and the
+                    # scalars and dense plans scan again without them
+                    for spec in collectors:
+                        path, run = spec.scan_fallback
+                        record_spill_downgrade(
+                            "scan", spec.plan.columns, path
+                        )
+                        deferred[spec.plan] = run
+                    scan_pairs = scan_pairs[: len(units) + len(dense)]
+                    collectors = []
+                    states = (
+                        engine.run_scan(data, scan_pairs)
+                        if scan_pairs else []
+                    )
             else:
                 # split phases so the sink learns the scan's quarantine
                 # geometry (chunk rows resident / batch rows streaming)
@@ -725,9 +750,10 @@ def _execute_fused_pass(
             dense = []
             # a shared-scan failure must not take the spill plans down
             # with it (they ran independently before one-pass fusion):
-            # each collector degrades to its own deferred re-scan
+            # each collector degrades to its own scan fallback (the
+            # deferred re-scan, or host Arrow without a resident cache)
             for spec in collectors:
-                deferred[spec.plan] = spec.scan_fallback
+                deferred[spec.plan] = spec.scan_fallback[1]
             collectors = []
 
     if states is not None:

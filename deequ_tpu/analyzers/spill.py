@@ -1015,56 +1015,68 @@ class ShardedDeviceFrequencies(DeviceFrequencies):
         )
 
 
-def device_spill_eligible(dataset: Dataset, plan, engine=None) -> bool:
-    """True when a frequency plan should run the device sort path:
-    a single INTEGRAL/FRACTIONAL grouping column whose flat sort fits
-    the device budget. Strings keep the dense/Arrow path (their keys
-    are dictionary codes); booleans and timestamps keep it too so
-    decoded key VALUES (True/False, datetime64) stay merge-compatible
-    with dense-path states; uint64 can't widen to the i64 key lane.
-
-    Note the asymmetry with the dense path: dense must first build a
-    host-side dictionary (an Arrow hash pass over every row) just to
-    LEARN the cardinality; the sort path needs no dictionary at all,
-    so for FRACTIONAL and unbounded-domain integer columns it wins
-    even at low cardinality. Bounded-domain integers are the
-    exception (the DENSE_DOMAIN_RANGE gate below): a single O(1)
-    min/max probe bounds their dictionary up front, and the dense
-    fused scan then beats one device sort per column."""
+def _sortable_key(dataset: Dataset, plan) -> Optional[Kind]:
+    """The kind of a plan's one INTEGRAL/FRACTIONAL grouping column
+    when the device sort can group it under the current options, else
+    None. Strings keep the dense/Arrow path (their keys are dictionary
+    codes); booleans and timestamps keep it too so decoded key VALUES
+    (True/False, datetime64) stay merge-compatible with dense-path
+    states; uint64 can't widen to the i64 key lane. Bounded-domain
+    integers stay dense as well: one O(1)-memory min/max probe (free
+    from parquet row-group stats) bounds their dictionary by the
+    range, and ALL such columns ride the shared fused dense scan,
+    while the sort path costs a sequential device sort per column
+    (r5: 5 qty columns = 2.75 s/run steady + a one-time ~60 s
+    sort-plan compile vs milliseconds dense)."""
     from deequ_tpu import config
 
     opts = config.options()
     if not opts.device_spill_grouping:
-        return False
-    if not opts.device_cache_bytes:
-        return False  # chunked device path needs the resident cache
+        return None
     if opts.engine == "cpu":
-        return False  # honor the engine-selection flag's placement
+        return None  # honor the engine-selection flag's placement
     if dataset.num_rows >= 2**31:
-        return False  # i32 segment counts; the dense path widens, we gate
+        return None  # i32 segment counts; the dense path widens, we gate
     if len(plan.columns) != 1:
-        return False
+        return None
     column = plan.columns[0]
     kind = dataset.schema.kind_of(column)
     if kind not in (Kind.INTEGRAL, Kind.FRACTIONAL):
-        return False
+        return None
     try:
         dt = dataset.request_dtype(ColumnRequest(column, "values"))
     except Exception:  # noqa: BLE001 — odd column: use the host path
-        return False
+        return None
     if dt.kind == "u" and dt.itemsize == 8:
-        return False
+        return None
     if kind == Kind.INTEGRAL:
-        # bounded-domain integers (TPC-DS quantity-style): one O(1)-
-        # memory min/max probe (free from parquet row-group stats)
-        # detects them, the host dictionary is then bounded by the
-        # range, and ALL such columns ride the shared fused dense scan
-        # — while the sort path costs a sequential device sort per
-        # column (r5: 5 qty columns = 2.75 s/run steady + a one-time
-        # ~60 s sort-plan compile vs milliseconds dense)
         rng = dataset.integral_range(column)
         if rng is not None and (rng[1] - rng[0]) < DENSE_DOMAIN_RANGE:
-            return False
+            return None
+    return kind
+
+
+def device_spill_eligible(dataset: Dataset, plan, engine=None) -> bool:
+    """True when a frequency plan should run the device sort path over
+    the resident device cache: a sortable key column
+    (``_sortable_key``) whose flat sort fits the cache's headroom.
+    With the cache on, a failed collector re-scans the cached chunks
+    (``device_spill_frequencies``), so this route NEEDS the cache; the
+    cache-off route for a streamed key is
+    ``streamed_spill_eligible``, which needs none.
+
+    Note the asymmetry with the dense path: dense must first build a
+    host-side dictionary (an Arrow hash pass over every row) just to
+    LEARN the cardinality; the sort path needs no dictionary at all,
+    so with the cache on it takes FRACTIONAL and unbounded-domain
+    integer columns even at low cardinality."""
+    from deequ_tpu import config
+
+    opts = config.options()
+    if not opts.device_cache_bytes:
+        return False  # the deferred re-scan reads the resident cache
+    if _sortable_key(dataset, plan) is None:
+        return False
     # f64 keys: CPU-class backends bitcast on device; elsewhere (TPU)
     # the canonical u64 bits pack on the HOST (f64_canonical_bits —
     # the X64 rewriter cannot lower the f64 bitcast, measured r4) and
@@ -1075,6 +1087,69 @@ def device_spill_eligible(dataset: Dataset, plan, engine=None) -> bool:
     # padded); 64 B/row keeps the whole pass clear of HBM even when the
     # budget is sized close to the device memory
     return dataset.num_rows * 64 <= opts.device_cache_bytes
+
+
+# device bytes a single-lane collector holds at its peak, per pow2 row
+# slot of its buffer: the u64 keys and the finalize's sort transients
+# (sorted copy, segment counts), as the cache route's gate counts them
+COLLECTOR_BYTES_PER_SLOT = 64
+
+
+def device_memory_bytes(engine) -> Optional[int]:
+    """The memory of one device the engine runs on, as its backend
+    reports it (``bytes_limit``), or None where the backend reports
+    none (the CPU backend, whose device is the host's memory)."""
+    mesh = getattr(engine, "mesh", None)
+    device = mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
+    try:
+        stats = device.memory_stats()
+    except Exception:  # noqa: BLE001 — a backend without the query
+        return None
+    return (stats or {}).get("bytes_limit")
+
+
+def streamed_spill_eligible(dataset: Dataset, plan, engine,
+                            held: int = 0) -> bool:
+    """True when a frequency plan over a streamed source with the
+    device cache off should group in the one-pass collector
+    (``single_collector_spec``): one INTEGRAL key column whose
+    dictionary would cost a traversal of the source
+    (``Dataset.dictionary_traversal_batch``) and whose statistics range
+    spans more values than one batch of that traversal. Past one
+    batch, the dense route's probe streams at least a batch's worth of
+    uniques before it can stop, and each batch's codes come from a
+    hash table larger than the batch itself; the collector's cost is
+    one device sort, linear in rows. No resident cache is needed: the
+    collector's buffer is scan state, and a failure groups on the host
+    (Arrow), as a lane plan does. A column without statistics, a
+    FRACTIONAL key, a bounded range, an in-memory table and the
+    cache-on route keep their paths.
+
+    The buffer is allocated with the scan's other states, so the
+    route must fit the device beside them: this collector and the
+    ``held`` collectors already routed into the same scan, at
+    ``COLLECTOR_BYTES_PER_SLOT`` each, take at most half of the
+    device's memory (``device_memory_bytes``); the other half is left
+    to the batches in flight and the dense count vectors. A plan past
+    that keeps the dense route."""
+    from deequ_tpu import config
+
+    opts = config.options()
+    if not opts.one_pass_spill or opts.device_cache_bytes:
+        return False
+    batch = dataset.dictionary_traversal_batch
+    if batch is None:
+        return False
+    if _sortable_key(dataset, plan) != Kind.INTEGRAL:
+        return False
+    rng = dataset.integral_range(plan.columns[0])
+    if rng is None or rng[1] - rng[0] < batch:
+        return False
+    limit = device_memory_bytes(engine)
+    if limit is None:
+        return True
+    local_cap = _collector_geometry(dataset, engine)[3]
+    return (held + 1) * local_cap * COLLECTOR_BYTES_PER_SLOT <= limit // 2
 
 
 def joint_spill_config_ok(dataset: Dataset, plan, engine=None) -> bool:
@@ -1792,8 +1867,10 @@ class CollectorSpec:
     construct the FrequenciesAndNumRows state. ``build`` may raise
     :class:`SpillOverflow` (sharded hash bucket past capacity); the
     planner attaches ``overflow_fallback`` (host Arrow) and
-    ``scan_fallback`` (the deferred per-plan re-scan, for when the
-    shared scan itself fails) plus ``on_success`` telemetry."""
+    ``scan_fallback``, a ``(path, run)`` pair for when the shared scan
+    or the finalize fails: ``run`` is the deferred per-plan re-scan
+    with the resident cache ("deferred"), else host Arrow
+    ("host-arrow"), plus ``on_success`` telemetry."""
 
     def __init__(self, plan, requests, ops, path, dispatch):
         self.plan = plan

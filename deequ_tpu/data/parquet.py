@@ -227,6 +227,12 @@ class ParquetDataset(Dataset):
         return self._num_rows
 
     @property
+    def dictionary_traversal_batch(self) -> int:
+        # a column's dictionary is a pre-pass over the files
+        # (``_collect_uniques``), read in batches of this many rows
+        return self._read_batch_rows
+
+    @property
     def num_columns(self) -> int:
         return len(self._schema)
 

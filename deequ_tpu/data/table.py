@@ -409,6 +409,14 @@ class Dataset:
         self._cache_key = id(self)
         weakref.finalize(self, Dataset._drop_cache_key, self._cache_key)
 
+    @property
+    def dictionary_traversal_batch(self) -> Optional[int]:
+        """The rows a traversal of the source reads at a time when it
+        learns a column's dictionary, or None where the dictionary is a
+        hash over the table already held in memory. A streamed source
+        (data/parquet.py) reads itself again for it."""
+        return None
+
     # device-cache accounting is GLOBAL across Datasets (one chip, one
     # HBM): LRU registry of datasets holding device-resident columns
     _cache_registry: "OrderedDict[int, weakref.ref]" = OrderedDict()

@@ -141,9 +141,11 @@ def record_memory_pressure(
 
 
 def record_spill_downgrade(stage: str, columns, path: str) -> None:
-    """Count + event one memory-pressure downgrade of a spill/collector
-    finalize (``engine.spill_downgrades``; the downgrade chain is
-    collector -> deferred per-plan re-scan -> host Arrow)."""
+    """Count + event one memory-pressure downgrade of a spill plan
+    (``engine.spill_downgrades``): a collector whose shared scan or
+    finalize failed, or a deferred re-scan. The downgrade chain is
+    collector -> deferred per-plan re-scan -> host Arrow, or collector
+    -> host Arrow for a collector with no resident cache to re-scan."""
     tm = get_telemetry()
     tm.counter("engine.spill_downgrades").inc()
     tm.event(

@@ -291,6 +291,16 @@ def plan_cache_snapshot() -> List[str]:
     return [plan_token_of(k) for k in _PLAN_CACHE.keys()]
 
 
+def _init_states(dataset: Dataset, ops: Sequence[ScanOps]) -> tuple:
+    """Every op's initial state, allocated on the device before the
+    first batch; a dataset's fault probe (``oom_probe_of``) fails the
+    allocation here as a device short of memory would."""
+    probe = oom_probe_of(dataset)
+    if probe is not None:
+        probe("scan_init")
+    return tuple(o.init() for o in ops)
+
+
 def estimated_run_bytes(dataset: Dataset, engine=None) -> int:
     """Module-level convenience for admission-adjacent callers (the
     service dataset cache) that need the SAME coarse device-footprint
@@ -1273,7 +1283,7 @@ class AnalysisEngine:
         )
         self.plan_cache_hit = self.plan_cache_hit or hit
 
-        states = tuple(o.init() for o in ops)
+        states = _init_states(dataset, ops)
         # delta-aware ops (LUTs in state, fed by streamed dictionary
         # deltas) see no deltas on the resident path — prime them with
         # the full dictionaries, or their LUTs silently stay empty
@@ -1575,7 +1585,7 @@ class AnalysisEngine:
         supervisor: Optional[ScanSupervisor] = None,
         wire_codecs: bool = True,
     ) -> List[Any]:
-        states = tuple(o.init() for o in ops)
+        states = _init_states(dataset, ops)
         # LUTs transfer ONCE; passing host numpy would re-copy per batch
         consts = jax.device_put(tuple(op.consts or {} for op in ops))
         # taken under the traversal span, before any scan:* span opens:

@@ -63,7 +63,9 @@ failure would, with zero real memory pressure:
   the transfer (device_put) stage;
 - ``oom_finalize=n`` / ``oom_deferred=n`` — the first ``n``
   collector-finalize / deferred-path probes OOM (the spill downgrade
-  chain in analyzers/grouping.py).
+  chain in analyzers/grouping.py);
+- ``oom_scan_init=n`` — the first ``n`` scans fail to allocate their
+  ops' initial states (a collector's key buffer among them).
 
 The fault ledger (remaining transient raises, remaining hangs, one-shot
 slow delays, remaining OOMs, the kill flag) is SHARED across iterator
@@ -135,6 +137,7 @@ class FaultInjectingDataset:
         oom_transfer_at: Optional[Any] = None,
         oom_finalize: int = 0,
         oom_deferred: int = 0,
+        oom_scan_init: int = 0,
         decode_transient: Optional[Dict[int, int]] = None,
         decode_permanent: Optional[Iterable[int]] = None,
     ):
@@ -171,6 +174,7 @@ class FaultInjectingDataset:
         self._oom_rows_over = int(oom_rows_over)
         self._oom_finalize_remaining = int(oom_finalize)
         self._oom_deferred_remaining = int(oom_deferred)
+        self._oom_scan_init_remaining = int(oom_scan_init)
         # r10 worker-stage faults: fired inside ``item.decode()`` on a
         # pool WORKER thread (simulated worker death), surfacing
         # through the ordered reassembly stage at the batch's exact
@@ -207,6 +211,11 @@ class FaultInjectingDataset:
         if stage == "deferred":
             if self._oom_deferred_remaining > 0:
                 self._oom_deferred_remaining -= 1
+                fire()
+            return
+        if stage == "scan_init":
+            if self._oom_scan_init_remaining > 0:
+                self._oom_scan_init_remaining -= 1
                 fire()
             return
         # a device that fits only `limit` rows: any wider allocation

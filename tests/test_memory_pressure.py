@@ -597,6 +597,106 @@ class TestSpillDowngrade:
             == 1
         )
 
+    def test_streamed_finalize_oom_falls_to_arrow(self, tmp_path, spill_data):
+        """With the cache off, a streamed near-unique key groups in the
+        collector with no cached chunks to re-scan: its finalize OOM
+        goes to Arrow's host group_by at once, never to the deferred
+        re-scan (whose probe would fire the armed deferred fault)."""
+        import pyarrow as pa
+        import pyarrow.parquet as pq
+
+        pq.write_table(pa.table(spill_data), str(tmp_path / "d.parquet"))
+        analyzers = [Size(), Mean("v"), Uniqueness(["id"])]
+        options = self._overrides(device_cache_bytes=0)
+        with config.configure(**options):
+            ref = _metric_values(
+                AnalysisRunner.do_analysis_run(
+                    Dataset.from_pydict(spill_data), analyzers
+                ),
+                analyzers,
+            )
+        tm = get_telemetry()
+        before = tm.counter("engine.spill_downgrades").value
+        arrow_before = tm.counter("grouping.spill.host-arrow").value
+        ds = FaultInjectingDataset(
+            Dataset.from_parquet(str(tmp_path), read_batch_rows=512),
+            oom_finalize=1,
+            oom_deferred=1,
+        )
+        with config.configure(**options):
+            with tm.run("streamed-finalize-oom") as cap:
+                ctx = AnalysisRunner.do_analysis_run(ds, analyzers)
+        assert _metric_values(ctx, analyzers) == ref
+        assert ds.faults_fired == [("oom", "finalize", 0, 0)]
+        assert tm.counter("engine.spill_downgrades").value - before == 1
+        assert tm.counter("grouping.spill.host-arrow").value == (
+            arrow_before + 1
+        )
+        (downgrade,) = [
+            e for e in _memory_events(cap)
+            if e["action"] == "spill-downgrade"
+        ]
+        assert downgrade["stage"] == "finalize"
+        assert downgrade["path"] == "host-arrow"
+
+    @pytest.mark.parametrize(
+        "cached, path",
+        [(True, "deferred"), (False, "host-arrow")],
+        ids=["cache-on", "cache-off-parquet"],
+    )
+    def test_scan_init_oom_keeps_scalars(
+        self, tmp_path, cpu_mesh, spill_data, cached, path
+    ):
+        """A shared scan that cannot allocate its states, the
+        collectors' key buffers among them, runs again without the
+        collectors: the scalars still come from the scan, and each
+        collector takes its scan fallback (the deferred re-scan over
+        the resident cache, or host Arrow for a streamed key)."""
+        import pyarrow as pa
+        import pyarrow.parquet as pq
+
+        analyzers = self._analyzers()
+        if cached:
+            options = self._overrides()
+            engine = AnalysisEngine(mesh=cpu_mesh)
+            inner = Dataset.from_pydict(spill_data)
+        else:
+            options = self._overrides(device_cache_bytes=0)
+            engine = None
+            pq.write_table(
+                pa.table(spill_data), str(tmp_path / "d.parquet")
+            )
+            inner = Dataset.from_parquet(str(tmp_path), read_batch_rows=512)
+        with config.configure(**options):
+            ref = _metric_values(
+                AnalysisRunner.do_analysis_run(
+                    Dataset.from_pydict(spill_data), analyzers, engine=engine
+                ),
+                analyzers,
+            )
+        tm = get_telemetry()
+        before = tm.counter("engine.spill_downgrades").value
+        ds = FaultInjectingDataset(inner, oom_scan_init=1)
+        with config.configure(**options):
+            with tm.run("scan-init-oom") as cap:
+                ctx = AnalysisRunner.do_analysis_run(
+                    ds, analyzers, engine=engine
+                )
+        assert _metric_values(ctx, analyzers) == ref
+        assert ds.faults_fired == [("oom", "scan_init", 0, 0)]
+        downgrades = [
+            e for e in _memory_events(cap)
+            if e["action"] == "spill-downgrade"
+        ]
+        assert downgrades
+        assert tm.counter("engine.spill_downgrades").value - before == len(
+            downgrades
+        )
+        assert {(e["stage"], e["path"]) for e in downgrades} == {
+            ("scan", path)
+        }
+        assert ["id"] in [e["columns"] for e in downgrades]
+
     def test_deferred_path_oom_without_collectors(
         self, cpu_mesh, spill_data
     ):
